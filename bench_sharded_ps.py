@@ -100,10 +100,13 @@ def _run(n: int, path: str, iters: int, warmup: int, bus: str,
     """One sweep point → {rows_per_sec_per_process, aggregate, wire...}.
 
     ``compute="jit"`` adds a real jitted model-grad step between pull and
-    push on every worker — rank 0 on the default backend (the chip when
-    alive and ``force_cpu`` is False), peers on CPU — the north-star
-    topology (accelerator workers against a sharded host PS) instead of
-    the bare control plane. ``hidden`` sizes that step's MLP."""
+    push on every worker — the north-star topology (accelerator workers
+    against a sharded host PS) instead of the bare control plane. One
+    accelerator belongs to one process, so the job states each rank's
+    device (``launch.check_device_claims``): rank 0 keeps the default
+    backend unless ``force_cpu``, every peer is pinned to the CPU; each
+    rank echoes the backend it actually ran on (``worker_compute``).
+    ``hidden`` sizes that step's MLP."""
     argv = _worker_argv(path, iters, warmup, compute, hidden,
                         push_comm, pull_wire, overlap, overlap_legs,
                         key_dist, staleness, cache_bytes, pull_dedup,
@@ -113,8 +116,13 @@ def _run(n: int, path: str, iters: int, warmup: int, bus: str,
     # the invoking shell must not silently move the zmq baseline arms
     # onto the shm backend (TRANSPORT-WIN would then compare shm vs shm)
     env_extra = {"MINIPS_BUS": bus}
+    env_per_rank = None
     if force_cpu:
         env_extra["MINIPS_FORCE_CPU"] = "1"
+    elif compute == "jit":
+        env_per_rank = {r: {"JAX_PLATFORMS": "cpu"} for r in range(1, n)}
+    else:  # a control-plane arm: no rank has device work
+        env_extra["JAX_PLATFORMS"] = "cpu"
     # chaos/reliable arms configure via env (launcher-inherited, no
     # per-app flag plumbing); explicit empty strings keep an armed
     # environment from leaking into the clean arms — MINIPS_TRACE too:
@@ -178,7 +186,7 @@ def _run(n: int, path: str, iters: int, warmup: int, bus: str,
         try:
             res = launch.run_local_job(
                 n, argv, base_port=None,  # OS-assigned free block
-                env_extra=env_extra or None,
+                env_extra=env_extra or None, env_per_rank=env_per_rank,
                 timeout=timeout)
         except Exception as e:  # noqa: BLE001 - may_fail arms record it
             if not may_fail:
@@ -2565,8 +2573,8 @@ def main() -> int:
     # resolved JAX backend stamp (satellite): probed in a SUBPROCESS so
     # the driver never grabs the TPU out from under a worker (libtpu is
     # exclusive per process) — ci/bench_regression.py refuses to
-    # compare artifacts whose backends differ (the r03-r05
-    # cpu-fallback runs were silently incomparable to r01/r02)
+    # compare artifacts whose backends differ (a CPU record is silently
+    # incomparable to a TPU one)
     def _resolve_jax_backend() -> str:
         try:
             probe = subprocess.run(

@@ -1,0 +1,388 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, no children, every local device through ``make_mesh()``. It
+drives the two SPMD apps through their own ``main`` (what ``python -m
+minips_tpu.apps.<app>`` runs) with the flags a user would type, at widths
+the repo already supports, then checks what came out by the repo's own
+means:
+
+- ``deepfm``: ``wide_deep_example --model deepfm`` — both table kinds in
+  one fused PS step; 2^22 slots x dim 8, Criteo shape, Adagrad, batch 16384.
+- ``lm``: ``lm_example --layout dp --attn flash`` — the Pallas kernels'
+  carrier; d=2048 x 8 layers, 32 heads, T=1024, B=16, bf16, remat=dots.
+- ``kernels``: the flash kernels against ``reference_attention`` on a small
+  input (GQA 32/4 forward and gradients; the sp ring path on the devices
+  present) and the opt-in ``gather_rows`` kernel against XLA's gather.
+
+It fails (non-zero exit, no result line) if JAX finds no TPU, if a loss is
+non-finite or does not fall, if the LM step holds fewer than three compiled
+Mosaic calls, if on several devices the LM step does not ask for an
+all-gather and a reduce-scatter, the DeepFM step moves a table-sized
+collective or table bytes are uneven, if a kernel disagrees with its
+reference, or if anything raises: nothing is caught and downgraded. It
+writes two JSON lines to stdout. The first is the facts of the run:
+versions, the compile cache directory, and per leg its losses, compiles,
+memory and the program's Mosaic calls and collectives. Times in it are
+host clock around work that ends in a device read; they are facts of this
+run, not a benchmark. The LAST line is the result, exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+with the device as JAX reports it.
+
+The script runs the checkout it sits in: a copy of it in a directory
+without the ``minips_tpu`` package fails before it touches the chip.
+
+``--rehearse-cpu`` is the explicit tiny-size rehearsal of the control flow
+on 4 fake CPU devices (the blockwise scan stands in for the kernels
+there); its output says ``cpu``. Without it, no TPU means failure.
+
+Usage: python chip_smoke.py
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import gc
+import json
+import math
+import os
+import statistics
+import sys
+import time
+
+STEPS = 8  # the compiling step + a handful
+
+
+def _argv(full: bool) -> dict[str, list[str]]:
+    """The two command lines, at full width or rehearsal size."""
+    if full:
+        slots, batch = 1 << 22, 16384
+        # lr: the app's default 3e-3 suits its 64-wide default model. At
+        # d=2048 without warmup an Adam step of 3e-4 still overshoots (on
+        # the chip the loss went 6.03 -> 6.67 before it fell); this script
+        # checks that the loss FALLS, so it takes steps small enough for
+        # the first-order term to win from the first one
+        lm = ["--dim", "2048", "--depth", "8", "--heads", "32",
+              "--seq_len", "1024", "--batch_size", "16",
+              "--head_chunk", "128", "--lr", "3e-5"]
+    else:
+        # slots >> batch x 26 fields even here: below that ratio GSPMD
+        # rightly all-gathers the tiny table and the traffic check misfires
+        slots, batch = 1 << 18, 256
+        lm = ["--dim", "64", "--depth", "2", "--heads", "4",
+              "--seq_len", "128", "--batch_size", "8", "--head_chunk", "32",
+              "--lr", "3e-3"]
+    common = ["--num_iters", str(STEPS), "--log_every", "1"]
+    return {
+        "deepfm": ["--model", "deepfm", "--exec", "spmd", "--num_slots",
+                   str(slots), "--updater", "adagrad", "--batch_size",
+                   str(batch), *common],
+        "lm": ["--layout", "dp", "--attn", "flash", *lm, "--dtype",
+               "bfloat16", "--remat", "--remat_mode", "dots", "--updater",
+               "adam", *common],
+    }
+
+
+class _Recorder:
+    """The ``metrics`` sink handed to an app: keeps every record with the
+    host clock, and reads each device's memory at ``tables_built``."""
+
+    def __init__(self, devices):
+        self.devices = devices
+        self.records: list[tuple[float, dict]] = []
+        self.memory_after_tables = None
+
+    def log(self, **record):
+        if record.get("event") == "tables_built":
+            self.memory_after_tables = _memory(self.devices, "bytes_in_use")
+        self.records.append((time.perf_counter(), record))
+        return record
+
+
+def _memory(devices, key: str) -> list:
+    """Per-device ``memory_stats()[key]``; None where the backend has no
+    allocator statistics (the CPU)."""
+    return [(d.memory_stats() or {}).get(key) for d in devices]
+
+
+class _CompileLog:
+    """JAX's own account of every backend compile in this process: its
+    function name, seconds (compile, or retrieval on a persistent-cache
+    hit) and whether the persistent cache answered it."""
+
+    def __init__(self):
+        import jax
+
+        self.compiles: list[dict] = []
+        self._hit = False
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
+
+    def _on_event(self, event, **kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            self._hit = True  # precedes its compile's duration event
+
+    def _on_secs(self, event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compiles.append({"fun": kw.get("fun_name"),
+                                  "secs": secs, "cache_hit": self._hit})
+            self._hit = False
+
+
+def _check(cond: bool, what: str) -> None:
+    if not cond:
+        raise SystemExit(f"chip_smoke: FAILED — {what}")
+
+
+def _run_leg(name, main, argv, devices, compile_log) -> tuple[dict, dict]:
+    """Drive one app through its ``main``; returns (facts, app result)."""
+    rec = _Recorder(devices)
+    first_compile = len(compile_log.compiles)
+    result = main(argv, metrics=rec)
+    compiles = compile_log.compiles[first_compile:]
+
+    at = [i for i, (_, r) in enumerate(rec.records)
+          if "step" in r and "loss" in r]
+    losses = [rec.records[i][1]["loss"] for i in at]
+    _check(len(losses) == STEPS, f"{name}: {len(losses)} of {STEPS} steps")
+    _check(all(math.isfinite(x) for x in losses),
+           f"{name}: non-finite loss in {losses}")
+    _check(losses[-1] < losses[0],
+           f"{name}: loss did not fall: {losses}")
+    # host clock from the record before to each step's own record, which
+    # follows a float(loss): every interval ends in a device read
+    step_s = [rec.records[i][0] - rec.records[i - 1][0] for i in at]
+    steady = statistics.median(step_s[1:])
+    step_compile = max(compiles, key=lambda c: c["secs"])
+    built = next(r for _, r in rec.records
+                 if r.get("event") == "tables_built")
+    table_bytes = built["table_bytes_per_device"]
+    _check(len(table_bytes) == len(devices)
+           and max(table_bytes.values()) <= 1.25 * min(table_bytes.values()),
+           f"{name}: table bytes uneven over devices: {table_bytes}")
+    facts = {
+        "argv": " ".join(argv),
+        "steps": len(losses),
+        "loss_first": losses[0], "loss_last": losses[-1],
+        "losses": losses,
+        # first step minus a steady one: trace + lower + compile (or the
+        # persistent-cache read)
+        "compile_s": round(step_s[0] - steady, 3),
+        "step_ms": [round(1e3 * s, 2) for s in step_s[1:]],
+        "step_compile": {**step_compile,
+                         "secs": round(step_compile["secs"], 3)},
+        "cache_hits": sum(c["cache_hit"] for c in compiles),
+        "cache_misses": sum(not c["cache_hit"] for c in compiles),
+        "table_bytes_per_device": table_bytes,
+        "memory_after_tables": rec.memory_after_tables,
+        "memory_peak": _memory(devices, "peak_bytes_in_use"),
+    }
+    return facts, result
+
+
+def _kinds(ops) -> dict[str, int]:
+    return dict(collections.Counter(op.kind for op in ops))
+
+
+def _program_facts(lowered) -> tuple[dict, list]:
+    """What the step holds. ``collectives_asked``: the program as lowered,
+    before the backend's passes — what the step asks the device for.
+    The rest is read from the optimized HLO — what the backend made of it
+    (a persistent-cache read: the app compiled this same program a moment
+    ago): Mosaic calls, and the collectives with their payload bytes."""
+    from minips_tpu.utils.comm_analysis import collective_ops
+
+    hlo = lowered.compile().as_text()
+    ops = collective_ops(hlo)
+    return {"mosaic_calls": hlo.count('custom_call_target="tpu_custom_call"'),
+            "collectives_asked": _kinds(
+                collective_ops(lowered.as_text(dialect="hlo"))),
+            "collectives": _kinds(ops),
+            "collective_bytes": sum(op.bytes for op in ops),
+            "largest_collectives": [
+                {"kind": op.kind, "shape": op.shape, "bytes": op.bytes}
+                for op in sorted(ops, key=lambda o: -o.bytes)[:4]]}, ops
+
+
+def _leg_deepfm(argv, devices, compile_log) -> dict:
+    from minips_tpu.apps import wide_deep_example as app
+    from minips_tpu.data import synthetic
+
+    facts, result = _run_leg("deepfm", app.main, argv, devices, compile_log)
+    ps = result["step"]
+    batch = int(argv[argv.index("--batch_size") + 1])
+    slots = int(argv[argv.index("--num_slots") + 1])
+    program, ops = _program_facts(
+        ps.lower(ps.shard_batch(synthetic.criteo_like(batch, seed=1))))
+    # pull/push of embedding rows must move the batch's rows, never a
+    # table (tests/test_sharded_traffic.py pins the same on the raw ops)
+    table_sized = [op.shape for op in ops
+                   if op.has_dim(slots) or op.has_dim(slots // len(devices))]
+    _check(not table_sized,
+           f"deepfm: table-sized collectives in the step: {table_sized}")
+    return {**facts, **program}
+
+
+def _leg_lm(argv, devices, compile_log, on_tpu) -> dict:
+    import numpy as np
+
+    from minips_tpu.apps import lm_example as app
+
+    facts, result = _run_leg("lm", app.main, argv, devices, compile_log)
+    table = result["table"]
+    batch, seq = (int(argv[argv.index(f) + 1])
+                  for f in ("--batch_size", "--seq_len"))
+    tokens = np.zeros((batch, seq + 1), np.int32)
+    program, _ = _program_facts(
+        result["step"].lower(table.params, table.opt_state,
+                             result["prep"]({"tokens": tokens})))
+    if on_tpu:  # forward, dQ, dK/dV — the kernels, not their scan twin
+        _check(program["mosaic_calls"] >= 3,
+               f"lm: {program['mosaic_calls']} Mosaic calls in the step")
+    if len(devices) > 1:  # pull ≡ all-gather, push ≡ reduce-scatter
+        _check({"all-gather", "reduce-scatter"}
+               <= set(program["collectives_asked"]),
+               f"lm: the step asks for {program['collectives_asked']}")
+        _check(program["collectives"],
+               "lm: no collective left in the compiled step")
+    return {**facts, **program}
+
+
+def _rel_err(a, b) -> float:
+    import jax.numpy as jnp
+
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+def _leg_kernels(on_tpu: bool) -> dict:
+    """The kernels against their references on a small input (off TPU, in
+    the rehearsal, their scan twins at a toy shape)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from minips_tpu.ops import pallas_kernels
+    from minips_tpu.ops.flash_attention import (flash_attention,
+                                                ring_flash_attention_local)
+    from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
+    from minips_tpu.parallel.ring_attention import reference_attention
+
+    B, T, H, Hk, D = (2, 1024, 32, 4, 64) if on_tpu else (2, 64, 4, 2, 16)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(kq, (B, T, H, D), jnp.bfloat16)
+    k = jax.random.normal(kk, (B, T, Hk, D), jnp.bfloat16)
+    v = jax.random.normal(kv, (B, T, Hk, D), jnp.bfloat16)
+    tol = 3e-2  # bf16 inputs and outputs against an f32-softmax oracle
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=True).astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        ref = reference_attention(q, k, v, causal=True)
+        g_ref = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(
+            q, k, v)
+    out = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))(
+        q, k, v)
+    g = jax.jit(jax.grad(loss(flash_attention), argnums=(0, 1, 2)))(q, k, v)
+    errs = {"gqa_fwd": _rel_err(out, ref),
+            **{f"gqa_d{n}": _rel_err(a, b)
+               for n, a, b in zip("qkv", g, g_ref)}}
+
+    # the sp ring: sequence sharded over the devices present, the same
+    # kernels offset-masked per ring step
+    mesh = make_mesh()
+    spec = P(None, DATA_AXIS)
+    ring = jax.jit(jax.shard_map(
+        lambda q, k, v: ring_flash_attention_local(
+            q, k, v, axis_name=DATA_AXIS, causal=True),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec))
+    errs["ring_fwd"] = _rel_err(ring(q, k, v), ref)
+    for name, err in errs.items():
+        _check(math.isfinite(err) and err < tol,
+               f"kernels: {name} differs from reference_attention by "
+               f"{err:.4f} (tolerance {tol})")
+    facts = {"shape": {"B": B, "T": T, "H": H, "kv_heads": Hk, "D": D},
+             "rel_err": {k: round(v, 5) for k, v in errs.items()},
+             "tolerance": tol}
+    if on_tpu:  # opt-in and off the default path; does it still compile?
+        emb = jax.random.normal(kq, (1 << 18, 128), jnp.float32)
+        slots = jax.random.randint(kk, (65536,), 0, 1 << 18)
+        rows = pallas_kernels.gather_rows(emb, slots)
+        _check(bool(jnp.array_equal(rows, emb[slots])),
+               "kernels: gather_rows differs from emb[slots]")
+        facts["gather_rows"] = "equal to emb[slots] at S=2^18 D=128 N=65536"
+    return facts
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="tiny-size rehearsal of the control flow on 4 fake "
+                         "CPU devices; the output says cpu")
+    args = ap.parse_args()
+    here = os.path.dirname(os.path.abspath(__file__))
+    if not os.path.isdir(os.path.join(here, "minips_tpu")):
+        raise SystemExit(
+            f"chip_smoke: no minips_tpu package beside {__file__}: this "
+            "script proves the checkout it sits in and nothing else")
+    if args.rehearse_cpu:  # stated before jax is imported
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=4")
+    t0 = time.perf_counter()
+    import jax
+
+    devices = jax.devices()
+    on_tpu = devices[0].platform == "tpu"
+    if not on_tpu and not args.rehearse_cpu:
+        raise SystemExit(
+            f"chip_smoke: no TPU — JAX found platform "
+            f"{devices[0].platform!r}. This script proves the program on "
+            "the chip and has no fallback (--rehearse-cpu is the explicit "
+            "tiny-size rehearsal)")
+
+    import jaxlib
+
+    from minips_tpu.utils import native_lib
+    from minips_tpu.utils.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    compile_log = _CompileLog()
+    argv = _argv(full=on_tpu)
+
+    legs = {"deepfm": _leg_deepfm(argv["deepfm"], devices, compile_log)}
+    gc.collect()  # the first leg's tables go before the LM fills the chip
+    legs["lm"] = _leg_lm(argv["lm"], devices, compile_log, on_tpu)
+    gc.collect()
+    legs["kernels"] = _leg_kernels(on_tpu)
+    _check(not native_lib.loaded_libs(),
+           f"a native library was loaded: {native_lib.loaded_libs()}")
+
+    from importlib import metadata
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = None
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    print(json.dumps({
+        "facts": "chip_smoke",
+        "device": device,
+        "rehearsal": "cpu" if args.rehearse_cpu else None,
+        "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                     "libtpu": libtpu},
+        "compile_cache_dir": cache_dir,
+        "native_libs_loaded": native_lib.loaded_libs(),
+        "wall_s": round(time.perf_counter() - t0, 1),
+        "legs": legs,
+    }))
+    # the result, last on stdout, in the shape the chip check reads
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1854,10 +1854,10 @@ def shape_mismatch(prior: dict, new: dict) -> list[str]:
 
 def backend_mismatch(prior: dict, new: dict) -> list[str]:
     """Refuse to compare artifacts measured on different JAX backends
-    (satellite): the r03-r05 ``cpu-fallback(tpu-unresponsive)`` runs
-    were silently incomparable to the r01/r02 TPU runs — absolute
-    rates across backends differ by integer factors, so every
-    REGRESSED/MISSING verdict would be noise. An artifact predating
+    (satellite): a record taken on the CPU is silently incomparable
+    to one taken on a TPU — absolute rates across backends differ by
+    integer factors, so every REGRESSED/MISSING verdict would be
+    noise. An artifact predating
     the stamp compares with a warning (we cannot refuse what was never
     recorded); re-basing on the new backend is the fix, as with any
     host change."""

@@ -36,7 +36,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from minips_tpu.apps.common import app_main
+from minips_tpu.apps.common import app_main, log_tables_built
 from minips_tpu.core.config import Config, TableConfig, TrainConfig
 from minips_tpu.data import synthetic
 from minips_tpu.data.loader import BatchIterator
@@ -44,7 +44,6 @@ from minips_tpu.models import transformer as tfm
 from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
 from minips_tpu.tables.dense import DenseTable
 from minips_tpu.train.loop import TrainLoop
-from minips_tpu.utils import jaxcompat
 
 DEFAULT = Config(
     table=TableConfig(name="lm", kind="dense", updater="adam", lr=3e-3),
@@ -284,6 +283,10 @@ def run(cfg: Config, args, metrics) -> dict:
     table = DenseTable(params, mesh, updater=cfg.table.updater,
                        lr=_lr_schedule(cfg, args), name=cfg.table.name,
                        updater_kwargs=_updater_kwargs(cfg, args, params))
+    # the table owns the parameters from here on; the template would sit
+    # on the default device for the whole run (1.6 GB at d=2048 x 8)
+    del params
+    log_tables_built(metrics, (table.params, table.opt_state))
     heads = model["heads"]
 
     ckpt, start_step = _maybe_checkpointer(cfg, args, table)
@@ -370,7 +373,10 @@ def run(cfg: Config, args, metrics) -> dict:
     gen = getattr(args, "generate", 0)
     out = {"losses": losses, "table": table, "layout": layout,
            "start_step": start_step,
-           "samples_per_sec": loop.timer.samples_per_sec}
+           "samples_per_sec": loop.timer.samples_per_sec,
+           # the jitted fused step and its batch placement, for callers
+           # that inspect the program (chip_smoke.py, tests)
+           "step": step, "prep": prep}
     if gen:
         # serving demo: pull the trained params and decode through the
         # KV cache (models/decode.py) — greedy unless --temperature
@@ -512,7 +518,7 @@ def _run_model_parallel(cfg, args, metrics, layout, seq_len) -> dict:
                 logits = tfm.apply_tp(p_, t_[:, :-1], heads=heads,
                                       axis_name=MODEL_AXIS)
             return jax.lax.pmean(tfm.nll(logits, t_[:, 1:]), DATA_AXIS)
-        return jaxcompat.shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(specs, P(DATA_AXIS)), out_specs=P())(p, toks)
 
@@ -560,7 +566,7 @@ def _run_ep(cfg, args, metrics, seq_len) -> dict:
                                        capacity=capacity, k_top=k_top)
             nll = jax.lax.pmean(tfm.nll(logits, t_[:, 1:]), DATA_AXIS)
             return nll + 0.01 * aux   # router load-balance pressure
-        return jaxcompat.shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(specs, P(DATA_AXIS)), out_specs=P())(p, toks)
 
@@ -569,8 +575,9 @@ def _run_ep(cfg, args, metrics, seq_len) -> dict:
                         capacity=capacity)
 
 
-def main():
-    return app_main("lm_example", DEFAULT, run, extra_flags=_flags)
+def main(argv=None, metrics=None):
+    return app_main("lm_example", DEFAULT, run, extra_flags=_flags,
+                    argv=argv, metrics=metrics)
 
 
 if __name__ == "__main__":

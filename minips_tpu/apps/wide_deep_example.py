@@ -13,7 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from minips_tpu.apps.common import app_main, holdout_split, score_holdout
+from minips_tpu.apps.common import (app_main, holdout_split,
+                                    log_tables_built, score_holdout)
 from minips_tpu.core.config import Config, TableConfig, TrainConfig
 from minips_tpu.data.loader import BatchIterator
 from minips_tpu.data import synthetic
@@ -161,6 +162,10 @@ def run(cfg: Config, args, metrics) -> dict:
                        compute_dtype=(jnp.bfloat16
                                       if getattr(args, "dtype", "float32")
                                       == "bfloat16" else None))
+    wide_t, emb_t, deep_t = tables
+    log_tables_built(metrics, [(wide_t.emb, wide_t.opt_state()),
+                               (emb_t.emb, emb_t.opt_state()),
+                               (deep_t.params, deep_t.opt_state)])
     _log_collisions(metrics, data["cat"], cfg.table.num_slots)
     batches = BatchIterator(data, cfg.train.batch_size, seed=cfg.train.seed)
     loop = TrainLoop(lambda b: ps(ps.shard_batch(b)), batches,
@@ -169,11 +174,10 @@ def run(cfg: Config, args, metrics) -> dict:
     losses = loop.run(cfg.train.num_iters)
     metrics.log(final_loss=losses[-1],
                 samples_per_sec=loop.timer.samples_per_sec)
-    wide_t, emb_t, deep_t = tables
     return score_holdout(
         _make_predict(wide_t, emb_t, deep_t.pull(), use_fm), holdout,
         {"losses": losses, "samples_per_sec": loop.timer.samples_per_sec,
-         "tables": tables}, metrics)
+         "tables": tables, "step": ps}, metrics)
 
 
 def _run_threaded(cfg: Config, args, metrics, data, holdout, *,
@@ -449,9 +453,10 @@ def _flags(parser):
                         default=-1)
 
 
-def main():
+def main(argv=None, metrics=None):
     return app_main("wide_deep_example", DEFAULT, run, extra_flags=_flags,
-                    exec_choices=("spmd", "threaded", "multiproc"))
+                    exec_choices=("spmd", "threaded", "multiproc"),
+                    argv=argv, metrics=metrics)
 
 
 if __name__ == "__main__":

@@ -133,18 +133,24 @@ def bus_endpoint_of(rank: int,
     return None
 
 
+def _hkey(host: str) -> str:
+    """Local aliases normalize to one key (a hostfile mixing 'localhost'
+    and '127.0.0.1' is one machine — same rule as bus_addresses)."""
+    return "127.0.0.1" if host in _LOCAL_NAMES else host
+
+
 def child_env(rank: int, hosts: list[str], base_port: int) -> dict[str, str]:
     env = dict(os.environ)
     env["MINIPS_PROC_ID"] = str(rank)
     env["MINIPS_NUM_PROCS"] = str(len(hosts))
+    if len(hosts) > 1:
+        # ranks of a multi-process job run cache-less (the hang finding
+        # in utils/compile_cache.py): a directory set in the launcher's
+        # environment must not arm JAX's cache in them either
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
     # processes COLOCATED on this rank's host — what host-resource
     # divisions (e.g. native parse threads) should divide by, not the
-    # world size. Local aliases normalize to one key (a hostfile mixing
-    # 'localhost' and '127.0.0.1' is one machine — same rule as
-    # bus_addresses; two would-be leaders would race the shared store).
-    def _hkey(h):
-        return "127.0.0.1" if h in _LOCAL_NAMES else h
-
+    # world size (two would-be leaders would race the shared store)
     keys = [_hkey(h) for h in hosts]
     env["MINIPS_LOCAL_PROCS"] = str(keys.count(keys[rank]))
     # my index among those colocated processes (0 = local leader, e.g.
@@ -156,6 +162,43 @@ def child_env(rank: int, hosts: list[str], base_port: int) -> dict[str, str]:
     env["MINIPS_BUS_ADDRS"] = ",".join(bus_addresses(hosts, base_port))
     env["MINIPS_COORDINATOR"] = f"{hosts[0]}:{base_port + 1000}"
     return env
+
+
+class DeviceClaimError(RuntimeError):
+    """More than one rank on a host would open the default backend."""
+
+
+def cpu_pinned(env) -> bool:
+    """Does this rank's environment STATE that it runs on the CPU?
+    ``JAX_PLATFORMS=cpu`` is JAX's own switch; ``MINIPS_FORCE_CPU`` is the
+    tests' per-child pin, applied by each app before its first backend
+    touch."""
+    return bool(env.get("MINIPS_FORCE_CPU")) or \
+        env.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def check_device_claims(hosts: list[str], envs: list[dict]) -> None:
+    """An accelerator belongs to one process at a time: a second rank
+    that opens the same chip fails or hangs at its first backend touch.
+    The launcher cannot look (touching JAX here would take the chip from
+    every child), so each host's ranks must state their device: at most
+    one rank per host may be left on the default backend, the rest pinned
+    to the CPU. Raises :class:`DeviceClaimError` otherwise — a job never
+    starts that would wait on a sibling's chip."""
+    claims: dict[str, list[int]] = {}
+    for rank, (host, env) in enumerate(zip(hosts, envs)):
+        if not cpu_pinned(env):
+            claims.setdefault(_hkey(host), []).append(rank)
+    for host, ranks in claims.items():
+        if len(ranks) > 1:
+            raise DeviceClaimError(
+                f"ranks {ranks} on {host} would all open the default JAX "
+                "backend, and one accelerator belongs to one process: the "
+                "others would fail or hang at their first device touch. "
+                "Run the ranks on the CPU (JAX_PLATFORMS=cpu python -m "
+                "minips_tpu.launch ...), leave at most one rank per host "
+                "unpinned, or drive all local chips from ONE process (the "
+                "fused SPMD apps do: python -m minips_tpu.apps.<app>)")
 
 
 def _sweep_shm() -> None:
@@ -179,17 +222,18 @@ def _sweep_shm() -> None:
 def spawn(hosts: list[str], argv: list[str], base_port: int = 5700,
           stdout=None) -> list[subprocess.Popen]:
     """Spawn one process per host entry; returns live Popen handles."""
+    envs = [child_env(rank, hosts, base_port) for rank in range(len(hosts))]
+    check_device_claims(hosts, envs)
     _sweep_shm()
     procs = []
-    for rank, host in enumerate(hosts):
-        env = child_env(rank, hosts, base_port)
+    for host, env in zip(hosts, envs):
         if host in _LOCAL_NAMES:
             cmd = argv
         else:  # remote: ssh with env inlined (reference ssh-spawn path)
             import shlex
             exports = " ".join(
                 f"{k}={shlex.quote(v)}" for k, v in env.items()
-                if k.startswith("MINIPS_"))
+                if k.startswith("MINIPS_") or k == "JAX_PLATFORMS")
             cmd = ["ssh", host,
                    exports + " " + " ".join(shlex.quote(a) for a in argv)]
         procs.append(subprocess.Popen(
@@ -240,9 +284,10 @@ def wait(procs: list[subprocess.Popen], timeout: Optional[float] = None,
 # the app module itself is imported post-fork from disk, so children run
 # current code with the process isolation the drills rely on (own pid,
 # own backend, killable with SIGKILL). Production spawns (`spawn()`, ssh,
-# TPU-bound ranks) keep plain subprocess: PJRT plugins and fork don't mix,
-# so only ranks pinned to CPU (MINIPS_FORCE_CPU) take the fast path.
-# Opt out with MINIPS_SPAWN=subprocess.
+# a rank that owns an accelerator) keep plain subprocess: a device
+# runtime does not survive fork, so only ranks pinned to CPU
+# (MINIPS_FORCE_CPU) take the fast path. Opt out with
+# MINIPS_SPAWN=subprocess.
 
 _FORK_CTX = None
 
@@ -349,6 +394,32 @@ def _spawn_rank(argv: list[str], env: dict, outfile):
                             stderr=subprocess.STDOUT)
 
 
+def _spawn_local_ranks(n: int, argv: list[str], base_port: Optional[int],
+                       env_extra: Optional[dict],
+                       env_per_rank: Optional[dict]):
+    """Shared front half of the two local-job runners: per-rank env,
+    the device-claim check, one harvest file and one process per rank.
+    Returns ``(outs, procs)``."""
+    import tempfile
+
+    if base_port is None:
+        base_port = find_free_base_port(n)
+    hosts = ["localhost"] * n
+    envs = []
+    for rank in range(n):
+        env = child_env(rank, hosts, base_port)
+        if env_extra:
+            env.update(env_extra)
+        if env_per_rank and rank in env_per_rank:
+            env.update(env_per_rank[rank])
+        envs.append(env)
+    check_device_claims(hosts, envs)
+    _sweep_shm()
+    outs = [tempfile.NamedTemporaryFile("w+", delete=False) for _ in hosts]
+    return outs, [_spawn_rank(argv, env, out)
+                  for env, out in zip(envs, outs)]
+
+
 def run_local_job(n: int, argv: list[str], *,
                   base_port: Optional[int] = None,
                   env_extra: Optional[dict] = None,
@@ -365,21 +436,9 @@ def run_local_job(n: int, argv: list[str], *,
     elastic-membership drills aim per-rank knobs (a joiner's standby
     config, a drain trigger) without giving every rank the flag."""
     import json
-    import tempfile
 
-    if base_port is None:
-        base_port = find_free_base_port(n)
-    _sweep_shm()
-    hosts = ["localhost"] * n
-    outs = [tempfile.NamedTemporaryFile("w+", delete=False) for _ in hosts]
-    procs = []
-    for rank in range(n):
-        env = child_env(rank, hosts, base_port)
-        if env_extra:
-            env.update(env_extra)
-        if env_per_rank and rank in env_per_rank:
-            env.update(env_per_rank[rank])
-        procs.append(_spawn_rank(argv, env, outs[rank]))
+    outs, procs = _spawn_local_ranks(n, argv, base_port, env_extra,
+                                     env_per_rank)
     rc = wait(procs, timeout=timeout)
     # read EVERY rank's output before judging any single one: the rank
     # that violates the protocol is often an innocent victim (killed by
@@ -438,21 +497,9 @@ def run_local_job_raw(n: int, argv: list[str], *,
     ``base_port=None`` auto-picks a free block (find_free_base_port);
     ``env_per_rank`` aims per-rank drill knobs like run_local_job's."""
     import json
-    import tempfile
 
-    if base_port is None:
-        base_port = find_free_base_port(n)
-    _sweep_shm()
-    hosts = ["localhost"] * n
-    outs = [tempfile.NamedTemporaryFile("w+", delete=False) for _ in hosts]
-    procs = []
-    for rank in range(n):
-        env = child_env(rank, hosts, base_port)
-        if env_extra:
-            env.update(env_extra)
-        if env_per_rank and rank in env_per_rank:
-            env.update(env_per_rank[rank])
-        procs.append(_spawn_rank(argv, env, outs[rank]))
+    outs, procs = _spawn_local_ranks(n, argv, base_port, env_extra,
+                                     env_per_rank)
     rc = wait(procs, timeout=timeout, kill_on_failure=kill_on_failure)
     events = []
     for f in outs:
@@ -511,7 +558,10 @@ def main(argv: Optional[list[str]] = None) -> int:
         hosts = ["localhost"] * args.n
     else:
         ap.error("need --hostfile or --n")
-    procs = spawn(hosts, cmd, base_port=args.base_port)
+    try:
+        procs = spawn(hosts, cmd, base_port=args.base_port)
+    except DeviceClaimError as e:
+        ap.error(str(e))
     return wait(procs, timeout=args.timeout)
 
 

@@ -23,16 +23,13 @@ Two attention modes, numerically identical:
 from __future__ import annotations
 
 import jax
-
 import jax.numpy as jnp
 
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
 from minips_tpu.parallel.mesh import DATA_AXIS
 from minips_tpu.parallel.ring_attention import (
     reference_attention,
     ring_attention_local,
 )
-from minips_tpu.utils import jaxcompat
 
 
 def init(key, *, vocab: int = 256, dim: int = 64, heads: int = 4,
@@ -123,7 +120,7 @@ def _block(h, blk, heads, attn_fn, compute_dtype, psum_axis=None,
     ``ffn_fn(blk, x_2d [B*T, D]) -> (y_2d, aux)`` replaces the dense MLP
     (the MoE variant); the dense path reports aux 0. Returns (h, aux)."""
     B, T, _ = h.shape
-    tp = 1 if psum_axis is None else _axis_size(psum_axis)
+    tp = 1 if psum_axis is None else jax.lax.axis_size(psum_axis)
     local_heads = heads // tp
     from jax.ad_checkpoint import checkpoint_name
     x = _ln(h, blk["ln1"]).astype(compute_dtype)
@@ -456,7 +453,7 @@ def apply_tp(params, tokens, *, heads=4, axis_name="model",
     taken inside would mis-reduce the replicated params
     (tests/test_tensor_parallel.py::test_tp_composes_with_dp).
     """
-    tp = _axis_size(axis_name)
+    tp = jax.lax.axis_size(axis_name)
     if heads % tp:
         raise ValueError(f"heads {heads} not divisible by tensor-parallel "
                          f"size {tp} (head-boundary sharding)")
@@ -679,10 +676,9 @@ def nll_chunked(h, tok_emb, targets, chunk, compute_dtype=jnp.bfloat16):
     # vary with the sharded inputs — pcast keeps the scan carry type fixed
     # (same treatment as DenseTable.make_step's accum fold)
     acc0 = jnp.zeros((), jnp.float32)
-    vma = (getattr(jaxcompat.typeof(h), "vma", frozenset())
-           | getattr(jaxcompat.typeof(targets), "vma", frozenset()))
+    vma = jax.typeof(h).vma | jax.typeof(targets).vma
     if vma:
-        acc0 = jaxcompat.pcast(acc0, tuple(sorted(vma)), to="varying")
+        acc0 = jax.lax.pcast(acc0, tuple(sorted(vma)), to="varying")
     total, _ = jax.lax.scan(body, acc0, (hs, ts))
     return total / (B * T)
 

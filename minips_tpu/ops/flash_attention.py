@@ -7,9 +7,11 @@ of the same exact math (softmax(QK^T)V, never materializing the [T, T]
 score matrix in HBM):
 
 - ``blockwise_attention`` — pure jnp, ``lax.scan`` over K/V chunks with
-  online-softmax carry. Runs anywhere (CPU tests, TPU), differentiable by
-  AD through the scan, O(T·block_k) live scores. This is the oracle-exact
-  portable path and the backward function for the kernel below.
+  online-softmax carry. Runs anywhere, differentiable by AD through the
+  scan, O(T·block_k) live scores. This is the kernels' platform twin: what
+  ``flash_attention`` runs OFF a TPU backend, and the tests' oracle. On a
+  TPU backend ``flash_attention`` runs the compiled kernels or raises —
+  never this scan.
 
 - ``flash_attention`` — Pallas TPU kernels. Forward: grid (batch, head,
   Q blocks, K blocks) with the K sweep innermost; the float32 online-
@@ -40,18 +42,10 @@ import functools
 from typing import Optional
 
 import jax
-
 import jax.numpy as jnp
 
-from minips_tpu.utils import jaxcompat
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
-
-try:  # pallas imports can fail on exotic backends; degrade to blockwise
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # finite mask value (matches ring_attention) — avoids
                   # -inf arithmetic NaNs on fully-masked rows
@@ -60,9 +54,9 @@ _NEG_INF = -1e30  # finite mask value (matches ring_attention) — avoids
 def _pcast_varying(x, axes):
     """pcast x to varying over exactly the axes it isn't already varying
     over (pcast rejects varying→varying)."""
-    have = getattr(jaxcompat.typeof(x), "vma", frozenset())
+    have = jax.typeof(x).vma
     need = tuple(a for a in axes if a not in have)
-    return jaxcompat.pcast(x, need, to="varying") if need else x
+    return jax.lax.pcast(x, need, to="varying") if need else x
 
 
 def gqa_group_size(num_q_heads: int, num_kv_heads: int) -> int:
@@ -77,7 +71,7 @@ def gqa_group_size(num_q_heads: int, num_kv_heads: int) -> int:
 
 def _expand_kv(q, k, v):
     """Repeat K/V heads up to Q's head count for the pure-jnp paths.
-    This forfeits GQA's memory saving (it exists only for oracle/fallback
+    This forfeits GQA's memory saving (it exists only for oracle/twin
     exactness off-TPU); the Pallas kernels instead map each q-head's
     block index onto its kv head and never materialize the repeat."""
     g = gqa_group_size(q.shape[2], k.shape[2])
@@ -242,7 +236,7 @@ def _vma_of(*xs):
     # varies over (VMA tracking); it varies exactly where the inputs do.
     vma = frozenset()
     for x in xs:
-        vma = vma | getattr(jaxcompat.typeof(x), "vma", frozenset())
+        vma = vma | jax.typeof(x).vma
     return vma
 
 
@@ -281,8 +275,8 @@ def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jaxcompat.sds((B, H, Tq, D), q.dtype, vma=vma),
-            jaxcompat.sds((B, H, Tq, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),   # acc
@@ -395,7 +389,7 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
         in_specs=[_smem_spec(), _smem_spec(),
                   q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jaxcompat.sds((B, H, Tq, D), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
     )(*offs, qt, kt, vt, dot, lse, dvec)
@@ -418,8 +412,8 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
                   row_spec_t],
         out_specs=[kv_spec_t, kv_spec_t],
         out_shape=[
-            jaxcompat.sds((B, Hk, Tk, D), k.dtype, vma=vma),
-            jaxcompat.sds((B, Hk, Tk, D), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hk, Tk, D), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hk, Tk, D), v.dtype, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
@@ -481,14 +475,33 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
 def kernel_supported(q_shape, k_shape, block_q: int, block_k: int) -> bool:
     """Static shape gate for the Pallas path: block sizes must tile the
     sequence (no ragged tails in the kernel) and D should be lane-friendly."""
-    if not _HAS_PALLAS:
-        return False
     B, Tq, H, D = q_shape
     Tk = k_shape[1]
     bq, bk = min(block_q, Tq), min(block_k, Tk)
     if q_shape[2] % k_shape[2]:   # GQA: kv heads must divide q heads
         return False
     return Tq % bq == 0 and Tk % bk == 0 and D % 8 == 0
+
+
+def _use_kernel(interpret: Optional[bool], q_shape, k_shape, block_q: int,
+                block_k: int) -> bool:
+    """The one platform rule both entry points share. ``interpret=None``
+    (every production caller): the compiled kernels on a TPU backend, the
+    blockwise scan — their documented platform twin — anywhere else. An
+    explicit ``interpret`` (tests: ``True`` runs the Pallas interpreter)
+    always means the kernels. Whoever gets the kernels gets them or a
+    ValueError naming the shape ``kernel_supported`` refused: the scan is
+    never a silent stand-in for a kernel that was asked for."""
+    if interpret is None and jax.default_backend() != "tpu":
+        return False
+    if not kernel_supported(q_shape, k_shape, block_q, block_k):
+        raise ValueError(
+            f"flash attention kernels refuse q{tuple(q_shape)} "
+            f"k{tuple(k_shape)} at blocks ({block_q}, {block_k}): the "
+            "blocks must tile both sequences, kv heads must divide q "
+            "heads, and the head dim must be a multiple of 8 — pick "
+            "tiling blocks or attn_impl='reference' for this shape")
+    return True
 
 
 def flash_attention(
@@ -504,24 +517,21 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Fused attention; same signature/semantics as
     ``ring_attention.reference_attention`` but never materializes the full
-    score matrix. Uses the Pallas kernel on TPU (or ``interpret=True``
-    anywhere, for tests); otherwise the blockwise scan — both exact.
+    score matrix. On a TPU backend: the compiled Pallas kernels, or a
+    ValueError for a shape they refuse. Off TPU: the blockwise scan, same
+    math (``interpret=True`` runs the kernels in the Pallas interpreter
+    anywhere — tests only). See :func:`_use_kernel`.
 
     Grouped-query attention: K/V may carry fewer heads than Q (kv divides
     q, q-head h reads kv head h // group). The kernel path streams the
     small K/V straight from HBM — traffic and ring wire bytes shrink by
-    the group factor; the fallback repeats heads (exact, memory-expanded).
+    the group factor; the scan repeats heads (exact, memory-expanded).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = False
-        use_kernel = (kernel_supported(q.shape, k.shape, block_q, block_k)
-                      and jax.default_backend() == "tpu")
-    else:
-        use_kernel = kernel_supported(q.shape, k.shape, block_q, block_k)
-    if use_kernel:
-        return _flash(q, k, v, causal, scale, block_q, block_k, interpret)
+    if _use_kernel(interpret, q.shape, k.shape, block_q, block_k):
+        return _flash(q, k, v, causal, scale, block_q, block_k,
+                      bool(interpret))
     return blockwise_attention(q, k, v, causal=causal, scale=scale,
                                block_k=block_k)
 
@@ -555,24 +565,21 @@ def ring_flash_attention_local(
     overlaps the hop with the kernel. Gradients flow through the kernels'
     custom VJP at every step.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if scale is None:
         scale = D ** -0.5
-    # Pallas path: compiled on TPU, interpreter only if explicitly asked
-    # (the interpreter can't track varying-manual-axes, so it only works
-    # under check_vma=False — kernel-level tests). Everywhere else the
+    # Pallas path: compiled on TPU (or a ValueError), interpreter only if
+    # explicitly asked (it can't track varying-manual-axes, so it only
+    # works under check_vma=False — kernel-level tests). Off TPU the
     # per-step math runs as the pure-jnp offset blockwise scan: same
     # algorithm and f32 softmax state, ordinary AD, no pallas involved.
     # Numerics match exactly for f32 inputs; for bf16 inputs the scan
     # upcasts q/k/v to f32 before its dots while the kernel runs
     # bf16-input dots with f32 accumulation (≤ bf16-rounding apart).
-    use_kernel = (kernel_supported(q.shape, k.shape, block_q, block_k)
-                  and (interpret is True
-                       or (interpret is None
-                           and jax.default_backend() == "tpu")))
-    interpret = bool(interpret) if interpret is not None else False
+    use_kernel = _use_kernel(interpret, q.shape, k.shape, block_q, block_k)
+    interpret = bool(interpret)
     perm = [(i, (i + 1) % n) for i in range(n)]
     # With causal=False no step masks, so the global offsets cannot affect
     # the math — and materializing axis_index here would leave an orphaned

@@ -41,12 +41,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-try:  # pallas imports can fail on exotic backends; degrade to the jnp path
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _CHUNK = 8  # rows per grid step = output sublane tile
 
@@ -54,7 +50,7 @@ _CHUNK = 8  # rows per grid step = output sublane tile
 def backend_supported() -> bool:
     """The compiled (non-interpret) kernels use pltpu primitives — TPU only;
     off-TPU they exist solely in interpret mode (tests)."""
-    return _HAS_PALLAS and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def pallas_enabled() -> bool:
@@ -63,7 +59,7 @@ def pallas_enabled() -> bool:
 
 
 def gather_supported(dim: int, n: int) -> bool:
-    return _HAS_PALLAS and dim % 128 == 0 and n % _CHUNK == 0
+    return dim % 128 == 0 and n % _CHUNK == 0
 
 
 def _gather_kernel(slots_ref, emb_ref, out_ref, sems):
@@ -85,16 +81,23 @@ def gather_rows(emb: jnp.ndarray, slots: jnp.ndarray,
                 interpret: bool = False) -> jnp.ndarray:
     """``emb[slots]`` via scalar-prefetch + per-row HBM→VMEM DMA.
 
-    emb: [S, D] with D % 128 == 0; slots: [N] int32, N % 8 == 0.
-    Falls back to XLA's gather when unsupported.
+    emb: [S, D] with D % 128 == 0; slots: [N] int32, N % 8 == 0. Whoever
+    calls this asked for the kernel: a shape it cannot take, or a compiled
+    call off TPU, raises — callers that want XLA's gather for such inputs
+    choose it themselves (``SparseTable.pull`` does).
     """
     slots = slots.reshape(-1).astype(jnp.int32)
     n, d = slots.shape[0], emb.shape[1]
-    # compiled kernels are TPU-only (pltpu primitives fail Mosaic lowering
-    # elsewhere); interpret mode runs anywhere
-    if not gather_supported(d, n) or (not interpret
-                                      and not backend_supported()):
-        return emb[slots]
+    if not gather_supported(d, n):
+        raise ValueError(
+            f"gather_rows needs dim % 128 == 0 and n % {_CHUNK} == 0, got "
+            f"dim={d}, n={n}")
+    if not interpret and not backend_supported():
+        # pltpu primitives fail Mosaic lowering anywhere else; interpret
+        # mode runs anywhere
+        raise RuntimeError(
+            "gather_rows: the compiled kernel needs a TPU backend, found "
+            f"{jax.default_backend()!r} (tests pass interpret=True)")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n // _CHUNK,),

@@ -24,10 +24,8 @@ training converges to the f32 loss within noise.
 from __future__ import annotations
 
 import jax
-
 import jax.numpy as jnp
 import numpy as np
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
 
 VALID = ("float32", "bfloat16", "int8")
 
@@ -350,7 +348,7 @@ def quantized_psum_scatter(gpad: jnp.ndarray, axis_name: str,
     _check(comm)
     if comm == "float32":
         return jax.lax.psum_scatter(gpad, axis_name, tiled=True)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     reduced, _ = a2a_reduce(gpad.reshape(n, -1), axis_name, comm,
                             block=block)
     return reduced
@@ -372,7 +370,7 @@ def quantized_psum_scatter_ef(gpad: jnp.ndarray, axis_name: str,
     if comm == "float32":
         return (jax.lax.psum_scatter(gpad, axis_name, tiled=True),
                 jnp.zeros_like(gpad))
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     chunks = gpad.reshape(n, -1)
     reduced, sent = a2a_reduce(chunks, axis_name, comm, block=block)
     return reduced, (chunks - sent).reshape(gpad.shape)

@@ -34,10 +34,8 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-
 import jax.numpy as jnp
 
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
 from minips_tpu.ops.flash_attention import _expand_kv
 from minips_tpu.parallel.mesh import DATA_AXIS
 from minips_tpu.parallel.ring_attention import reference_attention
@@ -65,7 +63,7 @@ def a2a_attention_local(
     kwargs are dropped). Default inner is the f32 reference; pass
     ``ops.flash_attention.flash_attention`` for full fused-kernel rate.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     H, Hk = q.shape[2], k.shape[2]
     if H % n:
         raise ValueError(

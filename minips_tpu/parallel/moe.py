@@ -25,9 +25,7 @@ one device.
 from __future__ import annotations
 
 import jax
-
 import jax.numpy as jnp
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
 
 
 def init_moe(key, num_experts: int, dim: int, hidden: int):
@@ -119,7 +117,7 @@ def moe_apply_local(params_local, x_local, *, axis_name: str,
 
     Like the other parallel schedules, take grads OUTSIDE the shard_map.
     """
-    k = _axis_size(axis_name)
+    k = jax.lax.axis_size(axis_name)
     E = params_local["router"].shape[1]
     e_local = params_local["w_in"].shape[0]
     if e_local * k != E:

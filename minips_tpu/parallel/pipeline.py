@@ -22,10 +22,7 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-
 import jax.numpy as jnp
-from minips_tpu.utils import jaxcompat
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
 
 
 def gpipe(
@@ -42,7 +39,7 @@ def gpipe(
     last stage's outputs are collected and broadcast, so the return value
     [M, ...] is valid on every device (replicated).
     """
-    k = _axis_size(axis_name)
+    k = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = x_microbatches.shape[0]
     # stage i sends to stage i+1; the wrap edge (k-1 -> 0) carries values
@@ -50,10 +47,10 @@ def gpipe(
     perm = [(i, (i + 1) % k) for i in range(k)]
     # fresh zeros are axis-invariant; the scan carry becomes varying after
     # one tick, so pre-cast both (shard_map VMA tracking)
-    out0 = jaxcompat.pcast(jnp.zeros_like(x_microbatches), axis_name,
-                           to="varying")
-    buf0 = jaxcompat.pcast(jnp.zeros_like(x_microbatches[0]), axis_name,
-                           to="varying")
+    out0 = jax.lax.pcast(jnp.zeros_like(x_microbatches), axis_name,
+                         to="varying")
+    buf0 = jax.lax.pcast(jnp.zeros_like(x_microbatches[0]), axis_name,
+                         to="varying")
 
     def tick(carry, t):
         buf_in, outputs = carry

@@ -29,19 +29,16 @@ import functools
 from typing import Optional
 
 import jax
-
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
 from minips_tpu.parallel.mesh import DATA_AXIS
 # GQA head expansion shared with the kernel module (ONE implementation of
 # the repeat + divisibility check). NOTE: under ring attention the repeat
 # happens AFTER each shard arrives, so the ppermute wire still carries
 # only the small kv heads.
 from minips_tpu.ops.flash_attention import _expand_kv
-from minips_tpu.utils import jaxcompat
 
 _NEG_INF = -1e30  # mask value; avoids -inf NaNs in (m - m_new) when a whole
                   # row is masked at an early ring step
@@ -91,7 +88,7 @@ def ring_attention_local(
     H, D] attention output, exactly equal to softmax(QK^T)V over the full
     gathered sequence.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     r = jax.lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -132,11 +129,11 @@ def ring_attention_local(
     o = jnp.zeros(q.shape, jnp.float32)
     # fresh arrays are axis-invariant; mark them varying over the ring axis
     # so the fori_loop carry type stays fixed (shard_map VMA tracking)
-    m = jaxcompat.pcast(jnp.full((B, Tq, H), _NEG_INF, jnp.float32),
-                        axis_name, to="varying")
-    l = jaxcompat.pcast(jnp.zeros((B, Tq, H), jnp.float32),
-                        axis_name, to="varying")
-    o = jaxcompat.pcast(o, axis_name, to="varying")
+    m = jax.lax.pcast(jnp.full((B, Tq, H), _NEG_INF, jnp.float32),
+                      axis_name, to="varying")
+    l = jax.lax.pcast(jnp.zeros((B, Tq, H), jnp.float32),
+                      axis_name, to="varying")
+    o = jax.lax.pcast(o, axis_name, to="varying")
 
     o, m, l, _, _ = jax.lax.fori_loop(0, n, body, (o, m, l, k, v))
 
@@ -158,7 +155,7 @@ def make_ring_attention(
     def attn(q, k, v):
         f = functools.partial(ring_attention_local, axis_name=axis_name,
                               causal=causal, scale=scale)
-        return jaxcompat.shard_map(
+        return jax.shard_map(
             f, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
 
     def sharded(x):

@@ -38,7 +38,6 @@ from minips_tpu.parallel.mesh import DATA_AXIS, padded_size
 from minips_tpu.parallel.partition import RangePartitioner
 from minips_tpu.tables.updaters import (Adam8bitState, LearningRate,
                                         make_updater, masked_merge_adam8)
-from minips_tpu.utils import jaxcompat
 
 PyTree = Any
 
@@ -112,8 +111,12 @@ class DenseTable:
 
         self._pspec = P(DATA_AXIS)
         self._sharding = NamedSharding(mesh, self._pspec)
-        padded_flat = jnp.zeros(self.padded, flat.dtype).at[: self.num_keys].set(flat)
-        self.params = jax.device_put(padded_flat, self._sharding)
+        # pad straight into the sharded layout: the padded copy never
+        # exists whole on one device (the template itself is the caller's)
+        self.params = jax.jit(
+            lambda f: jnp.zeros(self.padded, f.dtype)
+            .at[: self.num_keys].set(f),
+            out_shardings=self._sharding)(flat)
 
         opt_state = jax.eval_shape(self.tx.init, self.params)
         a8 = [x for x in jax.tree.leaves(
@@ -251,7 +254,7 @@ class DenseTable:
             return optax.apply_updates(p_shard, updates), new_opt
 
         return jax.jit(
-            jaxcompat.shard_map(apply_shard, mesh=self.mesh, in_specs=in_specs,
+            jax.shard_map(apply_shard, mesh=self.mesh, in_specs=in_specs,
                           out_specs=(self._pspec, self._opt_specs)),
             donate_argnums=(0, 1))
 
@@ -350,12 +353,12 @@ class DenseTable:
             # scan carry type fixed
             vma = frozenset()
             for leaf in jax.tree.leaves((params, batch)):
-                vma = vma | getattr(jaxcompat.typeof(leaf), "vma", frozenset())
+                vma = vma | jax.typeof(leaf).vma
             loss0, g0 = jnp.zeros((), jnp.float32), jnp.zeros(n)
             need = tuple(sorted(vma))
             if need:
-                loss0 = jaxcompat.pcast(loss0, need, to="varying")
-                g0 = jaxcompat.pcast(g0, need, to="varying")
+                loss0 = jax.lax.pcast(loss0, need, to="varying")
+                g0 = jax.lax.pcast(g0, need, to="varying")
             (loss_sum, gsum), _ = jax.lax.scan(fold, (loss0, g0), micro)
             if reduce == "sum":
                 # sum-semantics grad_fns: microbatch sums add up to the
@@ -382,7 +385,7 @@ class DenseTable:
             p_shard = optax.apply_updates(p_shard, updates)
             return p_shard, opt_shard, jax.lax.pmean(loss, DATA_AXIS)
 
-        step = jaxcompat.shard_map(
+        step = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(self._pspec, self._opt_specs, bspec),

@@ -170,22 +170,30 @@ class SparseTable:
             and n_dev == 1 and _pk.backend_supported())
 
         self._sharding = NamedSharding(mesh, P(DATA_AXIS, None))
+        shape = (self.num_slots, self.dim)
+
+        def build(fn, sharding=self._sharding):
+            # initial values are BUILT in the sharded layout: each device
+            # fills only its own row range, so a table sized to the mesh
+            # never has to fit on device 0 first (same values as the
+            # unsharded draw — threefry is partitionable)
+            return jax.jit(fn, out_shardings=sharding)()
+
         key = jax.random.PRNGKey(seed)
-        emb = jax.random.normal(key, (self.num_slots, self.dim), dtype) * init_scale
-        self.emb = jax.device_put(emb, self._sharding)
+        # the scale multiplies OUTSIDE the jitted draw (still sharded):
+        # fused, XLA folds it into normal()'s own constant and the values
+        # move by an ulp against every checkpoint and oracle drawn before
+        self.emb = build(
+            lambda: jax.random.normal(key, shape, dtype)) * init_scale
         self.accum = None
         self.m = self.v = self.steps = None
         if updater == "adagrad":
-            self.accum = jax.device_put(
-                jnp.full((self.num_slots, self.dim), adagrad_init, dtype),
-                self._sharding,
-            )
+            self.accum = build(lambda: jnp.full(shape, adagrad_init, dtype))
         elif updater == "adam":  # row-wise LAZY adam: moments + per-row t
-            zeros = jnp.zeros((self.num_slots, self.dim), dtype)
-            self.m = jax.device_put(zeros, self._sharding)
-            self.v = jax.device_put(zeros, self._sharding)
-            self.steps = jax.device_put(
-                jnp.zeros((self.num_slots,), jnp.int32),
+            self.m = build(lambda: jnp.zeros(shape, dtype))
+            self.v = build(lambda: jnp.zeros(shape, dtype))
+            self.steps = build(
+                lambda: jnp.zeros((self.num_slots,), jnp.int32),
                 NamedSharding(mesh, P(DATA_AXIS)))
 
     # --------------------------------------------------- unified opt state

@@ -255,7 +255,6 @@ class MeshTable:
 
         from minips_tpu.ops.quantized_comm import (
             quantized_psum_scatter, quantized_psum_scatter_ef)
-        from minips_tpu.utils import jaxcompat
 
         dim = self.dim
         lr = np.float32(self.lr)
@@ -406,7 +405,7 @@ class MeshTable:
         # check_vma/check_rep off: the all-gathered output is replicated
         # by construction, but older checkers cannot infer it through
         # the quantized a2a path
-        mapped = jaxcompat.shard_map(
+        mapped = jax.shard_map(
             body, mesh=self.plane.mesh, in_specs=(S,) * n_in,
             out_specs=((S,) * n_state, ((P(), S) if ef else P())),
             check_vma=False)
@@ -1188,7 +1187,6 @@ class MeshAggregator:
 
         from minips_tpu.ops.quantized_comm import \
             quantized_psum_scatter_ef
-        from minips_tpu.utils import jaxcompat
 
         padded, dim = self.padded, self.dim
         comm = "int8" if self.comm == "blk8" else "float32"
@@ -1208,7 +1206,7 @@ class MeshAggregator:
                 dense.reshape(-1), MESH_AXIS, comm=comm, block=block)
             return red.reshape(-1, dim), resid.reshape(padded, dim)[None]
 
-        mapped = jaxcompat.shard_map(
+        mapped = jax.shard_map(
             body, mesh=self.mesh, in_specs=(S, S), out_specs=(S, S),
             check_vma=False)
         return jax.jit(mapped)

@@ -177,6 +177,12 @@ class PSTrainStep:
         self._restore_state(new_state)
         return loss
 
+    def lower(self, batch):
+        """The fused step lowered against the tables' live state — the
+        program ``__call__`` runs, for inspection (collectives, kernels,
+        memory) without executing it."""
+        return self._jit_step.lower(self._collect_state(), batch)
+
     def shard_batch(self, batch: PyTree) -> PyTree:
         """device_put batch leaves sharded along the data axis (axis 0)."""
         sharding = NamedSharding(self._mesh, P(DATA_AXIS))

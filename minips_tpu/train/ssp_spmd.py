@@ -44,18 +44,15 @@ import time
 from typing import Any, Callable, Optional
 
 import jax
-
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from minips_tpu.utils.jaxcompat import axis_size as _axis_size
 from minips_tpu.comm.bus import ClockGossip
 from minips_tpu.consistency.gate import StalenessGate, publish_clock
 from minips_tpu.parallel.mesh import DATA_AXIS
 from minips_tpu.tables.dense import DenseTable
-from minips_tpu.utils import jaxcompat
 
 __all__ = ["CollectiveSSP", "SyncPlane", "make_control"]
 
@@ -100,7 +97,7 @@ class SyncPlane:
         def merge(block):             # [1, length/L] on each device
             return jax.lax.psum(block, "proc")
 
-        self._merge = jax.jit(jaxcompat.shard_map(
+        self._merge = jax.jit(jax.shard_map(
             merge, mesh=self.mesh,
             in_specs=P("proc", "local"), out_specs=P(None, "local")))
         self._mean_cache: dict = {}
@@ -166,7 +163,7 @@ class SyncPlane:
                                                    gather_broadcast)
 
         def merge_q(block):            # [1, Lb] on each device
-            n = _axis_size("proc")
+            n = jax.lax.axis_size("proc")
             v = block.reshape(n, -1)   # my row split into per-proc chunks
             c = v.shape[1]
             mine, sent = a2a_reduce(v, "proc", comm)
@@ -184,7 +181,7 @@ class SyncPlane:
         # dequantizes identically), but the varying-axis checker cannot
         # infer replication through all_gather the way it can through
         # psum
-        fn = jax.jit(jaxcompat.shard_map(
+        fn = jax.jit(jax.shard_map(
             merge_q, mesh=self.mesh, in_specs=P("proc", "local"),
             out_specs=(P(None, "local"), P("proc", "local"),
                        P("proc", "local")),

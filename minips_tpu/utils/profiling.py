@@ -19,24 +19,14 @@ from typing import Iterator, Optional
 @contextlib.contextmanager
 def profile_trace(log_dir: str) -> Iterator[None]:
     """Capture a jax.profiler trace into ``log_dir`` (view with
-    TensorBoard's profile plugin). Falls back to a no-op if the profiler
-    is unavailable on the backend."""
+    TensorBoard's profile plugin, or read the ``.xplane.pb`` with
+    ``jax.profiler.ProfileData``). Whoever calls this asked for a trace: a
+    profiler that cannot start or stop raises."""
     import jax
 
     os.makedirs(log_dir, exist_ok=True)
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception:  # pragma: no cover - profiler unsupported
-        started = False
-    try:
+    with jax.profiler.trace(log_dir):
         yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # pragma: no cover
-                pass
 
 
 class StepWindowProfiler:

@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from minips_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from minips_tpu.models import transformer as tfm
 from minips_tpu.parallel.a2a_attention import a2a_attention_local
 from minips_tpu.parallel.ring_attention import reference_attention

@@ -75,9 +75,8 @@ def test_sharded_ps_bench_worker_standalone():
 
 def test_sharded_ps_bench_worker_jit_compute():
     """--compute jit (the ps_tpu suite's worker): a real jitted MLP grad
-    runs on the pulled rows between pull and push. Forced-CPU here (the
-    chip leg engages only when the bench's probe says it is alive); the
-    result must label the backend and still count rows/wire."""
+    runs on the pulled rows between pull and push. Forced-CPU here; the
+    result must label the backend it ran on and still count rows/wire."""
     proc = subprocess.run(
         [sys.executable, "-m", "minips_tpu.apps.sharded_ps_bench",
          "--path", "sparse", "--iters", "8", "--warmup", "2",
@@ -111,37 +110,31 @@ def test_sharded_ps_bench_floor_two_processes():
         assert r["wire_push_bytes_per_sec"] > 0  # wire actually engaged
 
 
-def test_tpu_probe_sentinel_classification(monkeypatch):
-    """ADVICE r4 low: the probe's permanent-vs-retryable call keys on
-    sentinels the probe SUBPROCESS emits, not on parsing jax's stderr in
-    the parent with a wall-clock bound. Absent platform → permanent;
-    init failure, crash, or hang → retryable."""
+@pytest.mark.parametrize("suite", ["lrmlp", "all"])
+def test_bench_without_a_tpu_exits_at_once(suite):
+    """No probe, no fallback: a chip suite that finds no TPU exits 3 with
+    a message and prints no result — and ``--suite all`` stops at the
+    first child that says so."""
+    proc = subprocess.run(
+        [sys.executable, "bench.py", "--suite", suite],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 3, proc.stderr[-2000:]
+    assert "no TPU" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+def test_unknown_device_kind_has_no_peak():
     import types
 
     sys.path.insert(0, REPO)
     import bench
 
-    def fake(stdout, rc):
-        def run(cmd, timeout=None, capture_output=None, text=None):
-            return types.SimpleNamespace(returncode=rc, stdout=stdout,
-                                         stderr="")
-        return run
-
-    monkeypatch.setattr("subprocess.run", fake("MINIPS_PROBE_OK\n", 0))
-    assert bench._tpu_responsive(5) == (True, False)
-    monkeypatch.setattr("subprocess.run", fake("MINIPS_PROBE_NO_TPU\n", 3))
-    assert bench._tpu_responsive(5) == (False, True)
-    monkeypatch.setattr("subprocess.run",
-                        fake("MINIPS_PROBE_INIT_FAILED\n", 3))
-    assert bench._tpu_responsive(5) == (False, False)
-    monkeypatch.setattr("subprocess.run", fake("", 1))  # raw crash
-    assert bench._tpu_responsive(5) == (False, False)
-
-    def hang(cmd, timeout=None, capture_output=None, text=None):
-        raise subprocess.TimeoutExpired(cmd, timeout)
-
-    monkeypatch.setattr("subprocess.run", hang)
-    assert bench._tpu_responsive(5) == (False, False)
+    assert bench._peak_for(types.SimpleNamespace(
+        device_kind="TPU v5 lite")) == 197e12
+    with pytest.raises(SystemExit, match="TPU v5 lite chip"):
+        bench._peak_for(types.SimpleNamespace(
+            device_kind="TPU v5 lite chip"))  # no prefix match
 
 
 def test_ssp_schedule_simulation_invariants():

@@ -21,6 +21,19 @@ def test_init_pull_roundtrip(mesh8):
     np.testing.assert_allclose(np.asarray(pulled["w"]), 0.0)
 
 
+def test_sharded_init_keeps_the_template_values(mesh8):
+    """The padded vector is built in the sharded layout; its values are
+    the raveled template's, bitwise, and the padding is zeros."""
+    tmpl = {"w": jax.random.normal(jax.random.PRNGKey(2), (3, 4)),
+            "b": jnp.arange(5.0)}
+    t = DenseTable(tmpl, mesh8, updater="adam")
+    flat = np.concatenate([np.asarray(tmpl["b"]),
+                           np.asarray(tmpl["w"]).ravel()])  # sorted keys
+    np.testing.assert_array_equal(np.asarray(t.params)[:17], flat)
+    np.testing.assert_array_equal(np.asarray(t.params)[17:], 0.0)
+    assert {s.data.shape for s in t.params.addressable_shards} == {(3,)}
+
+
 def test_push_sgd_matches_oracle(mesh8):
     t = DenseTable(_template(), mesh8, updater="sgd", lr=0.5)
     grads = {"w": jnp.ones((3, 4)) * 2.0, "b": jnp.arange(5.0)}

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from minips_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from minips_tpu.ops.flash_attention import (blockwise_attention,
                                             flash_attention,
                                             kernel_supported)
@@ -129,6 +129,25 @@ def test_unsupported_shapes_fall_back():
     out = flash_attention(q, k, v, causal=True)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+
+
+def test_on_tpu_the_kernel_runs_or_raises(monkeypatch):
+    """With the backend reported as tpu, a shape the kernels refuse is an
+    error naming the shape — never the scan under the kernel's name."""
+    import jax.sharding as shd
+
+    from minips_tpu.ops.flash_attention import ring_flash_attention_local
+    from minips_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q, k, v = _qkv(T=48, D=12)  # D % 8 != 0 -> no kernel
+    with pytest.raises(ValueError, match=r"q\(2, 48, 2, 12\)"):
+        flash_attention(q, k, v, causal=True)
+    spec = shd.PartitionSpec(None, "data")
+    with pytest.raises(ValueError, match=r"refuse q\(2, 6, 2, 12\)"):
+        shard_map(lambda q_, k_, v_: ring_flash_attention_local(
+            q_, k_, v_, axis_name="data", causal=True),
+            mesh=make_mesh(8), in_specs=(spec,) * 3, out_specs=spec)(q, k, v)
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -13,28 +13,10 @@ import sys
 
 from tests.conftest import load_jax_compat_manifest
 
-# the byte-identical failure set every Tier-1 run since seed carried
-# (CHANGES.md PR1-PR5: "failure set identical, 146 pre-existing
-# jax-version failures") — the manifest may never grow past it. PR7
-# fixed 63 for real (the utils/jaxcompat.py shard_map/typeof shims:
-# checkpoint, cssp, dense-table, ssp_spmd, engine, mnist, transformer,
-# flash-attention, apps); PR12's pcast shim (identity on pre-vma jax)
-# fixed 15 more (ring_attention, gpipe, ring-flash); PR14 registered
-# the standard shard_map replication rules for the `name` primitive
-# (checkpoint_name is an identity marker — the old check_rep tracer
-# just lacked the rule the vma tracer ships built in), fixing 23 more
-# (a2a, pipeline, tensor-parallel, transformer remat/rope/gqa, lm
-# apps); PR 15's `jaxcompat.sds` shim (ShapeDtypeStruct's vma= kwarg
-# dropped on pre-vma jax — the same identity argument as pcast: the
-# old tracer carries no varying-axis types for the annotation to
-# change) fixed 15 more flash-kernel entries; PR 17 fixed the
-# ring-flash SPMD PartitionId compile drift for real (causal=False
-# left the axis_index-derived offsets dead inside the kernel, so the
-# lowered partition-id had no dataflow path to a manual-sharded
-# operand and sharding propagation could not mark it {manual} — the
-# ring now mints axis_index only when masking consumes it) — the
-# ceiling only moves down. The 2 left are deeper remat/compose
-# mismatches.
+# the manifest may never grow past the count below; it only moves
+# down. The version shims that emptied most of it are gone (the code
+# now calls jax.shard_map / jax.lax.pcast / jax.typeof directly); the 2
+# entries left XPASS on the installed jax (ROADMAP D0 removes them).
 SEED_FAILURE_COUNT = 2
 
 

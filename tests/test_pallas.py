@@ -34,28 +34,28 @@ def test_gather_repeated_and_boundary_rows(rng):
                                np.asarray(emb)[np.asarray(slots)], rtol=1e-6)
 
 
-def test_unsupported_shapes_fall_back(rng):
-    # D=8 (not lane-aligned) and N=7 (not chunk-aligned) take the XLA path
+def test_unsupported_shapes_raise(rng):
+    # D=8 (not lane-aligned) and N=7 (not chunk-aligned): whoever calls
+    # gather_rows asked for the kernel, so it refuses instead of quietly
+    # returning XLA's gather
     emb = jnp.asarray(rng.normal(size=(64, 8)), jnp.float32)
     slots = jnp.asarray(rng.integers(0, 64, 7), jnp.int32)
     assert not pk.gather_supported(8, 56)    # lane-misaligned dim
     assert not pk.gather_supported(128, 7)   # chunk-misaligned n
-    out = pk.gather_rows(emb, slots)  # must not raise
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(emb)[np.asarray(slots)], rtol=1e-6)
+    with pytest.raises(ValueError, match="dim=8, n=7"):
+        pk.gather_rows(emb, slots, interpret=True)
 
 
-def test_supported_shapes_fall_back_off_tpu(rng):
+def test_compiled_kernel_refuses_off_tpu(rng):
     # aligned shapes (D=128, N=64) with interpret=False: on this CPU test
-    # session the compiled pltpu kernel can't lower, so gather_rows must
-    # take the XLA path instead of crashing in Mosaic
+    # session the compiled pltpu kernel can't lower — a named error, not
+    # the XLA path under the kernel's name
     assert pk.gather_supported(128, 64)
     assert not pk.backend_supported()
     emb = jnp.asarray(rng.normal(size=(256, 128)), jnp.float32)
     slots = jnp.asarray(rng.integers(0, 256, 64), jnp.int32)
-    out = pk.gather_rows(emb, slots)  # must not raise
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(emb)[np.asarray(slots)], rtol=1e-6)
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        pk.gather_rows(emb, slots)
 
 
 def test_opt_in_is_off_by_default_and_off_tpu(monkeypatch):

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from minips_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from minips_tpu.models import transformer as tfm
 from minips_tpu.parallel.mesh import make_mesh
 from minips_tpu.parallel.pipeline import gpipe, stack_layers, unstack_layers

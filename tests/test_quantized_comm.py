@@ -17,7 +17,7 @@ from minips_tpu.tables.dense import DenseTable
 
 
 def _run(mesh, fn, *xs):
-    from minips_tpu.utils.jaxcompat import shard_map
+    from jax import shard_map
 
     return jax.jit(shard_map(
         fn, mesh=mesh, in_specs=(P("data"),) * len(xs),

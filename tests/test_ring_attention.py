@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from minips_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from minips_tpu.parallel.ring_attention import (
     make_ring_attention,
     reference_attention,

@@ -17,6 +17,25 @@ def test_hash_range_and_determinism():
     assert len(np.unique(s)) > 900
 
 
+@pytest.mark.parametrize("updater", ["adagrad", "adam"])
+def test_sharded_init_keeps_the_seeded_values(mesh8, updater):
+    """Tables are BUILT in the sharded layout; a given seed still draws
+    the values the unsharded draw on one device gave (bitwise)."""
+    import jax
+
+    t = SparseTable(1 << 10, 8, mesh8, updater=updater, init_scale=0.01,
+                    adagrad_init=0.1, seed=5)
+    want = jax.random.normal(jax.random.PRNGKey(5), (1 << 10, 8),
+                             jnp.float32) * 0.01
+    np.testing.assert_array_equal(np.asarray(t.emb), np.asarray(want))
+    assert {s.data.shape for s in t.emb.addressable_shards} == {(128, 8)}
+    for leaf in t.opt_state():
+        assert len(leaf.sharding.device_set) == 8
+        fill = 0.1 if updater == "adagrad" else 0
+        np.testing.assert_array_equal(np.asarray(leaf),
+                                      np.full(leaf.shape, fill, leaf.dtype))
+
+
 def test_pull_shape(mesh8):
     t = SparseTable(256, 8, mesh8)
     rows = t.pull(jnp.arange(12))

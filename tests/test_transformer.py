@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from minips_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from minips_tpu.models import transformer as tfm
 
 CFG = dict(vocab=61, dim=32, heads=4, depth=2, max_len=128)
