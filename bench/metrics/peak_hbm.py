@@ -1,0 +1,5 @@
+"""``peak_bytes_in_use`` of the fullest device after the window, in GB."""
+
+
+def read(run):
+    return run.memory_peak_bytes / 1e9 if run.memory_peak_bytes else None
