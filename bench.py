@@ -950,12 +950,12 @@ def main() -> int:
     if _PROFILE_DIR and suites:
         import os
 
-        from minips_tpu.utils.trace_analysis import (latest_trace_file,
+        from minips_tpu.utils.trace_analysis import (latest_xplane,
                                                      summarize)
         # one suite per invocation when profiling; the table lands on it.
         # Freshness-gate: a pre-existing trace in a reused dir must not
         # be misattributed to this run as its profile.
-        newest = latest_trace_file(_PROFILE_DIR)
+        newest = latest_xplane(_PROFILE_DIR)
         if newest is not None and os.path.getmtime(newest) >= profile_t0:
             prof = summarize(_PROFILE_DIR, top=12)
         else:

@@ -44,6 +44,7 @@ from minips_tpu.models import transformer as tfm
 from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
 from minips_tpu.tables.dense import DenseTable
 from minips_tpu.train.loop import TrainLoop
+from minips_tpu.utils import profiling as prof
 
 DEFAULT = Config(
     table=TableConfig(name="lm", kind="dense", updater="adam", lr=3e-3),
@@ -321,6 +322,7 @@ def run(cfg: Config, args, metrics) -> dict:
         drop_key = jax.random.PRNGKey(cfg.train.seed + 71)
         n_prepped = [start_step]
 
+        @prof.span(prof.FEED)
         def prep(batch):
             out = {"tokens": jax.device_put(
                 jnp.asarray(batch["tokens"]), batch_sharding)}
@@ -345,6 +347,7 @@ def run(cfg: Config, args, metrics) -> dict:
                                compute_dtype=compute_dtype, comm=comm)
         seq_sharding = NamedSharding(mesh, P(None, DATA_AXIS))
 
+        @prof.span(prof.FEED)
         def prep(batch):
             t = jnp.asarray(batch["tokens"])
             return {"inp": jax.device_put(t[:, :-1], seq_sharding),

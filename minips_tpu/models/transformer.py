@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from minips_tpu.parallel.mesh import DATA_AXIS
+from minips_tpu.utils import profiling as prof
 from minips_tpu.parallel.ring_attention import (
     reference_attention,
     ring_attention_local,
@@ -123,39 +124,40 @@ def _block(h, blk, heads, attn_fn, compute_dtype, psum_axis=None,
     tp = 1 if psum_axis is None else jax.lax.axis_size(psum_axis)
     local_heads = heads // tp
     from jax.ad_checkpoint import checkpoint_name
-    x = _ln(h, blk["ln1"]).astype(compute_dtype)
-    # q/k/v stay in compute_dtype: the flash kernel runs its dots at the
-    # input dtype's MXU rate with f32 accumulation, so a bf16 run keeps
-    # bf16 VMEM/HBM traffic end-to-end (upcasting here doubled both and
-    # forced f32-rate attention matmuls)
-    if "wkv" in blk:
-        # grouped-query layout: full-width Q, narrow fused KV; the
-        # attention impls map q-head h onto kv head h // g themselves
-        q = x @ blk["wq"].astype(compute_dtype)
-        kv = jnp.einsum("btd,dce->btce", x,
-                        blk["wkv"].astype(compute_dtype))
-        # same checkpoint names as the fused layout, so every remat
-        # policy ("hybrid_qkv" saves the projections) works unchanged
-        q = checkpoint_name(q, "qkv")
-        kv = checkpoint_name(kv, "qkv")
-        hd = q.shape[-1] // local_heads
-        local_kv = kv.shape[-1] // hd
-        q = q.reshape(B, T, local_heads, hd)
-        k = kv[:, :, 0].reshape(B, T, local_kv, hd)
-        v = kv[:, :, 1].reshape(B, T, local_kv, hd)
-    else:
-        qkv = jnp.einsum("btd,dce->btce", x,
-                         blk["qkv"].astype(compute_dtype))
-        # named so "hybrid_qkv" can save it — with qkv, attn_out and
-        # mlp_hidden all resident, backward recomputes only the attention
-        # output projection (2 of 24 D^2-units per block)
-        qkv = checkpoint_name(qkv, "qkv")
-        q, k, v = (qkv[:, :, i] for i in range(3))
-        hd = q.shape[-1] // local_heads
-        q = q.reshape(B, T, local_heads, hd)
-        k = k.reshape(B, T, local_heads, hd)
-        v = v.reshape(B, T, local_heads, hd)
-    a = attn_fn(q, k, v).reshape(B, T, -1)
+    with jax.named_scope(prof.LM_ATTN):
+        x = _ln(h, blk["ln1"]).astype(compute_dtype)
+        # q/k/v stay in compute_dtype: the flash kernel runs its dots at
+        # the input dtype's MXU rate with f32 accumulation, so a bf16 run
+        # keeps bf16 VMEM/HBM traffic end-to-end (upcasting here doubled
+        # both and forced f32-rate attention matmuls)
+        if "wkv" in blk:
+            # grouped-query layout: full-width Q, narrow fused KV; the
+            # attention impls map q-head h onto kv head h // g themselves
+            q = x @ blk["wq"].astype(compute_dtype)
+            kv = jnp.einsum("btd,dce->btce", x,
+                            blk["wkv"].astype(compute_dtype))
+            # same checkpoint names as the fused layout, so every remat
+            # policy ("hybrid_qkv" saves the projections) works unchanged
+            q = checkpoint_name(q, "qkv")
+            kv = checkpoint_name(kv, "qkv")
+            hd = q.shape[-1] // local_heads
+            local_kv = kv.shape[-1] // hd
+            q = q.reshape(B, T, local_heads, hd)
+            k = kv[:, :, 0].reshape(B, T, local_kv, hd)
+            v = kv[:, :, 1].reshape(B, T, local_kv, hd)
+        else:
+            qkv = jnp.einsum("btd,dce->btce", x,
+                             blk["qkv"].astype(compute_dtype))
+            # named so "hybrid_qkv" can save it — with qkv, attn_out and
+            # mlp_hidden all resident, backward recomputes only the
+            # attention output projection (2 of 24 D^2-units per block)
+            qkv = checkpoint_name(qkv, "qkv")
+            q, k, v = (qkv[:, :, i] for i in range(3))
+            hd = q.shape[-1] // local_heads
+            q = q.reshape(B, T, local_heads, hd)
+            k = k.reshape(B, T, local_heads, hd)
+            v = v.reshape(B, T, local_heads, hd)
+        a = attn_fn(q, k, v).reshape(B, T, -1)
     return _block_tail(h, blk, a, compute_dtype, psum_axis, ffn_fn,
                        dropout, rng)
 
@@ -173,34 +175,37 @@ def _block_tail(h, blk, a, compute_dtype, psum_axis=None, ffn_fn=None,
     # so the backward never re-runs the attention itself (the priciest
     # recompute per byte: flash kernels + T^2 math) while everything else
     # still recomputes
-    a = checkpoint_name(a, "attn_out")
-    att = (a.astype(compute_dtype)
-           @ blk["proj"].astype(compute_dtype)).astype(jnp.float32)
-    if psum_axis is not None:
-        att = jax.lax.psum(att, psum_axis)
-    if dropout and rng is not None:   # GPT-style residual dropout
-        att = _dropout(att, dropout, jax.random.fold_in(rng, 0))
-    h = h + att
-    if ffn_fn is not None:
-        D = h.shape[-1]
-        y, aux = ffn_fn(blk, _ln(h, blk["ln2"]).reshape(B * T, D))
-        return h + y.reshape(B, T, D), aux
-    x = _ln(h, blk["ln2"]).astype(compute_dtype)
-    z = x @ blk["mlp_in"].astype(compute_dtype)
-    # the [B*T, 4D] PRE-gelu tensor is the bulk of a block's activation
-    # memory; the "hybrid" policies save it (with attn_out) so backward
-    # skips the expensive up-projection recompute while still shedding
-    # the dots-policy tensors that blow HBM at batch 32. It must be the
-    # pre-activation: gelu's VJP reads its input, so saving gelu(z)
-    # would force the up-projection to be recomputed anyway.
-    z = checkpoint_name(z, "mlp_hidden")
-    x = jax.nn.gelu(z)
-    m = (x @ blk["mlp_out"].astype(compute_dtype)).astype(jnp.float32)
-    if psum_axis is not None:
-        m = jax.lax.psum(m, psum_axis)
-    if dropout and rng is not None:
-        m = _dropout(m, dropout, jax.random.fold_in(rng, 1))
-    return h + m, 0.0
+    with jax.named_scope(prof.LM_ATTN):
+        a = checkpoint_name(a, "attn_out")
+        att = (a.astype(compute_dtype)
+               @ blk["proj"].astype(compute_dtype)).astype(jnp.float32)
+        if psum_axis is not None:
+            att = jax.lax.psum(att, psum_axis)
+        if dropout and rng is not None:   # GPT-style residual dropout
+            att = _dropout(att, dropout, jax.random.fold_in(rng, 0))
+        h = h + att
+    with jax.named_scope(prof.LM_MLP):
+        if ffn_fn is not None:
+            D = h.shape[-1]
+            y, aux = ffn_fn(blk, _ln(h, blk["ln2"]).reshape(B * T, D))
+            return h + y.reshape(B, T, D), aux
+        x = _ln(h, blk["ln2"]).astype(compute_dtype)
+        z = x @ blk["mlp_in"].astype(compute_dtype)
+        # the [B*T, 4D] PRE-gelu tensor is the bulk of a block's
+        # activation memory; the "hybrid" policies save it (with attn_out)
+        # so backward skips the expensive up-projection recompute while
+        # still shedding the dots-policy tensors that blow HBM at batch
+        # 32. It must be the pre-activation: gelu's VJP reads its input,
+        # so saving gelu(z) would force the up-projection to be
+        # recomputed anyway.
+        z = checkpoint_name(z, "mlp_hidden")
+        x = jax.nn.gelu(z)
+        m = (x @ blk["mlp_out"].astype(compute_dtype)).astype(jnp.float32)
+        if psum_axis is not None:
+            m = jax.lax.psum(m, psum_axis)
+        if dropout and rng is not None:
+            m = _dropout(m, dropout, jax.random.fold_in(rng, 1))
+        return h + m, 0.0
 
 
 def _forward(params, tokens, pos, heads, attn_fn, compute_dtype,
@@ -219,11 +224,13 @@ def _forward(params, tokens, pos, heads, attn_fn, compute_dtype,
         if pos.shape[0] > max_len:
             raise ValueError(f"sequence length {pos.shape[0]} exceeds the "
                              f"model's max_len {max_len}")
-        h = params["tok_emb"][tokens] + params["pos_emb"][pos]
+        with jax.named_scope(prof.LM_EMBED):
+            h = params["tok_emb"][tokens] + params["pos_emb"][pos]
     else:
         # rope model: positions enter through the attention rotation
         # (below); no table, no sequence-length cap
-        h = params["tok_emb"][tokens]
+        with jax.named_scope(prof.LM_EMBED):
+            h = params["tok_emb"][tokens]
         if attn_fn is not None:
             attn_fn = _rope_wrap(attn_fn, pos)
     if not 0.0 <= dropout < 1.0:
@@ -259,12 +266,14 @@ def _forward(params, tokens, pos, heads, attn_fn, compute_dtype,
             h, aux = block_fn(h, blk, heads, attn_fn, compute_dtype,
                               psum_axis, ffn_fn, dropout, blk_rng)
             aux_total = aux_total + aux
-    h = _ln(h, params["ln_f"])
-    if not head:  # chunked-CE path applies the tied head itself
-        return h, aux_total
-    # weight-tied head
-    logits = (h.astype(compute_dtype)
-              @ params["tok_emb"].T.astype(compute_dtype)).astype(jnp.float32)
+    with jax.named_scope(prof.LM_HEAD):
+        h = _ln(h, params["ln_f"])
+        if not head:  # chunked-CE path applies the tied head itself
+            return h, aux_total
+        # weight-tied head
+        logits = (h.astype(compute_dtype)
+                  @ params["tok_emb"].T.astype(compute_dtype)
+                  ).astype(jnp.float32)
     return logits, aux_total
 
 
@@ -636,6 +645,7 @@ def ep_lm_specs(params, axis_name=DATA_AXIS):
     }
 
 
+@jax.named_scope(prof.LM_HEAD)
 def nll(logits, targets):
     """Mean next-token negative log-likelihood — the one cross-entropy
     shared by every layout (full/sp/tp/pp)."""
@@ -644,6 +654,7 @@ def nll(logits, targets):
         -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0])
 
 
+@jax.named_scope(prof.LM_HEAD)
 def nll_chunked(h, tok_emb, targets, chunk, compute_dtype=jnp.bfloat16):
     """Tied-head projection + cross-entropy, scanned over sequence chunks
     so the full ``[B, T, vocab]`` f32 logits tensor NEVER exists — in the

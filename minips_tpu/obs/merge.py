@@ -27,8 +27,8 @@ Three jobs:
    pair counts let the acceptance drill check one flow per remote
    owner.
 
-3. **XLA interleave** (``--xla <logdir>``): the newest
-   ``*.trace.json.gz`` the profiler wrote (utils/trace_analysis.py) is
+3. **XLA interleave** (``--xla <logdir>``): the newest Chrome trace
+   (``*.json.gz``) the profiler wrote beside its ``.xplane.pb`` is
    appended with its pids offset past the rank pids, so device compute
    and wire activity share one timeline. XLA traces carry their own
    epoch; they are shifted so their first event aligns with the first
@@ -141,13 +141,13 @@ def _link_flows(events: list[dict]) -> tuple[int, dict[str, int]]:
 
 
 def _load_xla(logdir: str, t_base_us: float) -> list[dict]:
-    from minips_tpu.utils.trace_analysis import latest_trace_file
-
     import gzip
 
-    path = latest_trace_file(logdir)
-    if path is None:
+    hits = glob.glob(os.path.join(logdir, "**", "*.json.gz"),
+                     recursive=True)
+    if not hits:
         return []
+    path = max(hits, key=os.path.getmtime)
     with gzip.open(path, "rt") as f:
         doc = json.load(f)
     events = doc.get("traceEvents", [])
@@ -215,9 +215,9 @@ def main(argv: Optional[list[str]] = None) -> int:
                     help="merged output (default: "
                          "<first dir>/merged_trace.json)")
     ap.add_argument("--xla", default=None, metavar="LOGDIR",
-                    help="interleave the newest *.trace.json.gz under "
-                         "LOGDIR (profiler output) on the same "
-                         "timeline")
+                    help="interleave the newest Chrome trace "
+                         "(*.json.gz) under LOGDIR (profiler output) "
+                         "on the same timeline")
     args = ap.parse_args(argv)
     try:
         doc, summary = merge_traces(args.paths, xla_logdir=args.xla)

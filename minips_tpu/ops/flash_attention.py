@@ -47,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from minips_tpu.utils import profiling as prof
+
 _NEG_INF = -1e30  # finite mask value (matches ring_attention) — avoids
                   # -inf arithmetic NaNs on fully-masked rows
 
@@ -284,6 +286,7 @@ def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
             pltpu.VMEM((bq, 1), jnp.float32),   # normalizer l
         ],
         interpret=interpret,
+        name=prof.FLASH_FWD,
     )(*offs, qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -392,6 +395,7 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name=prof.FLASH_DQ,
     )(*offs, qt, kt, vt, dot, lse, dvec)
 
     # transposed grid: K outer, (group q-head, Q block) inner — grid dim 1
@@ -418,6 +422,7 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
+        name=prof.FLASH_DKV,
     )(*offs, qt, kt, vt, dot, lse, dvec)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))

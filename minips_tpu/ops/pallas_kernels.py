@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from minips_tpu.utils import profiling as prof
+
 _CHUNK = 8  # rows per grid step = output sublane tile
 
 
@@ -110,4 +112,5 @@ def gather_rows(emb: jnp.ndarray, slots: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, d), emb.dtype),
         interpret=interpret,
+        name=prof.GATHER_ROWS,
     )(slots, emb)

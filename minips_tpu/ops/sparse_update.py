@@ -16,7 +16,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from minips_tpu.utils import profiling as prof
 
+
+@jax.named_scope(prof.SPARSE_DEDUP)
 def dedup_segment_sum(slots: jnp.ndarray, grads: jnp.ndarray):
     """Merge duplicate slots. Returns (rep_slots [B], summed [B, D], valid
     [B]) where only the first k entries (k = number of unique slots) are
@@ -78,6 +81,7 @@ def row_adagrad(emb: jnp.ndarray, accum: jnp.ndarray, slots: jnp.ndarray,
     return _row_adagrad_sorted(emb, accum, slots, grads, lr, eps)
 
 
+@jax.named_scope(prof.SPARSE_ADAGRAD_DENSE)
 def _row_adagrad_dense(emb, accum, slots, grads, lr, eps):
     # Untouched rows need no masking: their scattered g is exactly 0, so
     # g2 = 0 leaves accum bitwise unchanged (accum >= 0, no -0.0 case) and
@@ -89,6 +93,7 @@ def _row_adagrad_dense(emb, accum, slots, grads, lr, eps):
     return emb - lr * g / (jnp.sqrt(new_accum) + eps), new_accum
 
 
+@jax.named_scope(prof.SPARSE_ADAGRAD_SORTED)
 def _row_adagrad_sorted(emb, accum, slots, grads, lr, eps):
     rep, g_sum, _ = dedup_segment_sum(slots, grads.astype(emb.dtype))
     g2 = g_sum * g_sum
@@ -123,6 +128,7 @@ def row_adam(emb: jnp.ndarray, m: jnp.ndarray, v: jnp.ndarray,
                             eps)
 
 
+@jax.named_scope(prof.SPARSE_ADAM_DENSE)
 def _row_adam_dense(emb, m, v, steps, slots, grads, lr, b1, b2, eps):
     flat = slots.reshape(-1)
     g = (jnp.zeros_like(emb)
@@ -139,6 +145,7 @@ def _row_adam_dense(emb, m, v, steps, slots, grads, lr, b1, b2, eps):
     return (emb - jnp.where(tcol, update, 0.0), m_new, v_new, steps_new)
 
 
+@jax.named_scope(prof.SPARSE_ADAM_SORTED)
 def _row_adam_sorted(emb, m, v, steps, slots, grads, lr, b1, b2, eps):
     rep, g_sum, valid = dedup_segment_sum(slots, grads.astype(emb.dtype))
     vcol = valid[:, None]

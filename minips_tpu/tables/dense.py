@@ -38,6 +38,7 @@ from minips_tpu.parallel.mesh import DATA_AXIS, padded_size
 from minips_tpu.parallel.partition import RangePartitioner
 from minips_tpu.tables.updaters import (Adam8bitState, LearningRate,
                                         make_updater, masked_merge_adam8)
+from minips_tpu.utils import profiling as prof
 
 PyTree = Any
 
@@ -61,6 +62,7 @@ def cast_floating(tree: PyTree, dtype) -> PyTree:
 class DenseTable:
     """A dense parameter table sharded across the mesh ``data`` axis."""
 
+    @prof.span(prof.TABLE_INIT)
     def __init__(
         self,
         template: PyTree,
@@ -318,13 +320,13 @@ class DenseTable:
             _check, quantized_all_gather, quantized_psum_scatter)
         _check(comm)  # eager: tracing happens on first step call
 
-        if compute_dtype is not None:
-            cd = jnp.dtype(compute_dtype)
+        cd = None if compute_dtype is None else jnp.dtype(compute_dtype)
+        if cd is not None:
             user_grad_fn = grad_fn
 
             def grad_fn(params, batch):  # noqa: F811 - deliberate wrap
-                loss, grads = user_grad_fn(cast_floating(params, cd),
-                                           cast_floating(batch, cd))
+                # params arrive cast already: the pull phase casts them
+                loss, grads = user_grad_fn(params, cast_floating(batch, cd))
                 return (loss.astype(jnp.float32),
                         cast_floating(grads, jnp.float32))
 
@@ -367,22 +369,27 @@ class DenseTable:
             return loss_sum / accum, gsum / accum
 
         def local_step(p_shard, opt_shard, batch):
-            full = quantized_all_gather(p_shard, DATA_AXIS, comm)      # pull
-            loss, gflat = _grads_flat(unravel(full[:n]), batch)
-            gpad = jnp.zeros(padded, gflat.dtype).at[:n].set(gflat)
-            g_shard = quantized_psum_scatter(gpad, DATA_AXIS, comm)    # push
-            if reduce == "mean":
-                g_shard = g_shard / num_workers
-            if clip_norm:
-                # global-norm clip across ALL shards (the optax transform
-                # would only see this shard's slice)
-                sumsq = jax.lax.psum(jnp.sum(g_shard * g_shard),
-                                     DATA_AXIS)
-                g_shard = g_shard * jnp.minimum(
-                    1.0, clip_norm * jax.lax.rsqrt(
-                        jnp.maximum(sumsq, 1e-16)))
-            updates, opt_shard = tx.update(g_shard, opt_shard, p_shard)
-            p_shard = optax.apply_updates(p_shard, updates)
+            with jax.named_scope(prof.PULL):
+                full = quantized_all_gather(p_shard, DATA_AXIS, comm)
+                params = cast_floating(unravel(full[:n]), cd)
+            with jax.named_scope(prof.GRAD):
+                loss, gflat = _grads_flat(params, batch)
+            with jax.named_scope(prof.PUSH):
+                gpad = jnp.zeros(padded, gflat.dtype).at[:n].set(gflat)
+                g_shard = quantized_psum_scatter(gpad, DATA_AXIS, comm)
+                if reduce == "mean":
+                    g_shard = g_shard / num_workers
+                if clip_norm:
+                    # global-norm clip across ALL shards (the optax
+                    # transform would only see this shard's slice)
+                    sumsq = jax.lax.psum(jnp.sum(g_shard * g_shard),
+                                         DATA_AXIS)
+                    g_shard = g_shard * jnp.minimum(
+                        1.0, clip_norm * jax.lax.rsqrt(
+                            jnp.maximum(sumsq, 1e-16)))
+            with jax.named_scope(prof.UPDATE):
+                updates, opt_shard = tx.update(g_shard, opt_shard, p_shard)
+                p_shard = optax.apply_updates(p_shard, updates)
             return p_shard, opt_shard, jax.lax.pmean(loss, DATA_AXIS)
 
         step = jax.shard_map(
@@ -391,13 +398,18 @@ class DenseTable:
             in_specs=(self._pspec, self._opt_specs, bspec),
             out_specs=(self._pspec, self._opt_specs, P()),
         )
+        # a stable name for the program and its trace, whatever the
+        # caller called its grad_fn
+        step.__name__ = step.__qualname__ = prof.DENSE_STEP_FN
         if jit:
             step = jax.jit(step, donate_argnums=(0, 1))
         return step
 
     def step_inplace(self, step, batch) -> jnp.ndarray:
         """Run a fused step against the table's own state."""
-        self.params, self.opt_state, loss = step(self.params, self.opt_state, batch)
+        with prof.span(prof.STEP):
+            self.params, self.opt_state, loss = step(
+                self.params, self.opt_state, batch)
         return loss
 
     # ------------------------------------------------------------- state I/O

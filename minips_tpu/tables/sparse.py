@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from minips_tpu.parallel.mesh import DATA_AXIS
+from minips_tpu.utils import profiling as prof
 
 _HASH_MULT = np.uint32(2654435761)  # Knuth multiplicative hash
 
@@ -123,6 +124,7 @@ def next_pow2(n: int, floor: int = 1) -> int:
 class SparseTable:
     """Hashed, sharded embedding table with server-side SGD/Adagrad on push."""
 
+    @prof.span(prof.TABLE_INIT)
     def __init__(
         self,
         num_slots: int,

@@ -9,8 +9,10 @@ SPMD path BSP is implicit in the collectives).
 
 from __future__ import annotations
 
+import os
 from typing import Any, Callable, Iterable, Optional
 
+from minips_tpu.utils import profiling as prof
 from minips_tpu.utils.metrics import MetricsLogger
 from minips_tpu.utils.timing import StepTimer
 
@@ -62,9 +64,8 @@ class TrainLoop:
         self.timer = StepTimer(warmup_steps=warmup_steps)
         self.profiler = None
         if profile_dir:
-            from minips_tpu.utils.profiling import StepWindowProfiler
-
-            self.profiler = StepWindowProfiler(profile_dir, *profile_range)
+            self.profiler = prof.StepWindowProfiler(profile_dir,
+                                                    *profile_range)
 
     def run(self, num_iters: int) -> list[float]:
         try:
@@ -72,6 +73,8 @@ class TrainLoop:
         finally:
             if self.profiler is not None:
                 self.profiler.close()  # an open trace must flush even on error
+                # the run's spans and counters, beside the trace
+                prof.dump(os.path.join(self.profiler.log_dir, "spans.json"))
 
     def _run(self, num_iters: int) -> list[float]:
         losses: list[float] = []
@@ -97,7 +100,8 @@ class TrainLoop:
                 batch, ahead = ahead, None
             else:
                 try:
-                    batch = next(it)
+                    with prof.span(prof.LOOP_NEXT_BATCH):
+                        batch = next(it)
                 except StopIteration:
                     # finite sources (one-pass streams) end the loop
                     # cleanly; BatchIterator-style sources cycle and
@@ -112,29 +116,35 @@ class TrainLoop:
                 # num_iters bound lands between them) is the callback
                 # owner's cleanup (PullFuture.cancel)
                 try:
-                    ahead = next(it)
+                    with prof.span(prof.LOOP_NEXT_BATCH):
+                        ahead = next(it)
                 except StopIteration:
                     ahead = None
                 else:
-                    self.prefetch(ahead)
+                    with prof.span(prof.LOOP_PREFETCH):
+                        self.prefetch(ahead)
             loss = self.step(batch)
             n = (self.batch_size if self.batch_size is not None
                  else _leading_dim(batch))
             self.timer.step(n)
-            losses.append(float(loss))
+            with prof.span(prof.LOOP_READBACK):   # waits for the device
+                losses.append(float(loss))
             gstep = self.step_offset + i + 1
             if self.log_every and (i + 1) % self.log_every == 0:
-                extra = (self.extra_metrics()
-                         if self.extra_metrics is not None else {})
-                self.metrics.log(step=gstep, loss=float(loss),
-                                 samples_per_sec=self.timer.samples_per_sec,
-                                 **extra)
+                with prof.span(prof.LOOP_LOG):
+                    extra = (self.extra_metrics()
+                             if self.extra_metrics is not None else {})
+                    self.metrics.log(
+                        step=gstep, loss=float(loss),
+                        samples_per_sec=self.timer.samples_per_sec,
+                        **extra)
             # GLOBAL-step modulo: a resumed run keeps the same checkpoint
             # cadence as an uninterrupted one (local modulo would drift by
             # start_step and can leave resumed tail steps never saved)
             if (self.checkpointer is not None and self.checkpoint_every
                     and gstep % self.checkpoint_every == 0):
-                self.checkpointer.save(step=gstep)
+                with prof.span(prof.LOOP_CHECKPOINT):
+                    self.checkpointer.save(step=gstep)
         return losses
 
 

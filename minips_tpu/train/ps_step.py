@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from minips_tpu.parallel.mesh import DATA_AXIS
 from minips_tpu.tables.dense import DenseTable, cast_floating
 from minips_tpu.tables.sparse import SparseTable
+from minips_tpu.utils import profiling as prof
 
 PyTree = Any
 
@@ -117,9 +118,16 @@ class PSTrainStep:
 
         def step(state, batch):
             # ----- pull phase (differentiable views of table state)
-            if dense is not None:
-                p_flat, opt = state["dense"]
-            cbatch = cast_floating(batch, cd)
+            with jax.named_scope(prof.PULL):
+                if dense is not None:
+                    p_flat, opt = state["dense"]
+                cbatch = cast_floating(batch, cd)
+                slots = {}
+                rows = {}
+                for name, t in sparse.items():
+                    keys = key_fns[name](batch)
+                    slots[name] = t.slots_of(keys)
+                    rows[name] = state[name][0][slots[name]]
 
             def compute_loss(p_flat_in, rows_in):
                 dp = (cast_floating(
@@ -128,40 +136,38 @@ class PSTrainStep:
                 return loss_fn(dp, cast_floating(rows_in, cd),
                                cbatch).astype(jnp.float32)
 
-            slots = {}
-            rows = {}
-            for name, t in sparse.items():
-                keys = key_fns[name](batch)
-                slots[name] = t.slots_of(keys)
-                rows[name] = state[name][0][slots[name]]
-
-            if dense is not None:
-                loss, (g_flat, g_rows) = jax.value_and_grad(
-                    compute_loss, argnums=(0, 1))(p_flat, rows)
-            else:
-                loss, g_rows = jax.value_and_grad(
-                    lambda rw: compute_loss(None, rw))(rows)
-            if gscale != 1.0:
-                g_rows = jax.tree.map(lambda g: g * gscale, g_rows)
+            with jax.named_scope(prof.GRAD):
                 if dense is not None:
-                    g_flat = g_flat * gscale
+                    loss, (g_flat, g_rows) = jax.value_and_grad(
+                        compute_loss, argnums=(0, 1))(p_flat, rows)
+                else:
+                    loss, g_rows = jax.value_and_grad(
+                        lambda rw: compute_loss(None, rw))(rows)
+                if gscale != 1.0:
+                    g_rows = jax.tree.map(lambda g: g * gscale, g_rows)
+                    if dense is not None:
+                        g_flat = g_flat * gscale
 
             new_state = dict(state)
             # ----- dense push: reduce-scatter + sharded optax update
             if dense is not None:
-                g_flat = jax.lax.with_sharding_constraint(
-                    g_flat, NamedSharding(mesh, P(DATA_AXIS)))
-                updates, opt = dense.tx.update(g_flat, opt, p_flat)
-                new_state["dense"] = (optax.apply_updates(p_flat, updates),
-                                      opt)
+                with jax.named_scope(prof.PUSH_DENSE):
+                    g_flat = jax.lax.with_sharding_constraint(
+                        g_flat, NamedSharding(mesh, P(DATA_AXIS)))
+                    updates, opt = dense.tx.update(g_flat, opt, p_flat)
+                    new_state["dense"] = (
+                        optax.apply_updates(p_flat, updates), opt)
             # ----- sparse pushes: row-wise updater on touched slots
             # (shared transition with SparseTable.push: t.row_update)
             for name, t in sparse.items():
                 emb, opt = state[name]
-                new_state[name] = t.row_update(emb, opt, slots[name],
-                                               g_rows[name])
+                with jax.named_scope(prof.PUSH_SPARSE), \
+                        jax.named_scope(name):
+                    new_state[name] = t.row_update(emb, opt, slots[name],
+                                                   g_rows[name])
             return new_state, loss
 
+        step.__name__ = step.__qualname__ = prof.FUSED_STEP_FN
         # un-jitted pure transition, exposed for scan-chained microbenching
         # (bench.py chains K steps in one dispatch to defeat host overhead)
         self.step_fn_pure = step
@@ -172,9 +178,13 @@ class PSTrainStep:
         """Run one fused step against the tables' live state. The batch
         should already be device_put with data-axis sharding (use
         ``shard_batch``)."""
-        state = self._collect_state()
-        new_state, loss = self._jit_step(state, batch)
-        self._restore_state(new_state)
+        with prof.span(prof.STEP):
+            with prof.span(prof.STEP_COLLECT):
+                state = self._collect_state()
+            with prof.span(prof.STEP_DISPATCH):
+                new_state, loss = self._jit_step(state, batch)
+            with prof.span(prof.STEP_RESTORE):
+                self._restore_state(new_state)
         return loss
 
     def lower(self, batch):
@@ -186,5 +196,6 @@ class PSTrainStep:
     def shard_batch(self, batch: PyTree) -> PyTree:
         """device_put batch leaves sharded along the data axis (axis 0)."""
         sharding = NamedSharding(self._mesh, P(DATA_AXIS))
-        return jax.tree.map(
-            lambda x: jax.device_put(jnp.asarray(x), sharding), batch)
+        with prof.span(prof.FEED):
+            return jax.tree.map(
+                lambda x: jax.device_put(jnp.asarray(x), sharding), batch)

@@ -1,29 +1,267 @@
-"""Profiling hooks — jax.profiler made first-class (SURVEY.md §5.1).
+"""Spans, named phases and profiler windows for the fused PS step.
 
-The reference has only glog-timestamped iteration timers; on TPU the real
-tool is the XLA profiler: ``jax.profiler.trace`` captures a TensorBoard-
-readable trace (HLO timelines, per-op HBM/MXU utilization). Because the
-[T1] primary metric is samples/sec/chip, profiling is not an afterthought:
-``profile_steps`` wraps a window of training steps, and ``TrainLoop``
-exposes it via ``profile_dir``/``profile_range``.
+One span type. ``span(name)`` records name, start, end
+(``time.perf_counter_ns``), the span that caused it (the innermost span
+open on this thread) and the step it belongs to (the ordinal of the
+enclosing ``ps.step``). Every span goes three places: a bounded in-memory
+ring (always: a stall that shows only with no profiler running has to be
+found there), per-name count and total, and the profiler's own trace when
+a session is open (``jax.profiler.TraceAnnotation``;
+``StepTraceAnnotation`` for ``ps.step``), so that host spans and device
+ops share one clock. There is no switch: "off" is "no profiler session",
+and the ring is the cost that is always paid.
+
+Compilations are counted at the same boundaries: every program built or
+read back from the persistent cache is a ``ps.compile`` record with its
+duration, its parent span and its step; every cache miss a
+``ps.cache_miss``.
+
+The names below are the only definition of the host spans and of the
+``jax.named_scope`` phases inside the jitted steps: call sites and the
+reduction (``utils/trace_analysis.py``) import them from here.
+
+``profile_trace`` / ``StepWindowProfiler`` capture the profiler's trace;
+``TrainLoop(profile_dir=, profile_range=)`` is the operator's way to one,
+and writes ``spans.json`` beside it.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
+import json
 import os
+import threading
 import time
-from typing import Iterator, Optional
+from typing import Iterable, Iterator, NamedTuple, Optional
+
+import jax
+from jax import monitoring
+
+# ---- host spans
+STEP = "ps.step"
+STEP_COLLECT = "ps.step.collect"
+STEP_DISPATCH = "ps.step.dispatch"
+STEP_RESTORE = "ps.step.restore"
+FEED = "ps.feed"
+TABLE_INIT = "ps.table.init"
+LOOP_NEXT_BATCH = "loop.next_batch"
+LOOP_PREFETCH = "loop.prefetch"
+LOOP_READBACK = "loop.readback"
+LOOP_LOG = "loop.log"
+LOOP_CHECKPOINT = "loop.checkpoint"
+# ---- counters, recorded in the ring like spans, under the span open then
+COMPILE = "ps.compile"
+CACHE_MISS = "ps.cache_miss"
+# ---- phases of the jitted steps (jax.named_scope -> an HLO op's op_name)
+PULL = "ps.pull"
+GRAD = "ps.grad"
+PUSH = "ps.push"
+PUSH_DENSE = "ps.push.dense"
+PUSH_SPARSE = "ps.push.sparse"
+UPDATE = "ps.update"
+SPARSE_DEDUP = "sparse.dedup"
+SPARSE_ADAGRAD_SORTED = "sparse.adagrad_sorted"
+SPARSE_ADAGRAD_DENSE = "sparse.adagrad_dense"
+SPARSE_ADAM_SORTED = "sparse.adam_sorted"
+SPARSE_ADAM_DENSE = "sparse.adam_dense"
+LM_EMBED = "lm.embed"
+LM_ATTN = "lm.attn"
+LM_MLP = "lm.mlp"
+LM_HEAD = "lm.head"
+# ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names
+FLASH_FWD = "flash_fwd"
+FLASH_DQ = "flash_dq"
+FLASH_DKV = "flash_dkv"
+GATHER_ROWS = "gather_rows"
+DENSE_STEP_FN = "ps_dense_step"
+FUSED_STEP_FN = "ps_fused_step"
+
+# an op_name path may hold several (ps.grad/lm.attn/...): a reduction
+# takes the innermost
+PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
+          SPARSE_DEDUP, SPARSE_ADAGRAD_SORTED, SPARSE_ADAGRAD_DENSE,
+          SPARSE_ADAM_SORTED, SPARSE_ADAM_DENSE,
+          LM_EMBED, LM_ATTN, LM_MLP, LM_HEAD)
+KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, GATHER_ROWS)
+
+RING_SPANS = 8192
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+class Span(NamedTuple):
+    """One closed span. ``parent`` is the id of the span that caused it
+    (None at top level), ``step`` the ordinal of the enclosing
+    ``ps.step`` (None outside one); times are ``perf_counter_ns``."""
+    id: int
+    parent: Optional[int]
+    name: str
+    parent_name: Optional[str]
+    start_ns: int
+    end_ns: int
+    step: Optional[int]
+
+
+_ring: collections.deque = collections.deque(maxlen=RING_SPANS)
+_counters: dict[str, list] = {}      # name -> [count, total_ns]
+_lock = threading.Lock()             # guards _ring, _counters, _listening
+_ids = itertools.count()
+_steps = itertools.count()
+_open = threading.local()            # .stack: the spans open on a thread
+_listening = False
+
+
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+def _record(name: str, start_ns: int, end_ns: int, sid: int,
+            parent: Optional["span"], step: Optional[int]) -> None:
+    rec = Span(sid, None if parent is None else parent.id, name,
+               None if parent is None else parent.name,
+               start_ns, end_ns, step)
+    with _lock:
+        _ring.append(rec)
+        c = _counters.get(name)
+        if c is None:
+            _counters[name] = [1, end_ns - start_ns]
+        else:
+            c[0] += 1
+            c[1] += end_ns - start_ns
+
+
+def _record_under_open_span(name: str, duration_ns: int) -> None:
+    """A counter's record, ending now, under the span open on this thread."""
+    end = time.perf_counter_ns()
+    stack = _stack()
+    parent = stack[-1] if stack else None
+    _record(name, end - duration_ns, end, next(_ids), parent,
+            None if parent is None else parent.step)
+
+
+def _on_duration(event: str, duration_secs: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        _record_under_open_span(COMPILE, int(duration_secs * 1e9))
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_MISS_EVENT:
+        _record_under_open_span(CACHE_MISS, 0)
+
+
+def _listen() -> None:
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+class span(contextlib.ContextDecorator):
+    """``with span(name):`` or ``@span(name)`` on a function — see the
+    module's docstring. An exception inside still closes and records the
+    span."""
+
+    __slots__ = ("name", "id", "step", "_parent", "_t0", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def _recreate_cm(self) -> "span":
+        return span(self.name)      # one object a call: spans nest
+
+    def __enter__(self) -> "span":
+        if not _listening:
+            _listen()
+        stack = _stack()
+        self._parent = stack[-1] if stack else None
+        self.id = next(_ids)
+        if self.name == STEP:
+            self.step = next(_steps)
+            self._ann = jax.profiler.StepTraceAnnotation(
+                STEP, step_num=self.step)
+        else:
+            self.step = (None if self._parent is None
+                         else self._parent.step)
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        stack.append(self)
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter_ns()
+        _stack().pop()
+        _record(self.name, self._t0, t1, self.id, self._parent, self.step)
+        self._ann.__exit__(*exc)
+        return False
+
+
+def snapshot() -> tuple[tuple, dict]:
+    """A copy of what was recorded: (the ring's spans, oldest first;
+    ``{name: (count, total_ns)}`` over every span since the start or the
+    last ``clear``, also those the ring has dropped)."""
+    with _lock:
+        return (tuple(_ring),
+                {k: (v[0], v[1]) for k, v in _counters.items()})
+
+
+def clear() -> None:
+    """Empty the ring and the counters (tests, tools); ids and step
+    ordinals keep counting."""
+    with _lock:
+        _ring.clear()
+        _counters.clear()
+
+
+def self_time(spans: Iterable[Span]) -> dict[int, int]:
+    """``{span id: ns}``: each span's duration minus the part of its
+    interval that its child spans (among ``spans``) cover."""
+    spans = list(spans)
+    kids: dict[int, list] = collections.defaultdict(list)
+    for s in spans:
+        if s.parent is not None:
+            kids[s.parent].append(s)
+    out = {}
+    for s in spans:
+        covered, hi = 0, s.start_ns
+        for k in sorted(kids.get(s.id, ()), key=lambda k: k.start_ns):
+            lo = max(k.start_ns, hi)
+            end = min(k.end_ns, s.end_ns)
+            if end > lo:
+                covered += end - lo
+                hi = end
+        out[s.id] = s.end_ns - s.start_ns - covered
+    return out
+
+
+def dump(path: str) -> None:
+    """Write ``snapshot()`` as JSON: ``{"spans": [Span fields...],
+    "fields": [...], "counters": {name: [count, total_ns]}}``."""
+    spans, counters = snapshot()
+    # a run that ended before its profiler window opened has no directory
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump({"fields": list(Span._fields),
+                   "spans": [list(s) for s in spans],
+                   "counters": {k: list(v) for k, v in counters.items()}},
+                  f)
 
 
 @contextlib.contextmanager
 def profile_trace(log_dir: str) -> Iterator[None]:
     """Capture a jax.profiler trace into ``log_dir`` (view with
-    TensorBoard's profile plugin, or read the ``.xplane.pb`` with
-    ``jax.profiler.ProfileData``). Whoever calls this asked for a trace: a
-    profiler that cannot start or stop raises."""
-    import jax
-
+    TensorBoard's profile plugin, or reduce the ``.xplane.pb`` with
+    ``python -m minips_tpu.utils.trace_analysis``). Whoever calls this
+    asked for a trace: a profiler that cannot start or stop raises."""
     os.makedirs(log_dir, exist_ok=True)
     with jax.profiler.trace(log_dir):
         yield
@@ -55,34 +293,3 @@ class StepWindowProfiler:
         if self._ctx is not None:
             self._ctx.__exit__(None, None, None)
             self._ctx = None
-
-
-class Annotation:
-    """Named host-side span that also shows up in device traces via
-    jax.profiler.TraceAnnotation; accumulates wall time per name so hot
-    host phases (data loading, checkpoint snapshot) are quantified even
-    without a device trace."""
-
-    totals: dict[str, float] = {}
-
-    def __init__(self, name: str):
-        self.name = name
-
-    def __enter__(self):
-        import jax
-
-        self._t0 = time.monotonic()
-        try:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:  # pragma: no cover
-            self._ann = None
-        return self
-
-    def __exit__(self, *exc):
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-        Annotation.totals[self.name] = (
-            Annotation.totals.get(self.name, 0.0)
-            + time.monotonic() - self._t0)
-        return False

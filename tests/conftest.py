@@ -51,6 +51,34 @@ def load_jax_compat_manifest() -> list[str]:
         return []
 
 
+_BENCH_TESTS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "bench", "tests")
+
+
+class BenchSuite(pytest.File):
+    """``tests/test_bench_suite.py`` stands for the benchmark's own test
+    files (see its docstring): one ``pytest.Module`` each."""
+
+    def collect(self):
+        import glob
+        import pathlib
+        import sys
+
+        # where benchlib and tiny are found; at the END of the path, so
+        # that ``tests.conftest`` stays this file and not bench/tests' own
+        for p in (os.path.dirname(_BENCH_TESTS), _BENCH_TESTS):
+            if p not in sys.path:
+                sys.path.append(p)
+        for path in sorted(glob.glob(os.path.join(_BENCH_TESTS,
+                                                  "test_*.py"))):
+            yield pytest.Module.from_parent(self, path=pathlib.Path(path))
+
+
+def pytest_collect_file(parent, file_path):
+    if file_path.name == "test_bench_suite.py":
+        return BenchSuite.from_parent(parent, path=file_path)
+
+
 def pytest_collection_modifyitems(config, items):
     quarantined = set(load_jax_compat_manifest())
     if not quarantined:

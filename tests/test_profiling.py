@@ -1,5 +1,5 @@
 """Profiling hooks (SURVEY.md §5.1): trace window produces an artifact;
-annotations accumulate host time."""
+spans accumulate host time by name (tests/test_spans.py has the rest)."""
 
 from __future__ import annotations
 
@@ -8,7 +8,8 @@ import time
 
 import jax.numpy as jnp
 
-from minips_tpu.utils.profiling import Annotation, StepWindowProfiler
+from minips_tpu.utils import profiling
+from minips_tpu.utils.profiling import StepWindowProfiler, span
 
 
 def test_step_window_profiler_writes_trace(tmp_path):
@@ -30,10 +31,12 @@ def test_window_closed_even_if_run_ends_early(tmp_path):
     p.close()  # idempotent
 
 
-def test_annotation_accumulates():
-    Annotation.totals.clear()
-    with Annotation("phase_x"):
+def test_span_accumulates_count_and_total_by_name():
+    profiling.clear()
+    with span("phase_x"):
         time.sleep(0.01)
-    with Annotation("phase_x"):
+    with span("phase_x"):
         time.sleep(0.01)
-    assert Annotation.totals["phase_x"] >= 0.02
+    count, total_ns = profiling.snapshot()[1]["phase_x"]
+    assert count == 2
+    assert total_ns >= 0.02e9
