@@ -14,20 +14,26 @@ score matrix in HBM):
   never this scan.
 
 - ``flash_attention`` — Pallas TPU kernels. Forward: grid (batch, head,
-  Q blocks, K blocks) with the K sweep innermost; the float32 online-
-  softmax state (running max m, normalizer l, accumulator acc) lives in
-  VMEM scratch across the sweep, blocks are pipelined HBM→VMEM by Pallas,
-  scores exist only in VMEM, and the per-row logsumexp is written out for
-  the backward. Backward (``jax.custom_vjp``): two kernels that recompute
-  p = exp(s − lse) per block — dQ accumulates over the K sweep, dK/dV over
-  the transposed Q sweep — so training memory stays O(T) and the [T, T]
-  matrix never exists in either pass. Causal runs skip fully-masked blocks
-  in all three kernels.
+  Q majors, K majors); a grid step owns a major block of Q rows (the
+  whole sequence where a head fits ``_RESIDENT_BYTES``) with the head's
+  K/V resident in VMEM, and walks score tiles itself, ``lax.fori_loop``
+  inside ``lax.fori_loop``, over the live tiles only: the bounds come
+  from the global offsets, tiles under the diagonal skip the mask, tiles
+  above it are never visited. Scores are computed transposed (k q^T, Q on
+  the lanes), so the float32 online-softmax state (running max m,
+  normalizer l: lane-dense rows; accumulator acc^T) is a small loop
+  carry; scores exist only in VMEM, and the per-row logsumexp is written
+  out for the backward as the rows it is,
+  [B, H, Q majors, tiles, tile_q]. Backward (``jax.custom_vjp``): two
+  kernels that recompute p = exp(s − lse) per tile — dQ over the same
+  sweep, dK/dV owning K rows and sweeping Q tiles, transposed like the
+  forward — accumulating in float32 VMEM scratch, and computing the tile
+  ON the diagonal as its live 128-wide groups only; training memory stays
+  O(T) and the [T, T] matrix never exists in either pass. ``flash_plan``
+  is the one place tiles and resident extents are chosen, from the shape.
 
-Measured on the one real chip here (2026-07-29, bf16, B=2 H=8 D=64,
-T=8192): forward 5.8ms vs 12.4ms XLA full-scores; fwd+bwd 21ms vs 40ms;
-end-to-end LM training (apps/lm_example --attn flash) 1.5x tokens/sec at
-T=8192, and T=32768 works where full scores OOM HBM.
+Speeds: PERF.md section 5 (the benchmark cell's trace by kernel) and
+section 6, PR 27 (what each part of this design brought on the v5e).
 
 Layout matches the rest of the stack: q/k/v are ``[B, T, H, D]`` (the
 ring-attention convention, parallel/ring_attention.py). The kernel wants
@@ -39,7 +45,8 @@ surrounding program.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -166,67 +173,300 @@ def blockwise_attention(
 # ring flash attention (ring_flash_attention_local) passes each shard's
 # sequence offsets so the same kernels compute the diagonal, kept, and
 # fully-masked ring steps. Offsets arrive as (1,) int32 arrays in SMEM.
+#
+# A grid step owns a MAJOR block of one sequence axis for one (batch,
+# head) and walks score TILES of it itself: the forward and dQ own Q rows
+# and sweep the K tiles of the resident K/V, dK/dV owns K rows and sweeps
+# the Q tiles of the resident Q/dO. The sweep's bounds come from the
+# offsets, so a tile the causal mask kills costs nothing, and only tiles
+# the diagonal crosses build the mask. A sequence whose head does not fit
+# ``_RESIDENT_BYTES`` is walked in major blocks by a sequential grid axis,
+# the accumulators crossing it in VMEM scratch.
+#
+# The forward and dK/dV compute their scores TRANSPOSED, [K rows, Q lanes]
+# = k q^T: the softmax statistics are then lane-dense rows [1, tile_q]
+# (two vregs where a column [tile_q, 1] takes tile_q / 8), their
+# reductions run down the sublanes on the VPU instead of across lanes on
+# the XLU, p^T and ds^T feed their dots as they are, and the logsumexp
+# leaves and enters the kernels as the rows it is stored in.
 
-def _mask_scores(s, masked, i, j, bq, bk, q_off, k_off):
+_LANES = 128
+# VMEM budget for ONE resident operand of a grid step (a head's K, V, Q or
+# dO; Pallas double-buffers each). Past it the sequence goes in major blocks.
+_RESIDENT_BYTES = 512 * 1024
+# Upper bounds (Q, K) on a computed score tile, tuned on the v5e at GPT-2
+# XL's head shape (PERF.md section 6, PR 27). A smaller tile computes less
+# of the causal matrix and loses more than that to the latency of its
+# dots, so the tiles are large and the backward cuts the tile ON the
+# diagonal into groups instead (_diag_groups). block_q / block_k bound
+# every tile too.
+_FWD_TILE = (512, 512)
+_BWD_TILE = (1024, 1024)
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T, contraction on both minor dims
+
+
+class FlashPlan(NamedTuple):
+    """What :func:`flash_plan` chose for a shape."""
+    tile_q: int    # Q extent of a forward score tile, and the width of a
+    tile_k: int    # logsumexp row; K extent of a forward tile
+    bwd_q: int     # the backward kernels' tile (bwd_q a multiple of tile_q)
+    bwd_k: int
+    major_q: int   # Q rows resident in a grid step (what fwd / dQ own)
+    major_k: int   # K rows resident in a grid step (what dK/dV owns)
+    causal_share: float  # share of the causal scores the forward computes
+    bwd_share: float     # ... and each backward kernel
+
+
+def _pick_tile(T: int, cap: int, unit: int = 1) -> int:
+    """Largest divisor of T within cap (a multiple of unit) that is a
+    multiple of 128 lanes; a sequence with none gets its largest such
+    divisor of at most 128. So a tile is at most 128 or a multiple of it."""
+    divs = [d for d in range(unit, min(T, max(cap, unit)) + 1, unit)
+            if T % d == 0]
+    wide = [d for d in divs if d % _LANES == 0]
+    return max(wide) if wide else max(d for d in divs if d <= _LANES)
+
+
+def _pick_major(T: int, unit: int, row_bytes: int, budget: int) -> int:
+    """Whole sequence when it fits the budget, else its largest divisor
+    that does and is a multiple of unit; a sequence with no such divisor
+    stays whole."""
+    if T * row_bytes <= budget:
+        return T
+    fits = [d for d in range(unit, T, unit)
+            if T % d == 0 and d * row_bytes <= budget]
+    return max(fits) if fits else T
+
+
+def _diag_groups(tq: int, tk: int) -> int:
+    """How many 128-wide groups the backward cuts the tile ON the diagonal
+    into (0: it is not cut): a square tile that the diagonal halves is
+    computed as its (n + 1) / 2n live share, group by group, inside one
+    loop body: the groups are independent, so their dots overlap."""
+    return tq // _LANES if tq == tk and tq % _LANES == 0 and tq > _LANES \
+        else 0
+
+
+def computed_share(Tq: int, Tk: int, tile_q: int, tile_k: int,
+                   cut: bool = False) -> float:
+    """Share of the Tq x Tk score matrix inside tiles that causal masking
+    (offsets 0) leaves live — what the kernels compute; 0.5 is the floor.
+    ``cut``: the tiles on the diagonal count their live groups only."""
+    live = sum(min(-(-((i + 1) * tile_q) // tile_k), Tk // tile_k)
+               for i in range(Tq // tile_q))
+    n = _diag_groups(tile_q, tile_k) if cut else 0
+    if n:   # min(Tq, Tk) / tile tiles lie on the diagonal
+        live -= min(Tq, Tk) // tile_q * (n - 1) / (2 * n)
+    return live * tile_q * tile_k / (Tq * Tk)
+
+
+def flash_plan(Tq: int, Tk: int, D: int, itemsize: int,
+               block_q: Optional[int] = None,
+               block_k: Optional[int] = None) -> FlashPlan:
+    """Tiles and resident extents for a shape: pure, and the one place the
+    kernels take them from. ``block_q`` / ``block_k``, where given, are
+    upper bounds on every tile."""
+    cap_q, cap_k = block_q or Tq, block_k or Tk
+    tq = _pick_tile(Tq, min(cap_q, _FWD_TILE[0]))
+    tk = _pick_tile(Tk, min(cap_k, _FWD_TILE[1]))
+    bq = _pick_tile(Tq, min(cap_q, _BWD_TILE[0]), tq)   # whole lse rows
+    bk = _pick_tile(Tk, min(cap_k, _BWD_TILE[1]))
+    return FlashPlan(
+        tq, tk, bq, bk,
+        _pick_major(Tq, bq, D * itemsize, _RESIDENT_BYTES),
+        _pick_major(Tk, math.lcm(tk, bk), D * itemsize, _RESIDENT_BYTES),
+        computed_share(Tq, Tk, tq, tk),
+        computed_share(Tq, Tk, bq, bk, cut=True))
+
+
+def _scale_folds(scale: float) -> bool:
+    """A power-of-two scale (64 ** -0.5) commutes with every rounding, so
+    it is applied to the [tile_q, D] Q tile (and dQ) instead of the
+    scores; any other scale stays on the scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _scaled(qb, scale):
+    return qb * jnp.asarray(scale, qb.dtype) if _scale_folds(scale) else qb
+
+
+def _scores(a, b, scale):
+    """a b^T in float32, times the scale where it did not go onto Q."""
+    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    return s if _scale_folds(scale) else s * scale
+
+
+def _lanes(x, n):
+    """A lane-replicated [rows, 128] statistic at n lanes (a tile's width:
+    at most 128, or a multiple of it)."""
+    return x[:, :n] if n <= _LANES else jnp.concatenate(
+        [x] * (n // _LANES), axis=1)
+
+
+def _get_row(blk, t):
+    """Row t (traced) of a small [rows, n] block as [1, n]: a select and
+    a sublane sum, since a single dynamic row cannot be loaded or stored."""
+    pick = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0) == t
+    return jnp.sum(jnp.where(pick, blk, 0.0), axis=0, keepdims=True)
+
+
+def _set_row(row_ref, t, row):
+    pick = jax.lax.broadcasted_iota(jnp.int32, row_ref.shape[3:], 0) == t
+    row_ref[0, 0, 0] = jnp.where(pick, row, row_ref[0, 0, 0])
+
+
+def _get_rows(row_ref, t, n, start=0):
+    """Rows [t * n, (t + 1) * n) of a [1, 1, 1, rows, w] ref side by side,
+    from lane ``start`` (static) of the n * w on: [1, n * w - start]."""
+    w = row_ref.shape[4]
+    return jnp.concatenate(
+        [_get_row(row_ref[0, 0, 0, :, max(start - j * w, 0):], t * n + j)
+         for j in range(n) if start < (j + 1) * w], axis=1)
+
+
+def _rows_to_col(row_ref, t, n):
+    """The same rows as one [n * w, 128] lane-replicated column (dQ's
+    scores have Q on the sublanes): 128 lanes at a time, keep the
+    diagonal of the row broadcast down the sublanes, sum across lanes."""
+    w = row_ref.shape[4]
+    c = min(w, _LANES)
+    eye = _tile_diff(c, c) == 0
+    return jnp.concatenate(
+        [jnp.broadcast_to(jnp.sum(
+            jnp.where(eye, _get_row(row_ref[0, 0, 0, :, i:i + c], t * n + j),
+                      0.0), axis=1, keepdims=True), (c, _LANES))
+         for j in range(n) for i in range(0, w, c)], axis=0)
+
+
+def _tile_diff(rows, cols):
+    # local row index minus local column index of a score tile
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _cdiv_clip(x, d, n):
+    """ceil(max(x, 0) / d) clipped to [0, n], on int32 scalars."""
+    return jnp.clip(jax.lax.div(jnp.maximum(x, 0) + (d - 1), d), 0, n)
+
+
+def _live_k_tiles(masked, q_lo, k_base, tq, tk, nk):
+    """For the Q tile whose first global row is q_lo: K tiles [0, full)
+    lie wholly under the diagonal, [full, live) cross it, the rest are
+    dead. Unmasked: every tile is kept."""
     if not masked:
-        return s
-    q_pos = q_off + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = k_off + j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        return nk, nk
+    full = jnp.clip(jax.lax.div(jnp.maximum(q_lo - k_base + 1, 0), tk),
+                    0, nk)
+    return full, _cdiv_clip(q_lo + tq - k_base, tk, nk)
 
 
-def _block_live(masked, i, j, bq, bk, q_off, k_off):
-    """False only for blocks that the global causal mask kills entirely —
-    skip their matmuls (the block DMA still happens; compute dominates)."""
+def _live_q_tiles(masked, k_lo, q_base, tq, tk, nq):
+    """For the K tile whose first global column is k_lo: Q tiles [0, lo)
+    are dead, [lo, full) cross the diagonal, [full, nq) are wholly kept."""
     if not masked:
-        return True
-    return k_off + j * bk <= q_off + (i + 1) * bq - 1
+        return 0, 0
+    lo = jnp.clip(jax.lax.div(jnp.maximum(k_lo - q_base, 0), tq), 0, nq)
+    return lo, _cdiv_clip(k_lo + tk - 1 - q_base, tq, nq)
+
+
+def _loop(lo, hi, tile, carry, **kw):
+    if isinstance(lo, int) and isinstance(hi, int) and lo >= hi:
+        return carry      # statically empty: costs nothing
+    return jax.lax.fori_loop(lo, hi, functools.partial(tile, **kw), carry)
+
+
+def _crossing(lo, hi, off, n_tiles, size, groups, tile, diag, carry):
+    """The tiles [lo, hi) of a sweep (tiles of ``size``) that the diagonal
+    crosses. ``off`` is where the diagonal enters the swept axis (this
+    side's first position minus the swept side's base): where tile lo
+    starts exactly there (and is resident) it is the only crossing tile,
+    and ``diag`` computes its live groups; else every crossing tile is
+    computed whole, and masked."""
+    masked = functools.partial(_loop, lo, hi, tile, mask_it=True)
+    if not groups:
+        return masked(carry)
+    aligned = jnp.logical_and(off == lo * size, lo < n_tiles)
+    return jax.lax.cond(aligned, functools.partial(diag, lo), masked, carry)
+
+
+def _rows(i, size):
+    return pl.ds(pl.multiple_of(i * size, size), size)
 
 
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                  acc_ref, m_ref, l_ref, *, scale, masked, num_k):
-    # Grid (B, H, nQ, nK), K innermost and sequential on TPU: the online-
-    # softmax state for one Q block lives in VMEM scratch across the nK
-    # sweep. Blocks: q/o [1, 1, bq, D]; k/v [1, 1, bk, D]; lse [1, 1, bq, 1].
-    bq = q_ref.shape[2]
+                  vt_s, *scratch, scale, masked, tq, tk):
+    # Grid (B, H, Q majors, K majors), K majors sequential. Blocks: q/o
+    # [1, 1, major_q, D]; k/v [1, 1, major_k, D], resident across the Q
+    # tiles; lse [1, 1, 1, major_q // tq, tq], one lane-dense row a Q tile.
+    # Scores are [tk, tq] = k q^T; the online-softmax state (m, l rows
+    # [1, tq]; acc^T [D, tq] += v^T p^T) is the K sweep's loop carry,
+    # float32, and the output tile is transposed back once, at the end.
+    bq, D = q_ref.shape[2], q_ref.shape[3]
     bk = k_ref.shape[2]
-    i = pl.program_id(2)
-    j = pl.program_id(3)
-    q_off, k_off = qoff_ref[0], koff_ref[0]
+    kmaj, num_major = pl.program_id(3), pl.num_programs(3)
+    q_base = qoff_ref[0] + pl.program_id(2) * bq
+    k_base = koff_ref[0] + kmaj * bk
+    diff = _tile_diff(tk, tq) if masked else None
+    for c in range(bk // tk):       # v^T of the resident V, tile by tile
+        vt_s[c] = v_ref[0, 0, c * tk:(c + 1) * tk, :].T
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    if scratch:   # the state crosses the K majors in VMEM
+        m_s, l_s, acc_s = scratch
 
-    @pl.when(_block_live(masked, i, j, bq, bk, q_off, k_off))
-    def _fold():
-        # dots run in the INPUT dtype (bf16 inputs → bf16 MXU rate, half
-        # the VMEM traffic) with f32 accumulation; all online-softmax
-        # state stays f32. f32 inputs behave exactly as before.
-        qb = q_ref[0, 0, :, :]
-        kb = k_ref[0, 0, :, :]
-        vb = v_ref[0, 0, :, :]
-        s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, masked, i, j, bq, bk, q_off, k_off)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [bq, 1]
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = (acc_ref[:] * alpha
-                      + jnp.dot(p.astype(vb.dtype), vb,
-                                preferred_element_type=jnp.float32))
-        m_ref[:] = m_new
+        @pl.when(kmaj == 0)
+        def _init():
+            m_s[:] = jnp.full_like(m_s, _NEG_INF)
+            l_s[:] = jnp.zeros_like(l_s)
+            acc_s[:] = jnp.zeros_like(acc_s)
 
-    @pl.when(j == num_k - 1)
-    def _write():
-        l_safe = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        # true logsumexp per row — the backward recomputes p = exp(s - lse),
-        # and the ring merge weights shards by exp(lse_s - lse_total)
-        lse_ref[0, 0, :, 0] = (m_ref[:] + jnp.log(l_safe))[:, 0]
+    def q_tile(t, _):
+        rows = _rows(t, tq)
+        q_lo = q_base + t * tq
+        # dots run in the INPUT dtype (bf16 inputs -> bf16 MXU rate) with
+        # f32 accumulation; all online-softmax state stays f32
+        qb = _scaled(q_ref[0, 0, rows, :], scale)
+
+        def k_tile(j, carry, mask_it):
+            m, l, acc = carry
+            s = _scores(k_ref[0, 0, _rows(j, tk), :], qb, scale)
+            if mask_it:
+                s = jnp.where(diff <= q_lo - (k_base + j * tk), s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+            vt = vt_s[j]
+            acc = acc * alpha + jnp.dot(
+                vt, p.astype(vt.dtype), preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        if scratch:
+            carry = m_s[t], l_s[t], acc_s[t]
+        else:
+            carry = (jnp.full((1, tq), _NEG_INF, jnp.float32),
+                     jnp.zeros((1, tq), jnp.float32),
+                     jnp.zeros((D, tq), jnp.float32))
+        full, live = _live_k_tiles(masked, q_lo, k_base, tq, tk, bk // tk)
+        carry = _loop(0, full, k_tile, carry, mask_it=False)
+        carry = _loop(full, live, k_tile, carry, mask_it=True)
+        m, l, acc = carry
+
+        def write():
+            l_safe = jnp.maximum(l, 1e-30)
+            o_ref[0, 0, rows, :] = (acc / l_safe).T.astype(o_ref.dtype)
+            # true logsumexp per row — the backward recomputes
+            # p = exp(s - lse), and the ring merge weights shards by
+            # exp(lse_s - lse_total)
+            _set_row(lse_ref, t, m + jnp.log(l_safe))
+
+        if scratch:
+            m_s[t], l_s[t], acc_s[t] = m, l, acc
+            pl.when(kmaj == num_major - 1)(write)
+        else:
+            write()
+
+    jax.lax.fori_loop(0, bq // tq, q_tile, None)
 
 
 def _smem_spec():
@@ -242,175 +482,272 @@ def _vma_of(*xs):
     return vma
 
 
+def _offsets(q_off, k_off):
+    return (jnp.asarray(q_off, jnp.int32).reshape(1),
+            jnp.asarray(k_off, jnp.int32).reshape(1))
+
+
+_SWEEP_LAST = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+
 def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
                    interpret):
     """[B, T, H, D] in/out; kernel runs on [B, H, T, D]. K/V may carry
     fewer heads (GQA): each q-head's K/V block index maps onto kv head
     h // g — the repeat never materializes, so KV HBM traffic shrinks by
-    the group factor."""
+    the group factor. Returns (out, lse [B, H, Q majors, tiles, tile_q]):
+    the logsumexp row-major over the sequence, a lane-dense row a tile."""
     B, Tq, H, D = q.shape
     g = gqa_group_size(H, k.shape[2])
     Tk = k.shape[1]
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    bq = min(block_q, Tq)
-    bk = min(block_k, Tk)
-    grid = (B, H, Tq // bq, Tk // bk)
+    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k)
+    tq, tk, bq, bk = plan.tile_q, plan.tile_k, plan.major_q, plan.major_k
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     vma = _vma_of(q, k, v, q_off, k_off)
-    offs = (jnp.asarray(q_off, jnp.int32).reshape(1),
-            jnp.asarray(k_off, jnp.int32).reshape(1))
+    num_major = Tk // bk
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, i, j: (b, h // g, j, 0))
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, masked=masked,
-                          num_k=Tk // bk),
-        grid=grid,
-        in_specs=[
-            _smem_spec(), _smem_spec(),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, D),
-                         lambda b, h, i, j: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, D),
-                         lambda b, h, i, j: (b, h // g, j, 0)),
-        ],
+                          tq=tq, tk=tk),
+        grid=(B, H, Tq // bq, num_major),
+        in_specs=[_smem_spec(), _smem_spec(), q_spec, kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            q_spec,
+            pl.BlockSpec((1, 1, 1, bq // tq, tq),
+                         lambda b, h, i, j: (b, h, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((B, H, Tq // bq, bq // tq, tq),
+                                 jnp.float32, vma=vma),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),   # acc
-            pltpu.VMEM((bq, 1), jnp.float32),   # running max m
-            pltpu.VMEM((bq, 1), jnp.float32),   # normalizer l
-        ],
+        scratch_shapes=[pltpu.VMEM((bk // tk, D, tk), v.dtype)] + (
+            [] if num_major == 1 else [
+                pltpu.VMEM((bq // tq, 1, tq), jnp.float32),   # running max
+                pltpu.VMEM((bq // tq, 1, tq), jnp.float32),   # normalizer
+                pltpu.VMEM((bq // tq, D, tq), jnp.float32),   # acc^T
+            ]),
+        compiler_params=_SWEEP_LAST,
         interpret=interpret,
         name=prof.FLASH_FWD,
-    )(*offs, qt, kt, vt)
+    )(*_offsets(q_off, k_off), qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
 
 def _flash_bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                         lse_ref, dvec_ref, dq_ref, dq_acc, *, scale,
-                         masked, num_k):
-    # Grid (B, H, nQ, nK), K innermost; dQ for one Q block accumulates in
-    # scratch across the K sweep. p is recomputed from the saved
+                         lse_ref, dvec_ref, dq_ref, dq_s, *, scale,
+                         masked, tq, tk):
+    # The forward's grid; scores [tq, tk] = q k^T, Q on the sublanes, so
+    # that dQ [tq, D] += ds k accumulates as it is written, in float32
+    # VMEM scratch, in place (see dK/dV). p is recomputed from the saved
     # logsumexp — the [T, T] matrix never exists.
-    bq, bk = q_ref.shape[2], k_ref.shape[2]
-    i, j = pl.program_id(2), pl.program_id(3)
-    q_off, k_off = qoff_ref[0], koff_ref[0]
+    bq, D = q_ref.shape[2], q_ref.shape[3]
+    nk = k_ref.shape[2] // tk
+    per_tile = tq // lse_ref.shape[4]    # logsumexp rows a Q tile spans
+    kmaj, num_major = pl.program_id(3), pl.num_programs(3)
+    q_base = qoff_ref[0] + pl.program_id(2) * bq
+    k_base = koff_ref[0] + kmaj * k_ref.shape[2]
+    fold = _scale_folds(scale)
+    diff = _tile_diff(tq, tk) if masked else None
 
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    @pl.when(_block_live(masked, i, j, bq, bk, q_off, k_off))
-    def _fold():
-        # native-dtype dots, f32 accumulation/softmax state (see _fold in
+    def q_tile(t, _):
+        rows = _rows(t, tq)
+        q_lo = q_base + t * tq
+        # native-dtype dots, f32 accumulation/softmax state (see
         # _flash_kernel); ds is cast back to the input dtype for its dot
-        qb = q_ref[0, 0, :, :]
-        kb = k_ref[0, 0, :, :]
-        vb = v_ref[0, 0, :, :]
-        dob = do_ref[0, 0, :, :]
-        s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, masked, i, j, bq, bk, q_off, k_off)
-        p = jnp.exp(s - lse_ref[0, 0, :, :])            # [bq, bk] f32
-        dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec_ref[0, 0, :, :]) * scale
-        dq_acc[:] = dq_acc[:] + jnp.dot(
-            ds.astype(kb.dtype), kb, preferred_element_type=jnp.float32)
+        qb, dob = _scaled(q_ref[0, 0, rows, :], scale), do_ref[0, 0, rows, :]
+        lse = _rows_to_col(lse_ref, t, per_tile)             # [tq, 128]
+        dvec = _rows_to_col(dvec_ref, t, per_tile)
 
-    @pl.when(j == num_k - 1)
-    def _write():
-        dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
+        @pl.when(kmaj == 0)
+        def _init():
+            dq_s[rows, :] = jnp.zeros((tq, D), jnp.float32)
+
+        def grad(s, dob, kb, vb, lse, dvec):
+            keys = s.shape[1]
+            p = jnp.exp(s - _lanes(lse, keys))               # [q, keys] f32
+            dp = jax.lax.dot_general(dob, vb, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - _lanes(dvec, keys))
+            if not fold:
+                ds = ds * scale
+            return jnp.dot(ds.astype(kb.dtype), kb,
+                           preferred_element_type=jnp.float32)
+
+        def k_tile(j, _, mask_it):
+            cols = _rows(j, tk)
+            kb, vb = k_ref[0, 0, cols, :], v_ref[0, 0, cols, :]
+            s = _scores(qb, kb, scale)
+            if mask_it:
+                s = jnp.where(diff >= k_base + j * tk - q_lo, s, _NEG_INF)
+            dq_s[rows, :] += grad(s, dob, kb, vb, lse, dvec)
+
+        def diag_tile(j, _):
+            # Q row group g of the tile ON the diagonal: keys [0, (g+1)*128)
+            u = _LANES
+            for g in range(tq // u):
+                grp, keys = slice(g * u, (g + 1) * u), (g + 1) * u
+                pre = pl.ds(pl.multiple_of(j * tk, tk), keys)
+                kb, vb = k_ref[0, 0, pre, :], v_ref[0, 0, pre, :]
+                s = _scores(qb[grp, :], kb, scale)
+                s = jnp.where(diff[:u, :keys] >= -g * u, s, _NEG_INF)
+                dq_s[pl.ds(pl.multiple_of(t * tq + g * u, u), u), :] += grad(
+                    s, dob[grp, :], kb, vb, lse[grp, :], dvec[grp, :])
+
+        full, live = _live_k_tiles(masked, q_lo, k_base, tq, tk, nk)
+        _loop(0, full, k_tile, None, mask_it=False)
+        if masked:
+            _crossing(full, live, q_lo - k_base, nk, tk,
+                      _diag_groups(tq, tk), k_tile, diag_tile, None)
+
+        @pl.when(kmaj == num_major - 1)
+        def _write():
+            dq = dq_s[rows, :]
+            dq_ref[0, 0, rows, :] = (dq * scale if fold else dq).astype(
+                dq_ref.dtype)
+
+    jax.lax.fori_loop(0, bq // tq, q_tile, None)
 
 
 def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                          lse_ref, dvec_ref, dk_ref, dv_ref, dk_acc,
-                          dv_acc, *, scale, masked, num_q, q_per_kv):
-    # Grid (B, Hk, nK, q_per_kv*nQ), the combined (group q-head, Q block)
-    # sweep innermost; dK/dV for one KV-head K block accumulate in scratch
-    # across BOTH — under GQA every kv head receives gradient from all
-    # q_per_kv q-heads of its group (the transposed iteration of dq).
-    bq, bk = q_ref.shape[2], k_ref.shape[2]
-    j, t = pl.program_id(2), pl.program_id(3)   # j: K block
-    i = jax.lax.rem(t, num_q)                   # i: Q block within head
-    q_off, k_off = qoff_ref[0], koff_ref[0]
+                          lse_ref, dvec_ref, dk_ref, dv_ref, dk_s, dv_s, *,
+                          scale, masked, tq, tk, num_q_major):
+    # Grid (B, Hk, K majors, q_per_kv * Q majors), the combined (group
+    # q-head, Q major) axis sequential: under GQA every kv head receives
+    # gradient from all q-heads of its group. A grid step walks its K
+    # tiles, and for each the live Q tiles of the resident Q/dO; scores
+    # [tk, tq] = k q^T as in the forward, logsumexp and dvec the rows
+    # they are stored as. dK/dV accumulate in float32 VMEM scratch, in
+    # place: as loop carries their 2 x tk / 8 vregs spill at every bound.
+    bk, D = k_ref.shape[2], k_ref.shape[3]
+    nq = q_ref.shape[2] // tq
+    per_tile = tq // lse_ref.shape[4]    # logsumexp rows a Q tile spans
+    t, num_t = pl.program_id(3), pl.num_programs(3)
+    q_base = qoff_ref[0] + jax.lax.rem(t, num_q_major) * q_ref.shape[2]
+    k_base = koff_ref[0] + pl.program_id(2) * bk
+    fold = _scale_folds(scale)
+    diff = _tile_diff(tk, tq) if masked else None
 
-    @pl.when(t == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def k_tile(c, _):
+        rows = _rows(c, tk)
+        k_lo = k_base + c * tk
+        kb, vb = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
 
-    @pl.when(_block_live(masked, i, j, bq, bk, q_off, k_off))
-    def _fold():
-        qb = q_ref[0, 0, :, :]
-        kb = k_ref[0, 0, :, :]
-        vb = v_ref[0, 0, :, :]
-        dob = do_ref[0, 0, :, :]
-        s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, masked, i, j, bq, bk, q_off, k_off)
-        p = jnp.exp(s - lse_ref[0, 0, :, :])            # [bq, bk] f32
-        dv_acc[:] = dv_acc[:] + jnp.dot(
-            p.T.astype(dob.dtype), dob, preferred_element_type=jnp.float32)
-        dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec_ref[0, 0, :, :]) * scale
-        dk_acc[:] = dk_acc[:] + jnp.dot(
-            ds.T.astype(qb.dtype), qb, preferred_element_type=jnp.float32)
+        @pl.when(t == 0)
+        def _init():
+            dk_s[rows, :] = jnp.zeros((tk, D), jnp.float32)
+            dv_s[rows, :] = jnp.zeros((tk, D), jnp.float32)
 
-    @pl.when(t == num_q * q_per_kv - 1)
-    def _write():
-        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
+        def grad(s, qb, dob, vb, lse, dvec):
+            p = jnp.exp(s - lse)                         # [keys, q] f32
+            dv = jnp.dot(p.astype(dob.dtype), dob,
+                         preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(vb, dob, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - dvec)
+            if not fold:
+                ds = ds * scale
+            # with the scale folded into qb, ds^T qb carries it
+            return jnp.dot(ds.astype(qb.dtype), qb,
+                           preferred_element_type=jnp.float32), dv
+
+        def q_tile(i, _, mask_it):
+            cols = _rows(i, tq)
+            qb = _scaled(q_ref[0, 0, cols, :], scale)
+            s = _scores(kb, qb, scale)
+            if mask_it:
+                s = jnp.where(diff <= q_base + i * tq - k_lo, s, _NEG_INF)
+            dk, dv = grad(s, qb, do_ref[0, 0, cols, :], vb,
+                          _get_rows(lse_ref, i, per_tile),
+                          _get_rows(dvec_ref, i, per_tile))
+            dk_s[rows, :] += dk
+            dv_s[rows, :] += dv
+
+        def diag_tile(i, _):
+            # K row group r of the tile ON the diagonal: queries from
+            # r * 128 on
+            u = _LANES
+            for r in range(tk // u):
+                grp = pl.ds(pl.multiple_of(c * tk + r * u, u), u)
+                suf = pl.ds(pl.multiple_of(i * tq + r * u, u), tq - r * u)
+                qb = _scaled(q_ref[0, 0, suf, :], scale)
+                s = _scores(kb[r * u:(r + 1) * u, :], qb, scale)
+                s = jnp.where(diff[:u, :tq - r * u] <= 0, s, _NEG_INF)
+                dk, dv = grad(
+                    s, qb, do_ref[0, 0, suf, :], vb[r * u:(r + 1) * u, :],
+                    _get_rows(lse_ref, i, per_tile, r * u),
+                    _get_rows(dvec_ref, i, per_tile, r * u))
+                dk_s[grp, :] += dk
+                dv_s[grp, :] += dv
+
+        lo, full = _live_q_tiles(masked, k_lo, q_base, tq, tk, nq)
+        if masked:
+            _crossing(lo, full, k_lo - q_base, nq, tq,
+                      _diag_groups(tq, tk), q_tile, diag_tile, None)
+        _loop(full, nq, q_tile, None, mask_it=False)
+
+        @pl.when(t == num_t - 1)
+        def _write():
+            dk_ref[0, 0, rows, :] = dk_s[rows, :].astype(dk_ref.dtype)
+            dv_ref[0, 0, rows, :] = dv_s[rows, :].astype(dv_ref.dtype)
+
+    jax.lax.fori_loop(0, bk // tk, k_tile, None)
 
 
 def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
                     block_q, block_k, interpret):
     """dQ/dK/dV via the two backward kernels; [B, T, H, D] layout.
-    ``dvec`` is [B, H, Tq, 1] — rowsum(dO*O) minus the lse cotangent.
+    ``lse`` and ``dvec`` (rowsum(dO*O) minus the lse cotangent) are
+    [B, H, Q majors, tiles, tile_q] as the forward leaves the logsumexp.
     Under GQA dk/dv come back at the kv head count."""
     B, Tq, H, D = q.shape
     Hk = k.shape[2]
     g = gqa_group_size(H, Hk)
     Tk = k.shape[1]
-    bq = min(block_q, Tq)
-    bk = min(block_k, Tk)
+    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k)
+    tq, tk, bq, bk = plan.bwd_q, plan.bwd_k, plan.major_q, plan.major_k
+    w = plan.tile_q
+    nqm, nkm = Tq // bq, Tk // bk
     qt, kt, vt, dot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, g_out))
     vma = _vma_of(q, k, v, q_off, k_off, g_out)
-    offs = (jnp.asarray(q_off, jnp.int32).reshape(1),
-            jnp.asarray(k_off, jnp.int32).reshape(1))
+    offs = _offsets(q_off, k_off)
 
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, D),
                            lambda b, h, i, j: (b, h // g, j, 0))
-    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, bq // w, w),
+                            lambda b, h, i, j: (b, h, i, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, masked=masked,
-                          num_k=Tk // bk),
-        grid=(B, H, Tq // bq, Tk // bk),
+                          tq=tq, tk=tk),
+        grid=(B, H, nqm, nkm),
         in_specs=[_smem_spec(), _smem_spec(),
                   q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=_SWEEP_LAST,
         interpret=interpret,
         name=prof.FLASH_DQ,
     )(*offs, qt, kt, vt, dot, lse, dvec)
 
-    # transposed grid: K outer, (group q-head, Q block) inner — grid dim 1
-    # walks KV heads, the q-head within the group rides the inner sweep
-    nq = Tq // bq
+    # transposed grid: K majors outer, (group q-head, Q major) inner — grid
+    # dim 1 walks KV heads, the q-head within the group rides the sweep
     q_spec_t = pl.BlockSpec(
-        (1, 1, bq, D), lambda b, hk, j, t: (b, hk * g + t // nq, t % nq, 0))
+        (1, 1, bq, D),
+        lambda b, hk, j, t: (b, hk * g + t // nqm, t % nqm, 0))
     kv_spec_t = pl.BlockSpec((1, 1, bk, D),
                              lambda b, hk, j, t: (b, hk, j, 0))
     row_spec_t = pl.BlockSpec(
-        (1, 1, bq, 1), lambda b, hk, j, t: (b, hk * g + t // nq, t % nq, 0))
+        (1, 1, 1, bq // w, w),
+        lambda b, hk, j, t: (b, hk * g + t // nqm, t % nqm, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale,
-                          masked=masked, num_q=nq, q_per_kv=g),
-        grid=(B, Hk, Tk // bk, g * nq),
+                          masked=masked, tq=tq, tk=tk, num_q_major=nqm),
+        grid=(B, Hk, nkm, g * nqm),
         in_specs=[_smem_spec(), _smem_spec(),
                   q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
                   row_spec_t],
@@ -421,6 +758,7 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        compiler_params=_SWEEP_LAST,
         interpret=interpret,
         name=prof.FLASH_DKV,
     )(*offs, qt, kt, vt, dot, lse, dvec)
@@ -459,8 +797,8 @@ def _flash_with_lse_bwd(masked, scale, block_q, block_k, interpret, res,
     # softmax-jacobian row term with opposite sign to D_i, so both ride
     # the same dvec input of the kernels (d lse / d s_k = p_k).
     dvec = (jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)[..., None]
-            - g_lse.astype(jnp.float32))                 # [B, H, Tq, 1]
+                    axis=-1).transpose(0, 2, 1).reshape(lse.shape)
+            - g_lse.astype(jnp.float32))      # row-major over Tq, as lse
     dq, dk, dv = _flash_backward(
         q, k, v, q_off, k_off, g, lse, dvec, masked, scale, block_q,
         block_k, interpret)
@@ -477,19 +815,21 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
                            block_k, interpret)[0]
 
 
-def kernel_supported(q_shape, k_shape, block_q: int, block_k: int) -> bool:
-    """Static shape gate for the Pallas path: block sizes must tile the
-    sequence (no ragged tails in the kernel) and D should be lane-friendly."""
+def kernel_supported(q_shape, k_shape, block_q: Optional[int] = None,
+                     block_k: Optional[int] = None) -> bool:
+    """Static shape gate for the Pallas path: block sizes, where given,
+    must tile the sequence (no ragged tails in the kernel) and D should be
+    lane-friendly."""
     B, Tq, H, D = q_shape
     Tk = k_shape[1]
-    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    bq, bk = min(block_q or Tq, Tq), min(block_k or Tk, Tk)
     if q_shape[2] % k_shape[2]:   # GQA: kv heads must divide q heads
         return False
     return Tq % bq == 0 and Tk % bk == 0 and D % 8 == 0
 
 
-def _use_kernel(interpret: Optional[bool], q_shape, k_shape, block_q: int,
-                block_k: int) -> bool:
+def _use_kernel(interpret: Optional[bool], q_shape, k_shape,
+                block_q: Optional[int], block_k: Optional[int]) -> bool:
     """The one platform rule both entry points share. ``interpret=None``
     (every production caller): the compiled kernels on a TPU backend, the
     blockwise scan — their documented platform twin — anywhere else. An
@@ -516,8 +856,8 @@ def flash_attention(
     *,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Fused attention; same signature/semantics as
@@ -525,7 +865,9 @@ def flash_attention(
     score matrix. On a TPU backend: the compiled Pallas kernels, or a
     ValueError for a shape they refuse. Off TPU: the blockwise scan, same
     math (``interpret=True`` runs the kernels in the Pallas interpreter
-    anywhere — tests only). See :func:`_use_kernel`.
+    anywhere — tests only). See :func:`_use_kernel`. ``block_q`` /
+    ``block_k`` bound the kernels' score tiles from above; left out, the
+    tiles are :func:`flash_plan`'s for the shape.
 
     Grouped-query attention: K/V may carry fewer heads than Q (kv divides
     q, q-head h reads kv head h // group). The kernel path streams the
@@ -538,7 +880,7 @@ def flash_attention(
         return _flash(q, k, v, causal, scale, block_q, block_k,
                       bool(interpret))
     return blockwise_attention(q, k, v, causal=causal, scale=scale,
-                               block_k=block_k)
+                               block_k=block_k or 512)
 
 
 # -------------------------------------------------------- ring flash attn
@@ -550,8 +892,8 @@ def ring_flash_attention_local(
     axis_name: str,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Ring attention with the flash kernel doing each step's blockwise
@@ -603,13 +945,13 @@ def ring_flash_attention_local(
         src = ((r - s) % n).astype(jnp.int32)     # original owner of k_cur
         if use_kernel:
             o_s, lse_s = _flash_with_lse(
-                q, k_cur, v_cur, q_off, src * Tk, causal, scale,
-                min(block_q, Tq), min(block_k, Tk), interpret)
-            lse_s = lse_s[..., 0].transpose(0, 2, 1)   # -> [B, Tq, H]
+                q, k_cur, v_cur, q_off, src * Tk, causal, scale, block_q,
+                block_k, interpret)
+            lse_s = lse_s.reshape(B, H, Tq).transpose(0, 2, 1)  # [B, Tq, H]
         else:
             o_s, lse_s = blockwise_attention(
                 q, k_cur, v_cur, causal=causal, scale=scale,
-                block_k=block_k, q_off=q_off, k_off=src * Tk,
+                block_k=block_k or 512, q_off=q_off, k_off=src * Tk,
                 return_lse=True)
         lse_new = jnp.logaddexp(lse_run, lse_s)
         acc = (acc * jnp.exp(lse_run - lse_new)[..., None]

@@ -11,8 +11,10 @@ import numpy as np
 import pytest
 
 from jax import shard_map
+from minips_tpu.ops import flash_attention as fa
 from minips_tpu.ops.flash_attention import (blockwise_attention,
-                                            flash_attention,
+                                            computed_share,
+                                            flash_attention, flash_plan,
                                             kernel_supported)
 from minips_tpu.parallel.ring_attention import reference_attention
 
@@ -31,11 +33,47 @@ def test_blockwise_matches_oracle(causal):
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
+# Shapes that exercise the in-kernel sweep: (qkv kwargs, block_q, block_k,
+# rows of a head that fit the resident budget, or None for the module's own).
+SWEEPS = {
+    "q_tile_over_k_tile": (dict(), 32, 16, None),
+    "k_tile_over_q_tile": (dict(), 16, 32, None),
+    "one_block": (dict(T=32), 32, 32, None),
+    "d64_odd_heads": (dict(B=1, H=3, D=64), 16, 16, None),
+    "scale_stays_on_scores": (dict(B=1, D=24), 16, 16, None),  # 24**-.5
+    "major_blocks": (dict(B=1, T=128), 8, 8, 64),
+    # tiles of 256: the tile ON the diagonal is computed as 3 of its 4
+    # 128-wide groups
+    "diagonal_groups": (dict(B=1, T=512, H=1), 256, 256, None),
+    # forward tiles of 128 under backward tiles of 256: a backward tile
+    # spans two logsumexp rows (the module's own tiles do at T 1024)
+    "two_lse_rows_a_tile": (dict(B=1, T=512, H=1), None, None, None),
+}
+
+
+def _sweep_case(monkeypatch, name, **over):
+    kw, bq, bk, rows = SWEEPS[name]
+    q, k, v = _qkv(**{**kw, **over})
+    if name == "two_lse_rows_a_tile":
+        monkeypatch.setattr(fa, "_FWD_TILE", (128, 128))
+        monkeypatch.setattr(fa, "_BWD_TILE", (256, 256))
+        plan = flash_plan(512, 512, q.shape[3], q.dtype.itemsize)
+        assert plan[:4] == (128, 128, 256, 256)
+    if rows is not None:
+        monkeypatch.setattr(fa, "_RESIDENT_BYTES",
+                            rows * q.shape[3] * q.dtype.itemsize)
+        plan = flash_plan(q.shape[1], k.shape[1], q.shape[3],
+                          q.dtype.itemsize, bq, bk)
+        assert plan.major_q < q.shape[1] and plan.major_k < k.shape[1]
+    return q, k, v, bq, bk
+
+
+@pytest.mark.parametrize("sweep", list(SWEEPS))
 @pytest.mark.parametrize("causal", [False, True])
-def test_pallas_kernel_matches_oracle_interpret(causal):
-    q, k, v = _qkv()
-    assert kernel_supported(q.shape, k.shape, 32, 16)
-    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=16,
+def test_pallas_kernel_matches_oracle_interpret(causal, sweep, monkeypatch):
+    q, k, v, bq, bk = _sweep_case(monkeypatch, sweep)
+    assert kernel_supported(q.shape, k.shape, bq, bk)
+    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
                           interpret=True)
     ref = reference_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
@@ -70,20 +108,29 @@ def test_gqa_forward_matches_repeat_oracle(causal, hk):
     np.testing.assert_allclose(out_kn, ref, atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("major", [False, True])
 @pytest.mark.parametrize("hk", [1, 2])
 @pytest.mark.parametrize("causal", [False, True])
-def test_gqa_gradients_match_repeat_oracle(causal, hk):
+def test_gqa_gradients_match_repeat_oracle(causal, hk, major, monkeypatch):
     """dK/dV under GQA must aggregate over every q-head in the group —
-    the kernel's combined (group-head, Q-block) sweep vs AD through the
-    explicit repeat (whose transpose is exactly that group-sum)."""
+    the kernel's combined (group-head, Q-major) sweep vs AD through the
+    explicit repeat (whose transpose is exactly that group-sum). With
+    ``major`` the sequence is walked in two major blocks as well."""
     q, k, v = _qkv_gqa(T=32, Hk=hk)
+    bq = bk = 16
+    if major:
+        q, k, v = _qkv_gqa(B=1, T=64, Hk=hk)
+        bq = bk = 4
+        monkeypatch.setattr(fa, "_RESIDENT_BYTES", 32 * 16 * 4)
+        plan = flash_plan(64, 64, 16, 4, bq, bk)
+        assert (plan.major_q, plan.major_k) == (32, 32)
 
     def loss_ref(q, k, v):
         return jnp.sum(_gqa_oracle(q, k, v, causal) ** 2)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=16,
-                                       block_k=16, interpret=True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=bq,
+                                       block_k=bk, interpret=True) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -106,16 +153,21 @@ def test_blockwise_ragged_tail_still_exact():
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("sweep", list(SWEEPS))
 @pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_oracle(causal):
-    q, k, v = _qkv(T=32)
+def test_gradients_match_oracle(causal, sweep, monkeypatch):
+    q, k, v, bq, bk = _sweep_case(monkeypatch, sweep)
+    if sweep not in ("major_blocks", "diagonal_groups",
+                     "two_lse_rows_a_tile"):
+        # as before: half the sequence, 16 x 16
+        q, k, v, bq, bk = q[:, :32], k[:, :32], v[:, :32], 16, min(bk, 16)
 
     def loss_ref(q, k, v):
         return jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=16,
-                                       block_k=16, interpret=True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=bq,
+                                       block_k=bk, interpret=True) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -208,32 +260,68 @@ def test_ring_flash_gradients_match_oracle():
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
-def test_kernel_lse_cotangent_matches_jnp():
+# One ring step seen from a shard of T rows (32 where not given):
+# (q_off, k_off, block, budget[, T]).
+RING_STEPS = {
+    "crosses_diagonal": (16, 0, 16, None),
+    "diagonal": (32, 32, 16, None),
+    "wholly_kept": (64, 0, 16, None),
+    "wholly_masked": (0, 32, 16, None),
+    "kept_tiles_q_over_k": (40, 0, 8, None),
+    "major_blocks": (16, 0, 2, 16 * 16 * 4),
+    "diagonal_in_groups": (512, 512, 256, None, 512),
+    "tiles_of_256_off_the_diagonal": (128, 0, 256, None, 512),
+}
+
+
+@pytest.mark.parametrize("step", list(RING_STEPS))
+def test_kernel_lse_cotangent_matches_jnp(step, monkeypatch):
     """The kernels' custom VJP must propagate the lse output's cotangent
     (the ring merge differentiates through lse). Compare against the
-    pure-jnp offset twin under a loss that uses BOTH outputs."""
+    pure-jnp offset twin under a loss that uses BOTH outputs, for every
+    kind of ring step: the sweep's bounds come from these offsets."""
     from minips_tpu.ops.flash_attention import _flash_with_lse
 
-    q, k, v = _qkv(B=1, T=32, H=2, D=16, seed=7)
-    q_off = jnp.int32(16)
-    k_off = jnp.int32(0)
+    off_q, off_k, blk, budget, T = (RING_STEPS[step] + (32,))[:5]
+    q, k, v = _qkv(B=1, T=T, H=2, D=16, seed=7)
+    q_off, k_off = jnp.int32(off_q), jnp.int32(off_k)
+    if budget is not None:
+        monkeypatch.setattr(fa, "_RESIDENT_BYTES", budget)
+        plan = flash_plan(32, 32, 16, 4, blk, blk)
+        assert (plan.major_q, plan.major_k) == (16, 16)
+
+    def kernel(q, k, v):
+        return _flash_with_lse(q, k, v, q_off, k_off, True, 16 ** -0.5,
+                               2 * blk if step.endswith("q_over_k") else blk,
+                               blk, True)
+
+    def twin(q, k, v):
+        return blockwise_attention(q, k, v, causal=True, scale=16 ** -0.5,
+                                   block_k=16, q_off=q_off, k_off=k_off,
+                                   return_lse=True)
 
     def loss_kernel(q, k, v):
-        out, lse = _flash_with_lse(q, k, v, q_off, k_off, True,
-                                   16 ** -0.5, 16, 16, True)
-        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse[..., 0]))
+        out, lse = kernel(q, k, v)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
 
     def loss_jnp(q, k, v):
-        out, lse = blockwise_attention(q, k, v, causal=True,
-                                       scale=16 ** -0.5, block_k=16,
-                                       q_off=q_off, k_off=k_off,
-                                       return_lse=True)
-        # jnp twin returns lse as [B, Tq, H]; kernel as [B, H, Tq, 1]
+        out, lse = twin(q, k, v)
+        # jnp twin returns lse as [B, Tq, H]; the kernel tile by tile,
+        # [B, H, Q majors, tiles, tile_q]: the same rows in the same order
         return jnp.sum(out ** 2) + jnp.sum(jnp.sin(
             lse.transpose(0, 2, 1)))
 
+    (out_k, lse_k), (out_j, lse_j) = kernel(q, k, v), twin(q, k, v)
+    if step == "wholly_masked":   # no live tile: zeros, at weight exp(-1e30)
+        assert not np.any(np.asarray(out_k)) and np.all(lse_k < -1e29)
+    else:
+        np.testing.assert_allclose(out_k, out_j, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(lse_k.reshape(1, 2, T),
+                               lse_j.transpose(0, 2, 1), rtol=1e-5)
     g_k = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
     g_j = jax.grad(loss_jnp, argnums=(0, 1, 2))(q, k, v)
+    if step == "wholly_masked":   # (the twin averages V over masked keys)
+        g_j = [jnp.zeros_like(x) for x in g_j]
     for a, b in zip(g_k, g_j):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
@@ -298,12 +386,88 @@ def test_transformer_apply_flash_matches_reference():
         np.testing.assert_allclose(a, b, atol=5e-3, rtol=5e-2)
 
 
-def test_bfloat16_inputs():
-    q, k, v = _qkv(dtype=jnp.bfloat16)
-    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+@pytest.mark.parametrize("sweep", ["q_tile_over_k_tile", "one_block",
+                                   "d64_odd_heads", "major_blocks"])
+def test_bfloat16_inputs(sweep, monkeypatch):
+    q, k, v, bq, bk = _sweep_case(monkeypatch, sweep, dtype=jnp.bfloat16)
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
                           interpret=True)
     ref = reference_attention(q.astype(jnp.float32), k.astype(jnp.float32),
                               v.astype(jnp.float32), causal=True)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(out.astype(np.float32), ref, atol=2e-2,
                                rtol=2e-2)
+
+
+def test_bfloat16_gradients_keep_the_input_dtype():
+    q, k, v = _qkv(T=32, dtype=jnp.bfloat16)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+
+    def loss(attn, **kw):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=True, **kw).astype(jnp.float32) ** 2)
+
+    g_ref = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(*f32)
+    g_fl = jax.grad(loss(flash_attention, block_q=16, block_k=16,
+                         interpret=True), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_fl, g_ref):
+        assert a.dtype == jnp.bfloat16
+        err = np.linalg.norm(a.astype(np.float32) - b) / np.linalg.norm(b)
+        assert err < 2e-2, err
+
+
+# ---- the plan: what the kernels and these tests both call
+@pytest.mark.parametrize("tiles, share", [
+    ((512, 512), 0.75),          # the grid-per-pair kernels' blocks: to beat
+    ((256, 256), 0.625), ((128, 128), 0.5625), ((1024, 1024), 1.0),
+    ((256, 512), 0.75), ((512, 256), 0.75), ((128, 256), 0.625),
+])
+def test_computed_share_of_the_causal_scores(tiles, share):
+    assert computed_share(1024, 1024, *tiles) == share
+
+
+@pytest.mark.parametrize("tile, share", [
+    (256, 0.5625), (512, 0.5625), (1024, 0.5625), (128, 0.5625)])
+def test_a_cut_diagonal_computes_the_share_of_128_blocks(tile, share):
+    """The backward's tiles on the diagonal count their live 128-wide
+    groups only: whatever the tile, (8 + 1) / 16 of a 1,024 square."""
+    assert computed_share(1024, 1024, tile, tile, cut=True) == share
+
+
+def test_plan_at_the_benchmark_shape_beats_the_old_blocks():
+    plan = flash_plan(1024, 1024, 64, 2)     # GPT-2 XL's head, bf16
+    assert plan.causal_share == computed_share(
+        1024, 1024, plan.tile_q, plan.tile_k) <= 0.75
+    assert plan.bwd_share == computed_share(
+        1024, 1024, plan.bwd_q, plan.bwd_k, cut=True) < 0.75
+    assert (plan.major_q, plan.major_k) == (1024, 1024)   # a head resident
+    for tile in plan[:4]:
+        assert tile % 128 == 0
+    assert plan.bwd_q % plan.tile_q == 0   # whole logsumexp rows a tile
+
+
+@pytest.mark.parametrize("T, D, itemsize", [
+    (32768, 64, 2), (8192, 128, 2), (16384, 64, 4)])
+def test_plan_walks_a_long_sequence_in_major_blocks(T, D, itemsize):
+    plan = flash_plan(T, T, D, itemsize)
+    for major, tiles in ((plan.major_q, (plan.tile_q, plan.bwd_q)),
+                         (plan.major_k, (plan.tile_k, plan.bwd_k))):
+        assert major < T and T % major == 0
+        assert major * D * itemsize <= fa._RESIDENT_BYTES
+        assert all(major % t == 0 for t in tiles)
+
+
+@pytest.mark.parametrize("T, bq, bk, fwd, bwd", [
+    (64, 32, 16, (32, 16), (32, 16)),   # a caller's blocks bound every tile
+    (64, None, None, (64, 64), (64, 64)),   # a short sequence is one tile
+    (192, None, None, (96, 96), (96, 96)),  # no 128-multiple divides: <= 128
+    (384, None, None, (384, 384), (384, 384)),
+    (768, None, None, (384, 384), (768, 768)),  # bwd_q: whole lse rows
+    (1024, 128, None, (128, fa._FWD_TILE[1]), (128, fa._BWD_TILE[1])),
+    (4096, None, None, fa._FWD_TILE, fa._BWD_TILE),
+])
+def test_plan_tiles_divide_and_stay_within_the_blocks(T, bq, bk, fwd, bwd):
+    plan = flash_plan(T, T, 64, 2, bq, bk)
+    assert (plan.tile_q, plan.tile_k) == fwd
+    assert (plan.bwd_q, plan.bwd_k) == bwd
+    assert all(T % t == 0 for t in plan[:4])
