@@ -13,6 +13,10 @@ means:
 - ``kernels``: the flash kernels against ``reference_attention`` on a small
   input (GQA 32/4 forward and gradients; the sp ring path on the devices
   present) and the opt-in ``gather_rows`` kernel against XLA's gather.
+- ``zaya``: one forward and backward of a one-layer ZAYA1 block
+  (``models/zaya.py``: compressed convolutional attention, the dropless
+  top-1 expert layer holding 8 of 16 experts) at published widths, T 1,024,
+  against the benchmark's plain float32 reference.
 
 It fails (non-zero exit, no result line) if JAX finds no TPU, if a loss is
 non-finite or does not fall, if the LM step holds fewer than three compiled
@@ -316,6 +320,74 @@ def _leg_kernels(on_tpu: bool) -> dict:
     return facts
 
 
+def _leg_zaya(on_tpu: bool, here: str) -> dict:
+    """One forward and backward of a one-layer ZAYA block, bfloat16 worker
+    math as the cell runs it, against the plain float32 reference the
+    benchmark keeps (published widths on the chip, toy widths off it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from minips_tpu.models import zaya
+    from minips_tpu.tables.dense import cast_floating
+
+    bench = os.path.join(here, "bench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from benchlib.reference import zaya_ref
+
+    with open(os.path.join(bench, "configs", "zaya1-8b.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=1)
+    B, T = 2, 1024
+    if not on_tpu:
+        config.update(hidden_size=32, head_dim=8, moe_intermediate_size=16,
+                      router_hidden_size=8, vocab_size=128, num_experts=2,
+                      held_experts=[0, 2], head_chunk=16,
+                      published=dict(config["published"], num_experts=4))
+        T = 64
+    m = zaya.from_config(config)
+    params = zaya.init(jax.random.PRNGKey(0), m)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, T + 1), 0, m.vocab)
+    cd = jnp.bfloat16
+    z = zaya_ref._sizes(config)
+    # the balancing bias both start from: the reference's own centring
+    bias = jnp.asarray(zaya_ref.centred_bias(params, toks, z, B))
+    loss, grads, _ = jax.jit(lambda p, t: zaya.grad_fn(
+        cast_floating(p, cd), {"tokens": t}, bias, m, compute_dtype=cd,
+        attn_impl="flash", head_chunk=int(config["head_chunk"])))(
+        params, toks)
+    (want, chosen), want_g = jax.jit(jax.value_and_grad(
+        lambda p, t: zaya_ref.loss_sum(p, t, bias, z, False),
+        has_aux=True))(params, toks)
+    want = float(want) / (B * T)
+    mine = jax.jit(lambda p, t: zaya.routing_stats(
+        p, {"tokens": t}, bias, m, compute_dtype=cd))(params, toks)
+    flips = int(jnp.sum(mine["expert"] != chosen))
+    _check(math.isfinite(float(loss)) and abs(float(loss) - want)
+           < 2e-3 * want, f"zaya: loss {float(loss)} against the "
+           f"reference's {want}")
+    # the norm of each leaf's gradient; a token whose top-1 choice flips
+    # between bfloat16 and float32 moves its expert's leaves, so the
+    # tolerance is bfloat16's plus the share of tokens that flipped
+    tol = 0.05 + 4.0 * flips / (B * T)
+    worst = 0.0
+    norms = [float(jnp.linalg.norm(x.astype(jnp.float32)))
+             for x in jax.tree.leaves(want_g)]
+    med = sorted(norms)[len(norms) // 2] / (B * T)
+    for g, w in zip(jax.tree.leaves(grads), norms):
+        w = w / (B * T)
+        gap = abs(float(jnp.linalg.norm(g.astype(jnp.float32))) - w) \
+            / max(w, med)
+        worst = max(worst, gap)
+    _check(worst < tol, f"zaya: a leaf's gradient norm is {worst:.4f} off "
+           f"the reference's (tolerance {tol:.4f}, {flips} tokens flipped)")
+    return {"shape": {"B": B, "T": T, "dim": m.dim, "heads": m.heads,
+                      "kv_heads": m.kv_heads, "head_dim": m.head_dim,
+                      "experts_held": list(m.held), "experts": m.experts},
+            "loss": float(loss), "reference_loss": want,
+            "tokens_flipped": flips, "grad_norm_worst_gap": round(worst, 5),
+            "tokens_held": mine["tokens_held"].tolist()}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse-cpu", action="store_true",
@@ -358,6 +430,8 @@ def main() -> int:
     legs["lm"] = _leg_lm(argv["lm"], devices, compile_log, on_tpu)
     gc.collect()
     legs["kernels"] = _leg_kernels(on_tpu)
+    gc.collect()
+    legs["zaya"] = _leg_zaya(on_tpu, here)
     _check(not native_lib.loaded_libs(),
            f"a native library was loaded: {native_lib.loaded_libs()}")
 

@@ -22,13 +22,24 @@ demonstrates the long-context/model-parallel paths end-to-end. Layouts:
   experts via two all_to_alls per block (``--experts``, ``--k_top``,
   ``--capacity``).
 
+``--model_config FILE`` (dp layout) takes the model from a configuration
+file of published keys instead of the flags: the ZAYA1-shaped decoder of
+``models/zaya.py`` (compressed convolutional attention, a dropless top-1
+expert layer that is told which experts it holds), trained through the
+same ``DenseTable.make_step``; ``bench/configs/zaya1-8b.json`` is such a
+file, and the benchmark's adapter calls :func:`zaya_dp_step` as ``run``
+does.
+
 Usage: python -m minips_tpu.apps.lm_example --num_iters 200 --layout sp
        python -m minips_tpu.apps.lm_example --layout tp --tp 2
+       python -m minips_tpu.apps.lm_example --seq_len 8192 --batch_size 4 \
+           --model_config bench/configs/zaya1-8b.json
 """
 
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +52,7 @@ from minips_tpu.core.config import Config, TableConfig, TrainConfig
 from minips_tpu.data import synthetic
 from minips_tpu.data.loader import BatchIterator
 from minips_tpu.models import transformer as tfm
+from minips_tpu.models import zaya
 from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
 from minips_tpu.tables.dense import DenseTable
 from minips_tpu.train.loop import TrainLoop
@@ -71,6 +83,13 @@ def _flags(parser):
                         help="ep layout: slots per expert per source "
                              "device (0 = 2x the even share)")
     parser.add_argument("--seq_len", type=int, default=128)
+    parser.add_argument("--model_config", default=None,
+                        help="dp layout: a configuration file of published "
+                             "keys (hidden_size, num_experts, cca_time0, "
+                             "...) names the model, its attention, head "
+                             "chunk and worker precision, in place of "
+                             "--dim/--depth/--heads/--attn/--dtype "
+                             "(models/zaya.py)")
     parser.add_argument("--tp", type=int, default=2,
                         help="model-axis size for tp/pp layouts")
     parser.add_argument("--microbatches", type=int, default=4,
@@ -231,6 +250,87 @@ def _updater_kwargs(cfg, args, params):
     return kw
 
 
+def zaya_dp_step(config: dict, mesh, params, first_batch, *, updater: str,
+                 lr, name: str = "lm"):
+    """The dp layout's table and fused step for a model taken from a
+    configuration file: ``(model, table, step, stats)``. ``config`` holds
+    the published keys (``zaya.from_config``) and how it is run: ``attn``,
+    ``head_chunk``, ``compute_dtype``, ``router_bias_rate``. ``params`` is
+    the caller's initial tree (the app draws ``zaya.init``, the benchmark
+    makes its own from its seed); the table owns the state from here on,
+    the router's balancing bias with it (``table.state``, started by
+    ``zaya.centred_bias`` over ``first_batch``, as ``prep`` places it).
+    ``stats(table.pull(), batch, table.state)`` is the routing observer
+    (``zaya.routing_stats``), jitted apart from the step."""
+    m = zaya.from_config(config)
+    cd = jnp.dtype(config.get("compute_dtype", "float32"))
+    how = dict(compute_dtype=cd, attn_impl=config.get("attn", "flash"))
+    stats = jax.jit(functools.partial(zaya.routing_stats, m=m, **how))
+    bias = zaya.centred_bias(lambda b: stats(params, first_batch, b), m)
+    table = DenseTable(params, mesh, updater=updater, lr=lr, name=name)
+    step = table.make_step(
+        functools.partial(zaya.grad_fn, m=m, axis_name=DATA_AXIS, **how,
+                          head_chunk=int(config.get("head_chunk", 0))),
+        batch_spec=P(DATA_AXIS),
+        compute_dtype=None if cd == jnp.float32 else cd, state=bias)
+    return m, table, step, stats
+
+
+def _run_model_config(cfg, args, mesh, layout, seq_len):
+    """``--model_config``: (the data, table, step, prep, the routing
+    observer as ``TrainLoop``'s ``extra_metrics``)."""
+    if layout != "dp":
+        raise SystemExit(f"--model_config is only wired into --layout dp "
+                         f"(got {layout})")
+    for flag in ("dim", "depth", "heads", "kv_heads"):
+        if getattr(args, flag, None) is not None:
+            raise SystemExit(f"--{flag} and --model_config both name the "
+                             "model: the file decides")
+    for flag, default in (("rope", False), ("dropout", 0.0), ("generate", 0),
+                          ("attn", "reference"), ("remat", False),
+                          ("head_chunk", 0), ("dtype", "float32"),
+                          ("accum", 1), ("comm", "float32"),
+                          ("clip_norm", 0.0), ("weight_decay", None)):
+        if getattr(args, flag, default) != default:
+            raise SystemExit(f"--{flag} is not wired into --model_config: "
+                             "the file says how the model is run")
+    with open(args.model_config) as f:
+        config = json.load(f)
+    data = _load_data(cfg, args, seq_len, int(config["vocab_size"]))
+    batch_sharding = NamedSharding(mesh, P(DATA_AXIS))
+    last = {}
+
+    @prof.span(prof.FEED)
+    def prep(batch):
+        last["batch"] = {"tokens": jax.device_put(
+            jnp.asarray(batch["tokens"]), batch_sharding)}
+        return last["batch"]
+
+    params = zaya.init(jax.random.PRNGKey(cfg.train.seed),
+                       zaya.from_config(config))
+    first = {"tokens": data["tokens"][: cfg.train.batch_size]}
+    _, table, step, stats = zaya_dp_step(
+        config, mesh, params, prep(first), updater=cfg.table.updater,
+        lr=_lr_schedule(cfg, args), name=cfg.table.name)
+
+    def routing_metrics() -> dict:
+        """The routing counters of the last batch fed, at ``log_every``."""
+        with prof.span(prof.LOOP_READBACK):
+            st = stats(table.pull(), last["batch"], table.state)
+            st = jax.device_get({k: st[k] for k in (
+                "tokens_held", "absent_share", "load_max_over_mean")})
+            prof.counter(prof.MOE_TOKENS_HELD,
+                         int(st["tokens_held"].sum()))
+            prof.counter(prof.MOE_LOAD_MAX_OVER_MEAN,
+                         float(st["load_max_over_mean"].max()))
+        return {"moe_tokens_held": st["tokens_held"].tolist(),
+                "moe_absent_share": st["absent_share"].tolist(),
+                "moe_load_max_over_mean":
+                    st["load_max_over_mean"].tolist()}
+
+    return data, table, step, prep, routing_metrics
+
+
 def run(cfg: Config, args, metrics) -> dict:
     seq_len = getattr(args, "seq_len", 128)
     layout = getattr(args, "layout", "dp")
@@ -278,17 +378,24 @@ def run(cfg: Config, args, metrics) -> dict:
     if seq_len % n_shards:
         raise SystemExit(f"--seq_len {seq_len} must divide by the "
                          f"{n_shards}-way mesh")
-    model = _model_cfg(args, seq_len)
-    data = _load_data(cfg, args, seq_len)
-    params = tfm.init(jax.random.PRNGKey(cfg.train.seed), **model)
-    table = DenseTable(params, mesh, updater=cfg.table.updater,
-                       lr=_lr_schedule(cfg, args), name=cfg.table.name,
-                       updater_kwargs=_updater_kwargs(cfg, args, params))
-    # the table owns the parameters from here on; the template would sit
-    # on the default device for the whole run (1.6 GB at d=2048 x 8)
-    del params
+    model_file = getattr(args, "model_config", None)
+    routing_metrics = None
+    if model_file:
+        data, table, step, prep, routing_metrics = _run_model_config(
+            cfg, args, mesh, layout, seq_len)
+        heads = None
+    else:
+        model = _model_cfg(args, seq_len)
+        data = _load_data(cfg, args, seq_len)
+        params = tfm.init(jax.random.PRNGKey(cfg.train.seed), **model)
+        table = DenseTable(params, mesh, updater=cfg.table.updater,
+                           lr=_lr_schedule(cfg, args), name=cfg.table.name,
+                           updater_kwargs=_updater_kwargs(cfg, args, params))
+        # the table owns the parameters from here on; the template would
+        # sit on the default device for the whole run (1.6 GB at d=2048 x 8)
+        del params
+        heads = model["heads"]
     log_tables_built(metrics, (table.params, table.opt_state))
-    heads = model["heads"]
 
     ckpt, start_step = _maybe_checkpointer(cfg, args, table)
 
@@ -302,7 +409,9 @@ def run(cfg: Config, args, metrics) -> dict:
         # the accum fold reshapes every batch leaf into microbatches,
         # which a [2]-shaped key cannot survive
         raise SystemExit("--dropout is incompatible with --accum > 1")
-    if layout == "dp":
+    if model_file:
+        pass        # step and prep came with the table, above
+    elif layout == "dp":
         remat = getattr(args, "remat", False)
         if remat and getattr(args, "remat_mode", "full") != "full":
             remat = args.remat_mode
@@ -363,7 +472,7 @@ def run(cfg: Config, args, metrics) -> dict:
                      batch_size=cfg.train.batch_size,
                      checkpointer=ckpt,
                      checkpoint_every=ckpt_every,
-                     step_offset=start_step)
+                     step_offset=start_step, extra_metrics=routing_metrics)
     # A completed run resumed again is a no-op, not an extra step.
     remaining = max(cfg.train.num_iters - start_step, 0)
     losses = loop.run(remaining)
@@ -399,13 +508,13 @@ def run(cfg: Config, args, metrics) -> dict:
     return out
 
 
-def _load_data(cfg, args, seq_len):
+def _load_data(cfg, args, seq_len, vocab: int = MODEL["vocab"]):
     path = getattr(args, "data_file", None)
     if path:
         from minips_tpu.data.text import read_lm_file
 
         return read_lm_file(path, seq_len, max_windows=65536)
-    return synthetic.lm_sequences(2048, seq_len, MODEL["vocab"],
+    return synthetic.lm_sequences(2048, seq_len, vocab,
                                   seed=cfg.train.seed)
 
 

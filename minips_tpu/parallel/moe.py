@@ -20,12 +20,20 @@ weights (straight-through on the top-1 choice, as in Switch).
 ``moe_apply_dense`` is the unsharded oracle: identical numerics (including
 capacity drops) computed without collectives, used by tests and usable on
 one device.
+
+``moe_apply_dropless`` is the other kind of expert layer: no capacity, no
+drops, no one-hot dispatch tensor. The caller routes; the layer is told
+which experts of all it holds, sorts the tokens by expert, runs grouped
+matrix products over the experts held and scatters back. It is what a
+chip's share of a larger expert layer computes (models/zaya.py).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from minips_tpu.utils import profiling as prof
 
 
 def init_moe(key, num_experts: int, dim: int, hidden: int):
@@ -153,3 +161,87 @@ def ep_specs(axis_name: str = "data"):
     from jax.sharding import PartitionSpec as P
 
     return {"router": P(), "w_in": P(axis_name), "w_out": P(axis_name)}
+
+
+# ------------------------------------------------ dropless, a chip's share
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """``x[perm]`` for a permutation whose inverse is ``inv``: the
+    cotangent is a gather by the inverse, never a scatter."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], (perm, inv)
+
+
+def _permute_bwd(res, g):
+    perm, inv = res
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def dropless_dispatch(expert, held: tuple[int, int]):
+    """Sort ``expert`` [N] (ids over ALL experts) for a worker that holds
+    the experts ``held = (lo, hi)``: returns (order, inverse, group_sizes
+    [hi - lo]). ``order`` is stable, tokens of held experts first in
+    expert order, tokens of absent experts last; no shape depends on the
+    routing and no token is dropped."""
+    lo, hi = held
+    n_held = hi - lo
+    local = expert.astype(jnp.int32) - lo
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    n = expert.shape[0]
+    inverse = jnp.zeros(n, jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    return order, inverse, sizes
+
+
+def moe_apply_dropless(experts, x, expert, gate, *, held: tuple[int, int],
+                       compute_dtype=jnp.bfloat16):
+    """The part of a top-1 gated-SiLU expert layer that the experts held
+    here give: [N, D] -> [N, D] float32.
+
+    ``experts`` holds the stacks ``w_gate``, ``w_up`` [n_held, D, F] and
+    ``w_down`` [n_held, F, D] of the experts ``held = (lo, hi)`` out of
+    however many the router knows; ``expert`` [N] is each token's choice
+    over ALL of them and ``gate`` [N] its probability. A token whose
+    expert is held gets ``gate * (silu(x w_gate) * (x w_up)) w_down``, a
+    token whose expert lives elsewhere gets 0: what the absent experts
+    would add is another worker's part (on one chip there is no
+    exchange, and nothing stands in for one). Tokens are sorted by expert
+    and go through three grouped products (``jax.lax.ragged_dot``); rows
+    beyond the groups' sum are kept zero."""
+    n_held = held[1] - held[0]
+    if experts["w_gate"].shape[0] != n_held:
+        raise ValueError(f"held {held} names {n_held} experts but the "
+                         f"stacks hold {experts['w_gate'].shape[0]}")
+    with jax.named_scope(prof.LM_MOE_DISPATCH):
+        order, inverse, sizes = dropless_dispatch(expert, held)
+        xs = _permute(x.astype(compute_dtype), order, inverse)
+        grouped_rows = (jnp.arange(x.shape[0], dtype=jnp.int32)[:, None]
+                        < jnp.sum(sizes))
+
+    def live(a):
+        return jnp.where(grouped_rows, a, jnp.zeros((), a.dtype))
+
+    def grouped(a, w):
+        # the TPU's grouped-matmul kernel leaves the rows beyond the
+        # groups' sum as it found them, in the product and (through its
+        # transpose) in the cotangent: both sides are masked
+        return live(jax.lax.ragged_dot(live(a), w.astype(compute_dtype),
+                                       sizes))
+
+    with jax.named_scope(prof.LM_MOE_EXPERTS):
+        g = grouped(xs, experts["w_gate"])
+        u = grouped(xs, experts["w_up"])
+        act = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+        ys = grouped(act.astype(compute_dtype), experts["w_down"])
+    with jax.named_scope(prof.LM_MOE_COMBINE):
+        ys = ys.astype(jnp.float32) * gate.astype(jnp.float32)[order][:, None]
+        return _permute(ys, inverse, order)

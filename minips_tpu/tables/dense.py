@@ -141,6 +141,7 @@ class DenseTable:
         self.opt_state = jax.jit(
             self.tx.init, out_shardings=opt_shardings
         )(self.params)
+        self.state = None       # see make_step(state=...)
 
     def _opt_specs_tree(self, opt_state) -> PyTree:
         """Spec tree for the opt state: params-length 1-D leaves range-
@@ -278,6 +279,7 @@ class DenseTable:
         comm: str = "float32",
         accum: int = 1,
         compute_dtype: Optional[Any] = None,
+        state: Optional[PyTree] = None,
     ):
         """Fuse pull → grad → push → update into one SPMD program.
 
@@ -308,6 +310,15 @@ class DenseTable:
         activation memory stays one microbatch's worth (one pull, one
         push, one optimizer step per call, so PS clock semantics are
         unchanged). The leading batch dim must divide by ``accum``.
+
+        ``state`` is what a model carries from step to step beside its
+        parameters and changes outside the gradient (an expert router's
+        balancing bias): a pytree, replicated, never cast. The table keeps
+        it as ``self.state``; ``grad_fn(params, batch, state) -> (loss,
+        grads, state)`` hands on the next one, the same on every worker
+        (it reduces over ``DATA_AXIS`` itself), and the step becomes
+        ``step(params, opt, batch, state) -> (params, opt, loss, state)``
+        (``step_inplace`` passes it through).
         """
         n, padded = self.num_keys, self.padded
         num_workers = self.num_shards
@@ -316,6 +327,10 @@ class DenseTable:
         bspec = batch_spec if batch_spec is not None else P(DATA_AXIS)
         if accum < 1:
             raise ValueError(f"accum must be >= 1, got {accum}")
+        if state is not None and accum > 1:
+            raise ValueError("a step-to-step state and accum > 1: which "
+                             "microbatch's state is handed on is undefined")
+        self.state = state
         from minips_tpu.ops.quantized_comm import (
             _check, quantized_all_gather, quantized_psum_scatter)
         _check(comm)  # eager: tracing happens on first step call
@@ -324,16 +339,17 @@ class DenseTable:
         if cd is not None:
             user_grad_fn = grad_fn
 
-            def grad_fn(params, batch):  # noqa: F811 - deliberate wrap
+            def grad_fn(params, batch, *state):  # noqa: F811 - a wrap
                 # params arrive cast already: the pull phase casts them
-                loss, grads = user_grad_fn(params, cast_floating(batch, cd))
+                loss, grads, *state = user_grad_fn(
+                    params, cast_floating(batch, cd), *state)
                 return (loss.astype(jnp.float32),
-                        cast_floating(grads, jnp.float32))
+                        cast_floating(grads, jnp.float32), *state)
 
-        def _grads_flat(params, batch):
+        def _grads_flat(params, batch, *state):
             if accum == 1:
-                loss, grads = grad_fn(params, batch)
-                return loss, ravel_pytree(grads)[0]
+                loss, grads, *state = grad_fn(params, batch, *state)
+                return (loss, ravel_pytree(grads)[0], *state)
 
             def to_micro(x):
                 if x.shape[0] % accum:
@@ -368,12 +384,12 @@ class DenseTable:
                 return loss_sum, gsum
             return loss_sum / accum, gsum / accum
 
-        def local_step(p_shard, opt_shard, batch):
+        def local_step(p_shard, opt_shard, batch, *state):
             with jax.named_scope(prof.PULL):
                 full = quantized_all_gather(p_shard, DATA_AXIS, comm)
                 params = cast_floating(unravel(full[:n]), cd)
             with jax.named_scope(prof.GRAD):
-                loss, gflat = _grads_flat(params, batch)
+                loss, gflat, *state = _grads_flat(params, batch, *state)
             with jax.named_scope(prof.PUSH):
                 gpad = jnp.zeros(padded, gflat.dtype).at[:n].set(gflat)
                 g_shard = quantized_psum_scatter(gpad, DATA_AXIS, comm)
@@ -390,13 +406,15 @@ class DenseTable:
             with jax.named_scope(prof.UPDATE):
                 updates, opt_shard = tx.update(g_shard, opt_shard, p_shard)
                 p_shard = optax.apply_updates(p_shard, updates)
-            return p_shard, opt_shard, jax.lax.pmean(loss, DATA_AXIS)
+            return (p_shard, opt_shard, jax.lax.pmean(loss, DATA_AXIS),
+                    *state)
 
+        carried = () if state is None else (P(),)
         step = jax.shard_map(
             local_step,
             mesh=self.mesh,
-            in_specs=(self._pspec, self._opt_specs, bspec),
-            out_specs=(self._pspec, self._opt_specs, P()),
+            in_specs=(self._pspec, self._opt_specs, bspec) + carried,
+            out_specs=(self._pspec, self._opt_specs, P()) + carried,
         )
         # a stable name for the program and its trace, whatever the
         # caller called its grad_fn
@@ -408,8 +426,12 @@ class DenseTable:
     def step_inplace(self, step, batch) -> jnp.ndarray:
         """Run a fused step against the table's own state."""
         with prof.span(prof.STEP):
-            self.params, self.opt_state, loss = step(
-                self.params, self.opt_state, batch)
+            if self.state is None:
+                self.params, self.opt_state, loss = step(
+                    self.params, self.opt_state, batch)
+            else:
+                self.params, self.opt_state, loss, self.state = step(
+                    self.params, self.opt_state, batch, self.state)
         return loss
 
     # ------------------------------------------------------------- state I/O
@@ -421,10 +443,13 @@ class DenseTable:
         SURVEY.md §3.5)."""
         from minips_tpu.comm.cluster import host_copy
 
-        return {
+        out = {
             "params": host_copy(self.params),
             "opt_state": jax.tree.map(host_copy, self.opt_state),
         }
+        if self.state is not None:
+            out["state"] = jax.tree.map(host_copy, self.state)
+        return out
 
     def global_arrays(self) -> dict:
         """The live (sharded) jax arrays, for coordinated multi-host
@@ -436,6 +461,10 @@ class DenseTable:
     def load_state_dict(self, state: dict) -> None:
         self.params = jax.device_put(
             jnp.asarray(state["params"]), self._sharding)
+        if self.state is not None and "state" in state:
+            self.state = jax.tree.unflatten(
+                jax.tree.structure(self.state),
+                [jnp.asarray(x) for x in jax.tree.leaves(state["state"])])
         # Graft by leaf order, not structure: a checkpoint roundtrip turns
         # optax's namedtuple states into plain lists, but leaf order is
         # deterministic either way.

@@ -70,11 +70,25 @@ LM_EMBED = "lm.embed"
 LM_ATTN = "lm.attn"
 LM_MLP = "lm.mlp"
 LM_HEAD = "lm.head"
+# the ZAYA block (models/zaya.py): lm.attn.cca lies inside lm.attn, and
+# lm.moe with its four parts stands where a dense block has lm.mlp
+LM_ATTN_CCA = "lm.attn.cca"
+LM_MOE = "lm.moe"
+LM_MOE_ROUTER = "lm.moe.router"
+LM_MOE_DISPATCH = "lm.moe.dispatch"
+LM_MOE_EXPERTS = "lm.moe.experts"
+LM_MOE_COMBINE = "lm.moe.combine"
+# ---- counters of the routing observer (zaya.routing_stats), per log line
+MOE_TOKENS_HELD = "moe.tokens_held"
+MOE_LOAD_MAX_OVER_MEAN = "moe.load_max_over_mean"
 # ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names
 FLASH_FWD = "flash_fwd"
 FLASH_DQ = "flash_dq"
 FLASH_DKV = "flash_dkv"
 GATHER_ROWS = "gather_rows"
+# XLA's own grouped-matmul kernel: what jax.lax.ragged_dot (the dropless
+# expert layer's three products) lowers to on the TPU
+RAGGED_DOT = "ragged-dot-none"
 DENSE_STEP_FN = "ps_dense_step"
 FUSED_STEP_FN = "ps_fused_step"
 
@@ -83,8 +97,9 @@ FUSED_STEP_FN = "ps_fused_step"
 PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
           SPARSE_DEDUP, SPARSE_ADAGRAD_SORTED, SPARSE_ADAGRAD_DENSE,
           SPARSE_ADAM_SORTED, SPARSE_ADAM_DENSE,
-          LM_EMBED, LM_ATTN, LM_MLP, LM_HEAD)
-KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, GATHER_ROWS)
+          LM_EMBED, LM_ATTN, LM_MLP, LM_HEAD, LM_ATTN_CCA, LM_MOE,
+          LM_MOE_ROUTER, LM_MOE_DISPATCH, LM_MOE_EXPERTS, LM_MOE_COMBINE)
+KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, GATHER_ROWS, RAGGED_DOT)
 
 RING_SPANS = 8192
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -143,6 +158,16 @@ def _record_under_open_span(name: str, duration_ns: int) -> None:
     parent = stack[-1] if stack else None
     _record(name, end - duration_ns, end, next(_ids), parent,
             None if parent is None else parent.step)
+
+
+def counter(name: str, value: float) -> None:
+    """A reading that is no time (tokens routed, a load ratio): a record
+    of no duration in the ring, under the span open on this thread, and
+    ``value`` added to the name's total, which ``snapshot`` hands out in
+    place of nanoseconds."""
+    _record_under_open_span(name, 0)
+    with _lock:
+        _counters[name][1] += value
 
 
 def _on_duration(event: str, duration_secs: float, **kw) -> None:

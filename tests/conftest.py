@@ -55,6 +55,20 @@ _BENCH_TESTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "bench", "tests")
 
 
+def add_bench_paths() -> None:
+    """Where ``benchlib`` and ``tiny`` are found: at the END of the path,
+    so that ``tests.conftest`` stays this file and not bench/tests' own.
+    The benchmark's test files come in through it, and so does a tier-1
+    test that holds the program against the benchmark's reference
+    (tests/test_zaya.py): program and yardstick are tested against one
+    file's mathematics."""
+    import sys
+
+    for p in (os.path.dirname(_BENCH_TESTS), _BENCH_TESTS):
+        if p not in sys.path:
+            sys.path.append(p)
+
+
 class BenchSuite(pytest.File):
     """``tests/test_bench_suite.py`` stands for the benchmark's own test
     files (see its docstring): one ``pytest.Module`` each."""
@@ -62,13 +76,8 @@ class BenchSuite(pytest.File):
     def collect(self):
         import glob
         import pathlib
-        import sys
 
-        # where benchlib and tiny are found; at the END of the path, so
-        # that ``tests.conftest`` stays this file and not bench/tests' own
-        for p in (os.path.dirname(_BENCH_TESTS), _BENCH_TESTS):
-            if p not in sys.path:
-                sys.path.append(p)
+        add_bench_paths()
         for path in sorted(glob.glob(os.path.join(_BENCH_TESTS,
                                                   "test_*.py"))):
             yield pytest.Module.from_parent(self, path=pathlib.Path(path))

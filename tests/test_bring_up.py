@@ -50,6 +50,13 @@ def test_chip_smoke_rehearsal_is_labelled_cpu():
     # pull ≡ all-gather, push ≡ reduce-scatter in the dp LM step
     assert {"all-gather", "reduce-scatter"} <= set(
         out["legs"]["lm"]["collectives_asked"])
+    # the ZAYA block against the benchmark's reference, a share of experts
+    zaya = out["legs"]["zaya"]
+    assert zaya["shape"]["experts_held"] == [0, 2]
+    assert zaya["shape"]["experts"] == 4
+    assert abs(zaya["loss"] - zaya["reference_loss"]) < 2e-3 * zaya["loss"]
+    assert zaya["grad_norm_worst_gap"] < 0.05 + 4 * zaya["tokens_flipped"] \
+        / (zaya["shape"]["B"] * zaya["shape"]["T"])
 
 
 def test_chip_smoke_alone_fails(tmp_path):
