@@ -103,7 +103,9 @@ def _flags(parser):
                              "training")
     parser.add_argument("--head_chunk", type=int, default=0,
                         help="sequence-chunked tied head + cross-entropy "
-                             "(the [B,T,vocab] logits never materialize); "
+                             "(the [B,T,vocab] logits never materialize; "
+                             "each chunk's gradients are formed while its "
+                             "logits are live, so no product runs twice); "
                              "0 = plain head. dp layout only")
     parser.add_argument("--remat_mode", default="full",
                         choices=["full", "attn", "dots", "hybrid",

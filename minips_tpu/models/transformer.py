@@ -22,9 +22,12 @@ Two attention modes, numerically identical:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from minips_tpu.ops.flash_attention import _pcast_varying
 from minips_tpu.parallel.mesh import DATA_AXIS
 from minips_tpu.utils import profiling as prof
 from minips_tpu.parallel.ring_attention import (
@@ -654,44 +657,95 @@ def nll(logits, targets):
         -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0])
 
 
+def _head_chunks(h, targets, chunk):
+    """[B, T, ..] -> [T / chunk, B, chunk, ..]: what the head's scan walks."""
+    B, T, D = h.shape
+    n = T // chunk
+    return (jnp.moveaxis(h.reshape(B, n, chunk, D), 1, 0),
+            jnp.moveaxis(targets.reshape(B, n, chunk), 1, 0))
+
+
+def _chunk_logp(hc, w, tc):
+    """One chunk's log-probabilities (float32, from a product in ``w``'s
+    dtype) and its summed NLL: :func:`nll`'s reduction, not yet averaged."""
+    logp = jax.nn.log_softmax((hc.astype(w.dtype) @ w.T).astype(jnp.float32))
+    return logp, -jnp.take_along_axis(logp, tc[..., None], axis=-1).sum()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _nll_chunked(h, tok_emb, targets, chunk, compute_dtype):
+    w = tok_emb.astype(compute_dtype)
+
+    def body(acc, xt):
+        return acc + _chunk_logp(xt[0], w, xt[1])[1], None
+
+    total, _ = jax.lax.scan(
+        body, _pcast_varying(jnp.zeros((), jnp.float32), jax.typeof(h).vma),
+        _head_chunks(h, targets, chunk))
+    return total / targets.size
+
+
+def _nll_chunked_fwd(h, tok_emb, targets, chunk, compute_dtype):
+    # the loss is a scalar, so a chunk's dlogits = (softmax - onehot) / (B T)
+    # is known while its logits are live: dh and dW are formed in the same
+    # loop, in the dtypes autodiff would give them, and the backward rule
+    # only scales them by the scalar cotangent
+    w = tok_emb.astype(compute_dtype)
+    vma = jax.typeof(h).vma
+    vocab = jnp.arange(tok_emb.shape[0])
+
+    def body(carry, xt):
+        acc, dw = carry
+        hc, tc = xt
+        logp, nll_sum = _chunk_logp(hc, w, tc)
+        dlogits = ((jnp.exp(logp) - (tc[..., None] == vocab))
+                   / targets.size).astype(compute_dtype)
+        dw = dw + jnp.einsum("bcv,bcd->vd", dlogits,
+                             hc.astype(compute_dtype)).astype(dw.dtype)
+        return (acc + nll_sum, dw), (dlogits @ w).astype(hc.dtype)
+
+    (total, dw), dhs = jax.lax.scan(
+        body, (_pcast_varying(jnp.zeros((), jnp.float32), vma),
+               _pcast_varying(jnp.zeros_like(tok_emb), vma)),
+        _head_chunks(h, targets, chunk))
+    return total / targets.size, (jnp.moveaxis(dhs, 0, 1).reshape(h.shape),
+                                  dw)
+
+
+def _nll_chunked_bwd(chunk, compute_dtype, res, g):
+    return tuple(x * g.astype(x.dtype) for x in res) + (None,)
+
+
+_nll_chunked.defvjp(_nll_chunked_fwd, _nll_chunked_bwd)
+
+
 @jax.named_scope(prof.LM_HEAD)
 def nll_chunked(h, tok_emb, targets, chunk, compute_dtype=jnp.bfloat16):
     """Tied-head projection + cross-entropy, scanned over sequence chunks
-    so the full ``[B, T, vocab]`` f32 logits tensor NEVER exists — in the
-    forward (each chunk's logits die inside its scan step) or the backward
-    (``jax.checkpoint`` recomputes one chunk's logits to form its
-    ``dlogits``/``dh``). At bench shapes (B=64, T=1024, V=16384) that
-    tensor is 4.3 GB of f32 each way; chunking trades it for one extra
-    per-chunk head matmul in the backward (~vocab·dim of the 6·P budget).
+    so the full ``[B, T, vocab]`` f32 logits tensor NEVER exists: each
+    chunk's logits die inside its scan step. At bench shapes (B=64, T=1024,
+    V=16384) that tensor is 4.3 GB of f32 each way. Under differentiation
+    the same ONE loop also forms the chunk's ``dlogits``, ``dh`` and its
+    share of ``dW`` while the logits are live (a ``jax.custom_vjp``), so a
+    training step runs the three vocabulary-sized products a chunk that
+    its mathematics needs and nothing is computed twice; the residuals are
+    ``dh`` ``[B, T, D]`` and ``dW`` ``[V, D]``, which a backward pass holds
+    at that point anyway. Not differentiated it is one product a chunk.
     Numerics: identical reduction tree to :func:`nll` per chunk, summed in
-    f32 — oracle-equality tested in tests/test_transformer.py."""
-    B, T, D = h.shape
-    if T % chunk:
-        raise ValueError(f"seq len {T} must divide by head chunk {chunk}")
-    n = T // chunk
-    hs = jnp.moveaxis(h.reshape(B, n, chunk, D), 1, 0)        # [n,B,c,D]
-    ts = jnp.moveaxis(targets.reshape(B, n, chunk), 1, 0)     # [n,B,c]
-
-    @jax.checkpoint
-    def chunk_nll_sum(hc, tc):
-        logits = (hc.astype(compute_dtype)
-                  @ tok_emb.T.astype(compute_dtype)).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.take_along_axis(logp, tc[..., None], axis=-1).sum()
-
-    def body(acc, xt):
-        hc, tc = xt
-        return acc + chunk_nll_sum(hc, tc), None
-
-    # under shard_map the fresh carry is axis-invariant but the chunk sums
-    # vary with the sharded inputs — pcast keeps the scan carry type fixed
-    # (same treatment as DenseTable.make_step's accum fold)
-    acc0 = jnp.zeros((), jnp.float32)
-    vma = jax.typeof(h).vma | jax.typeof(targets).vma
-    if vma:
-        acc0 = jax.lax.pcast(acc0, tuple(sorted(vma)), to="varying")
-    total, _ = jax.lax.scan(body, acc0, (hs, ts))
-    return total / (B * T)
+    f32; products in ``compute_dtype``, ``dW`` summed over the chunks in
+    ``tok_emb``'s dtype: oracle-equality tested in
+    tests/test_transformer.py."""
+    if h.shape[1] % chunk:
+        raise ValueError(
+            f"seq len {h.shape[1]} must divide by head chunk {chunk}")
+    # one set of varying axes for the inputs, the scan's carries and the
+    # cotangents (a custom_vjp's rule must return cotangents of the
+    # primals' own types); where an input varies over fewer, this pcast's
+    # transpose is the psum its gradient needs
+    vma = frozenset().union(*(jax.typeof(x).vma
+                              for x in (h, tok_emb, targets)))
+    return _nll_chunked(_pcast_varying(h, vma), _pcast_varying(tok_emb, vma),
+                        targets, chunk, compute_dtype)
 
 
 def loss(params, batch, *, heads=4, compute_dtype=jnp.bfloat16,

@@ -91,6 +91,26 @@ def test_dense_step_model_phases_are_inside_grad(dense_lm_text):
     assert (prof.LM_ATTN, "remat") in seen or (prof.LM_MLP, "remat") in seen
 
 
+def test_the_chunked_heads_one_loop_is_wholly_under_the_heads_scope(
+        dense_lm_text):
+    """``nll_chunked``'s forward and backward rules are a custom_vjp's:
+    the one loop that forms logits, dh and dW, and everything in its body,
+    reads ``lm.head``; no second loop, nothing run again for a backward."""
+    loops = [line for line in dense_lm_text.splitlines()
+             if re.search(r" while\(", line)]
+    assert len(loops) == 1
+    assert _phases_of(_instructions(loops[0]), "while") == {prof.LM_HEAD}
+    body = re.search(r"body=(%[\w.\-]+)", loops[0]).group(1)
+    start = dense_lm_text.index("\n" + body + " ")
+    inside = _instructions(
+        dense_lm_text[start:dense_lm_text.index("\n}", start)])
+    assert any(op == "dot" for op, _ in inside)
+    assert {phase_of(scope) for op, scope in inside
+            if op != "constant"} == {(prof.LM_HEAD, "fwd")}
+    assert not any(phase_of(scope) == (prof.LM_HEAD, "remat")
+                   for _, scope in _instructions(dense_lm_text))
+
+
 def test_phase_of_takes_the_innermost_phase_and_the_pass():
     assert phase_of("jit(ps_dense_step)/ps.grad/jvp(lm.attn)/dot_general") \
         == (prof.LM_ATTN, "fwd")
@@ -168,17 +188,39 @@ def test_each_row_update_strategy_has_its_own_scope(fn, prefer_dense, want):
     assert (prof.SPARSE_DEDUP in phases) == (not prefer_dense)
 
 
-def _pallas_names(jaxpr, out):
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
     for e in jaxpr.eqns:
-        if e.primitive.name == "pallas_call":
-            out.append(str(e.params["name"]))
+        yield e
         for v in e.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
                 inner = getattr(sub, "jaxpr", sub)
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    _pallas_names(inner, out)
-    return out
+                    yield from _eqns(inner)
+
+
+def _pallas_names(jaxpr) -> list:
+    return [str(e.params["name"]) for e in _eqns(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def test_the_chunked_heads_backward_rule_is_under_the_heads_scope():
+    """A custom_vjp's backward rule runs outside the Python scope of its
+    call: the one multiply that scales ``dW`` ``[vocab, dim]`` by the
+    cotangent must still read ``transpose(jvp(lm.head))``."""
+    from minips_tpu.models import transformer as tfm
+
+    p = tfm.init(jax.random.PRNGKey(0), vocab=48, dim=32, heads=2,
+                 depth=1, max_len=16)    # no other [48, 32] in the model
+    batch = {"tokens": jnp.zeros((2, 17), jnp.int32)}
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, s: s * tfm.loss(q, batch, heads=2, head_chunk=4)))(p, 3.0)
+    scopes = [str(e.source_info.name_stack) for e in _eqns(jaxpr.jaxpr)
+              if e.primitive.name == "mul"
+              and e.outvars[0].aval.shape == (48, 32)]
+    assert len(scopes) == 1
+    assert phase_of(scopes[0]) == (prof.LM_HEAD, "bwd")
 
 
 def test_the_three_flash_kernels_carry_their_names():
@@ -191,7 +233,7 @@ def test_the_three_flash_kernels_carry_their_names():
                                block_q=64, block_k=64).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-    assert _pallas_names(jaxpr.jaxpr, []) == [
+    assert _pallas_names(jaxpr.jaxpr) == [
         prof.FLASH_FWD, prof.FLASH_DQ, prof.FLASH_DKV]
 
 
@@ -202,7 +244,7 @@ def test_the_gather_kernel_carries_its_name():
     slots = jnp.arange(16, dtype=jnp.int32)
     jaxpr = jax.make_jaxpr(functools.partial(
         pallas_kernels.gather_rows, interpret=True))(emb, slots)
-    assert _pallas_names(jaxpr.jaxpr, []) == [prof.GATHER_ROWS]
+    assert _pallas_names(jaxpr.jaxpr) == [prof.GATHER_ROWS]
 
 
 def test_names_are_defined_in_profiling_only():

@@ -246,39 +246,148 @@ def test_lm_example_remat_rejected_off_dp():
                 MetricsLogger(None, verbose=False))
 
 
-def test_chunked_head_nll_matches_plain():
-    """nll_chunked (scanned tied head + CE, logits never whole) must equal
-    the plain path in loss AND grads — it is a memory-layout change, not a
-    numerics change."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from minips_tpu.models import transformer as tfm
-
-    p = tfm.init(jax.random.PRNGKey(0), vocab=64, dim=32, heads=2,
+def _head_case(vocab=64):
+    p = tfm.init(jax.random.PRNGKey(0), vocab=vocab, dim=32, heads=2,
                  depth=2, max_len=16)
     toks = jnp.asarray(
-        np.random.default_rng(0).integers(0, 64, size=(2, 17)))
-    batch = {"tokens": toks}
-    # f32 compute isolates the MATH parity (in bf16 the emb-grad's
-    # sequential per-chunk matmul accumulation legitimately differs from
-    # the one-shot matmul by ~1e-3 — an order change, not an error)
-    def f(dtype, chunk):
-        return jax.value_and_grad(
-            lambda q: tfm.loss(q, batch, heads=2, compute_dtype=dtype,
-                               head_chunk=chunk))(p)
+        np.random.default_rng(0).integers(0, vocab, size=(2, 17)))
+    return p, {"tokens": toks}
 
-    l0, g0 = f(jnp.float32, 0)
-    l1, g1 = f(jnp.float32, 4)
-    np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+
+def _leaf_errors(got, want):
+    """Each leaf's ``|got - want| / |want|`` as vectors."""
+    return [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+
+
+@pytest.mark.parametrize("chunk", [4, 16])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_chunked_head_nll_matches_plain(dtype, chunk):
+    """nll_chunked (scanned tied head + CE, logits never whole, each
+    chunk's gradients formed while its logits are live) must equal the
+    plain path in loss AND grads — it is a memory-layout change, not a
+    numerics change."""
+    p, batch = _head_case()
+
+    def f(chunk):
+        return jax.value_and_grad(
+            lambda q: tfm.loss(q, batch, heads=2, head_chunk=chunk,
+                               compute_dtype=jnp.dtype(dtype)))(p)
+
+    (l0, g0), (l1, g1) = f(0), f(chunk)
+    if dtype == "float32":
+        # f32 compute isolates the MATH parity
+        np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=1e-6)
+    else:
+        # bf16 (the bench path): same loss to bf16 resolution; the
+        # emb-grad's sequential per-chunk matmul accumulation legitimately
+        # differs from the one-shot matmul by ~1e-3 — an order change,
+        # not an error (bf16 rounds at 4e-3)
+        np.testing.assert_allclose(float(l0), float(l1), rtol=2e-3)
+        assert max(_leaf_errors(g1, g0)) < 4e-3
+
+
+@pytest.mark.parametrize("dtype, tol", [("float32", 1e-6),
+                                        ("bfloat16", 8e-3)])
+def test_chunked_head_scales_with_the_cotangent(dtype, tol):
+    """The gradients are formed in the forward loop for a cotangent of 1;
+    the backward rule owes the scale of any other."""
+    p, batch = _head_case()
+
+    def g(scale):
+        return jax.grad(
+            lambda q: scale * tfm.loss(q, batch, heads=2, head_chunk=4,
+                                       compute_dtype=jnp.dtype(dtype)))(p)
+
+    assert max(_leaf_errors(
+        g(3.0), jax.tree.map(lambda x: 3.0 * x, g(1.0)))) < tol
+
+
+def _head_instructions(fn, *args):
+    """(opcode, op_name) of the compiled instructions under ``lm.head``."""
+    from minips_tpu.utils import profiling as prof
+    from minips_tpu.utils.trace_analysis import phase_of
+    from tests.test_named_scopes import _instructions
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [(op, scope) for op, scope in _instructions(text)
+            if phase_of(scope)[0] == prof.LM_HEAD]
+
+
+@pytest.mark.parametrize("differentiated, products", [(False, 1), (True, 3)])
+def test_chunked_head_runs_no_product_twice(differentiated, products):
+    """From the compiled module: one loop over the chunks either way; one
+    vocabulary-sized product a chunk for the loss alone, and under
+    differentiation the three a step's mathematics needs (logits, dh,
+    dW) — no chunk's logits are made again for a backward loop."""
+    p, batch = _head_case()
+
+    def loss(q):
+        return tfm.loss(q, batch, heads=2, head_chunk=4,
+                        compute_dtype=jnp.float32)
+
+    got = _head_instructions(jax.grad(loss) if differentiated else loss, p)
+    assert sum(op == "while" for op, _ in got) == 1
+    assert sum(op == "dot" for op, _ in got) == products
+    assert not any("rematted_computation" in scope for _, scope in got)
+
+
+@pytest.mark.parametrize("dtype, tol", [("float32", 2e-5),
+                                        ("bfloat16", 1e-2)])
+def test_chunked_head_in_the_dense_step_on_four_devices(mesh4, dtype, tol):
+    """Inside ``DenseTable.make_step``'s shard_map ``h`` and the pulled
+    weights vary over ``data``: the scan's carries and the cotangents the
+    backward rule returns must carry those axes, and one SGD step must
+    move the parameters as the plain head's step moves them."""
+    from minips_tpu.tables.dense import DenseTable
+
+    params = tfm.init(jax.random.PRNGKey(0), vocab=128, dim=32, heads=2,
+                      depth=2, max_len=32)
+    batch = {"tokens": jnp.asarray(
+        np.random.default_rng(1).integers(0, 128, size=(8, 33)), jnp.int32)}
+
+    def one_step(head_chunk):
+        table = DenseTable(params, mesh4, updater="sgd", lr=0.5)
+        before = np.asarray(table.params)
+        step = table.make_step(
+            lambda p, b: jax.value_and_grad(functools.partial(
+                tfm.loss, heads=2, head_chunk=head_chunk,
+                compute_dtype=jnp.dtype(dtype)))(p, b),
+            compute_dtype=jnp.dtype(dtype))
+        loss = table.step_inplace(step, batch)
+        return float(loss), np.asarray(table.params) - before
+
+    (l0, d0), (l1, d1) = one_step(0), one_step(8)
+    np.testing.assert_allclose(l1, l0, rtol=tol)
+    assert np.linalg.norm(d0) > 0
+    assert np.linalg.norm(d1 - d0) < tol * np.linalg.norm(d0)
+
+
+def test_chunked_head_with_replicated_weights_inside_shard_map(mesh4):
+    """Differentiated inside shard_map with the weights replicated and the
+    batch sharded, the head's inputs vary over different axes: the
+    weights' cotangent must come back summed over the workers, as autodiff
+    sums it for the plain head."""
+    from minips_tpu.parallel.mesh import DATA_AXIS
+
+    p, _ = _head_case()
+    batch = {"tokens": jnp.asarray(
+        np.random.default_rng(2).integers(0, 64, size=(8, 17)), jnp.int32)}
+
+    def grads(head_chunk):
+        def local(q, b):
+            return jax.grad(lambda r: tfm.loss(
+                r, b, heads=2, head_chunk=head_chunk, **F32))(q)
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh4, in_specs=(P(), P(DATA_AXIS)),
+            out_specs=P()))(p, batch)
+
+    for a, b in zip(jax.tree.leaves(grads(4)), jax.tree.leaves(grads(0))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=1e-6)
-    # bf16 (the bench path): same loss to bf16 resolution
-    lb0, _ = f(jnp.bfloat16, 0)
-    lb1, _ = f(jnp.bfloat16, 4)
-    np.testing.assert_allclose(float(lb0), float(lb1), rtol=2e-3)
 
 
 def test_chunked_head_rejects_nondivisible():

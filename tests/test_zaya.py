@@ -173,6 +173,28 @@ def test_gradients_agree_leaf_by_leaf_as_vectors(ref):
             float(jnp.linalg.norm(w)), 1e-6), n
 
 
+@pytest.mark.parametrize("dtype, tol", [("float32", 2e-5),
+                                        ("bfloat16", 8e-3)])
+def test_the_chunked_head_gives_the_plain_heads_gradients(dtype, tol):
+    """``grad_fn`` with ``head_chunk`` 8 (``transformer.nll_chunked``: each
+    chunk's gradients formed while its logits are live) against 0 (whole
+    logits through autodiff): the loss, every leaf's gradient as a vector,
+    and the bias handed on. bfloat16 rounds at 4e-3 and sums ``tok_emb``'s
+    gradient chunk by chunk where the plain head sums it in one product."""
+    p = _params(4)
+    b = {"tokens": jnp.asarray(_batches(1, seed=11)[0]["tokens"])}
+    bias = jax.random.normal(jax.random.PRNGKey(5), (2, 4)) * 0.2
+    kw = dict(compute_dtype=jnp.dtype(dtype), attn_impl="reference")
+    l0, g0, b0 = zaya.grad_fn(p, b, bias, M, head_chunk=0, **kw)
+    l1, g1, b1 = zaya.grad_fn(p, b, bias, M, head_chunk=8, **kw)
+    assert float(l1) == pytest.approx(float(l0), rel=max(tol, 1e-6))
+    np.testing.assert_array_equal(b1, b0)
+    for n, got, want in zip(_names(p), jax.tree.leaves(g1),
+                            jax.tree.leaves(g0)):
+        assert float(jnp.linalg.norm(got - want)) <= tol * max(
+            float(jnp.linalg.norm(want)), 1e-6), n
+
+
 # ------------------------------------- the balancing bias, beside the table
 def test_the_bias_rises_where_an_expert_fell_short():
     """An expert with no tokens gains ``rate``, one with twice its even
