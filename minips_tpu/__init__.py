@@ -20,6 +20,8 @@ component inventory rather than file:line), designed TPU-first:
 
 __version__ = "0.1.0"
 
+# The top level is the fused path (core) only: the wire fleet is imported from
+# its own modules, and nothing here or below imports it (docs/architecture.md).
 from minips_tpu.core.config import Config, TableConfig, TrainConfig  # noqa: F401
 from minips_tpu.core.engine import Engine, Info, MLTask  # noqa: F401
 from minips_tpu.consistency import ASP, BSP, SSP, make_controller  # noqa: F401
@@ -31,7 +33,4 @@ from minips_tpu.train.ps_step import PSTrainStep  # noqa: F401
 from minips_tpu.utils.evaluation import (StreamingAUC,  # noqa: F401
                                          auc_exact, evaluate_auc)
 from minips_tpu.utils.metrics import MetricsLogger  # noqa: F401
-from minips_tpu.comm import cluster  # noqa: F401  (multi-host bootstrap)
-from minips_tpu.train.sharded_ps import (ShardedPSTrainer,  # noqa: F401
-                                         ShardedTable, table_state_bytes)
-from minips_tpu.train.ssp_spmd import CollectiveSSP  # noqa: F401
+from minips_tpu.parallel import cluster  # noqa: F401  (multi-host bootstrap)

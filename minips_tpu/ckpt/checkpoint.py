@@ -33,6 +33,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from minips_tpu.utils import profiling
+
 
 class Checkpointer:
     def __init__(self, directory: str, tables: dict[str, Any],
@@ -147,10 +149,10 @@ class Checkpointer:
 
         With ``step=None`` (the relaunch path) a TORN checkpoint —
         unreadable npz, corrupt manifest, a table file missing — is
-        skipped with a loud stderr warning (+ flight-recorder event)
-        and the walk continues to the next-newest step: a crash that
-        tore the latest checkpoint must cost one checkpoint interval
-        of progress, not the relaunch. An EXPLICIT ``step`` keeps the
+        skipped with a loud stderr warning (and a ``ckpt.skip_torn``
+        counter in the profiling ring) and the walk continues to the
+        next-newest step: a crash that tore the latest checkpoint must
+        cost one checkpoint interval of progress, not the relaunch. An EXPLICIT ``step`` keeps the
         strict semantics (the caller asked for that step; silently
         substituting another would be worse than failing). All state
         for a step is read and validated BEFORE any of it is applied,
@@ -173,14 +175,7 @@ class Checkpointer:
                 print(f"[ckpt] WARNING: skipping torn checkpoint "
                       f"{note} — walking back to the previous step",
                       file=sys.stderr, flush=True)
-                try:
-                    from minips_tpu.obs import flight as _fl
-
-                    _fl.record("ckpt_skip_torn",
-                               {"dir": self.dir, "step": int(s),
-                                "err": str(e)[:200]})
-                except Exception:  # noqa: BLE001 - obs must not block
-                    pass
+                profiling.counter(profiling.CKPT_SKIP_TORN, int(s))
                 skipped.append(note)
                 continue
             # apply pass: re-read one table at a time (old peak

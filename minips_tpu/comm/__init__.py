@@ -1,7 +1,7 @@
-from minips_tpu.comm.bus import ControlBus  # noqa: F401
-from minips_tpu.comm.heartbeat import HeartbeatMonitor  # noqa: F401
+"""The wire fleet's message layer: buses, framing, heartbeats, and the
+optional chaos / reliable layers that ``make_bus`` imports lazily.
 
-# The optional bus layers (comm/chaos.py ChaosBus, comm/reliable.py
-# ReliableChannel) are deliberately NOT re-exported here: make_bus
-# imports them lazily only when MINIPS_CHAOS / MINIPS_RELIABLE arm
-# them, and the plain bus path must not depend on their import.
+Nothing is re-exported: a name is imported from its module
+(``comm.bus.ControlBus``, ``comm.heartbeat.HeartbeatMonitor``), so
+importing ``comm.framing`` starts no zmq.
+"""

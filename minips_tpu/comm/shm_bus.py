@@ -99,6 +99,7 @@ from minips_tpu.comm.bus import (FrameLossTracker, deliver_frame,
                                  stop_bus_layers)
 from minips_tpu.comm.framing import (dup_msg, encode_head, rt_wrap,
                                      wire_fmt_from_env)
+from minips_tpu.utils.proc import pid_alive
 
 __all__ = ["ShmControlBus", "sweep_stale_segments"]
 
@@ -147,21 +148,6 @@ def _doorbell_path(ns: str, rank: int) -> str:
     return os.path.join(_shm_dir(), f"{_PREFIX}_{ns}_{rank}.doorbell")
 
 
-def _pid_alive(pid: int) -> bool:
-    """Portable liveness probe — /proc is Linux-only, and this module
-    deliberately runs on macOS x86-64 too (the tempdir fallback above):
-    a /proc check there reads EVERY run as dead and the sweeper would
-    unlink a live job's rings out from under it. Signal 0 probes
-    without sending; EPERM means alive-but-not-ours."""
-    try:
-        os.kill(pid, 0)
-    except ProcessLookupError:
-        return False
-    except OSError:
-        return True
-    return True
-
-
 def sweep_stale_segments(directory: Optional[str] = None) -> int:
     """Delete bus segments whose run (MINIPS_RUN_ID = launcher pid) is
     dead — a SIGKILLed job never unlinks its rings, and tmpfs pages are
@@ -177,7 +163,7 @@ def sweep_stale_segments(directory: Optional[str] = None) -> int:
         if not name.startswith(_PREFIX + "_"):
             continue
         run = name[len(_PREFIX) + 1:].split("_", 1)[0]
-        if not run.isdigit() or _pid_alive(int(run)):
+        if not run.isdigit() or pid_alive(int(run)):
             continue  # non-pid namespace (tests) or launcher still alive
         try:
             os.unlink(os.path.join(directory, name))

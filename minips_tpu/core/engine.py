@@ -245,5 +245,5 @@ class Engine:
         """All logical workers are joined at the end of run(); a standalone
         barrier is only meaningful multi-host, where it delegates to the
         cluster coordination service (SURVEY.md §3.4)."""
-        from minips_tpu.comm.cluster import barrier as cluster_barrier
+        from minips_tpu.parallel.cluster import barrier as cluster_barrier
         cluster_barrier()

@@ -34,6 +34,8 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from minips_tpu.utils.proc import pid_alive
+
 _PREFIX = "minips_shm"
 _CLEANUP_GRACE_S = 30.0  # max leader-exit wait for peers to attach
 
@@ -96,8 +98,7 @@ def sweep_stale_segments(directory: Optional[str] = None) -> int:
         run = name[len(_PREFIX) + 1:].split("_", 1)[0]
         if not run.isdigit():
             continue  # non-pid run id (e.g. tests): not ours to judge
-        from minips_tpu.comm.shm_bus import _pid_alive
-        if _pid_alive(int(run)):
+        if pid_alive(int(run)):
             continue  # launcher still alive (portable: /proc is
             # Linux-only and this store runs wherever the bus does)
         try:

@@ -331,7 +331,7 @@ def sweep_stale_dirs() -> int:
     import glob
     import shutil
 
-    from minips_tpu.comm.shm_bus import _pid_alive
+    from minips_tpu.utils.proc import pid_alive
 
     removed = 0
     for d in glob.glob(os.path.join(tempfile.gettempdir(),
@@ -340,10 +340,10 @@ def sweep_stale_dirs() -> int:
         if not pid_s.isdigit():
             continue
         try:
-            # the ONE portable liveness contract (shm_bus/_pid_alive,
+            # the ONE portable liveness contract (utils/proc.pid_alive,
             # shared with the shm sweepers); a number too big to be a
             # pid at all (a drill's synthetic run id) is dead
-            if _pid_alive(int(pid_s)):
+            if pid_alive(int(pid_s)):
                 continue
         except OverflowError:
             pass

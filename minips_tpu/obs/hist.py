@@ -1,6 +1,6 @@
 """Fixed-bucket log2 latency histograms — the tail the means were hiding.
 
-``CommTimers`` (utils/timing.py) has carried mean-only per-leg latencies
+``CommTimers`` (obs/comm_timers.py) has carried mean-only per-leg latencies
 since the overlapped-pipeline PR, and every sweep since has fought tail
 effects the means cannot show (bursty same-stamp cache misses, park/wake
 latency, retransmit delays). This module is the cheap fix: a histogram

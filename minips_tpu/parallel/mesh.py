@@ -43,7 +43,7 @@ def make_mesh(
     (SURVEY.md §1 L7): the mesh defines who computes and who owns which
     parameter range, with no process bootstrapping needed on a single host
     (multi-host adds ``jax.distributed.initialize`` upstream, see
-    minips_tpu/comm/cluster.py).
+    minips_tpu/parallel/cluster.py).
     """
     devs = list(devices) if devices is not None else list(jax.devices())
     if num_workers is None:

@@ -441,7 +441,7 @@ class DenseTable:
         with a process allgather — a collective, so every process must
         call this together (the reference's Dump is likewise coordinated,
         SURVEY.md §3.5)."""
-        from minips_tpu.comm.cluster import host_copy
+        from minips_tpu.parallel.cluster import host_copy
 
         out = {
             "params": host_copy(self.params),

@@ -48,7 +48,6 @@ vector, including the same optimizer-state stance (see
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable
 
 import jax
@@ -64,8 +63,7 @@ from minips_tpu.tables.dense import DenseTable
 from minips_tpu.tables.sparse import SparseTable, hash_to_slots_np, next_pow2
 from minips_tpu.train.ssp_spmd import (SyncPlane, avg_table_opt_state,
                                         check_avg_opt_sync_supported,
-                                        is_avg_leaf, make_control,
-                                        staleness_for)
+                                        is_avg_leaf, make_control)
 
 __all__ = ["CollectiveSSPPS", "sync_block_rows"]
 
@@ -358,177 +356,3 @@ class CollectiveSSPPS:
             for _, leaf in self._leaves(self.sparse[name]):
                 total += float(np.asarray(leaf, dtype=np.float64).sum())
         return total
-
-
-# --------------------------------------------------------------- runners
-def run_wd_cssp(args, rank: int, nprocs: int, multi: bool,
-                watchdog) -> int:
-    """multihost_example ``--model wd --mode bsp|ssp|asp``: the flagship
-    DeepFM (hashed wide + field embeddings + deep tower) under the
-    collective-gated consistency axis. Emits the smoke-protocol JSON
-    line with the row-sparse traffic observables."""
-    import json
-
-    from minips_tpu.apps.wide_deep_example import build
-    from minips_tpu.core.config import Config, TableConfig, TrainConfig
-    from minips_tpu.data import synthetic
-
-    staleness = staleness_for(args.mode, args.staleness)
-    if getattr(args, "sync_comm", "float32") != "float32":
-        raise SystemExit(
-            "--sync-comm compression is not wired for the wd row-sparse "
-            "merge (the error-feedback residual is defined over a "
-            "per-round-changing row union — per-slot EF bookkeeping is "
-            "future work); use --model lr or lm")
-    if args.batch % nprocs:
-        raise SystemExit(f"--batch {args.batch} must divide by {nprocs} "
-                         "processes")
-    per = args.batch // nprocs
-
-    def build_fn(mesh):
-        cfg = Config(
-            table=TableConfig(name="ctr", kind="sparse",
-                              updater=args.updater, lr=args.lr,
-                              dim=args.dim, num_slots=args.num_slots),
-            train=TrainConfig(batch_size=per, num_iters=args.iters),
-        )
-        ps, (wide_t, emb_t, deep_t) = build(cfg, use_fm=True, mesh=mesh,
-                                            seed=args.seed)
-        return ps, {"wide": wide_t, "emb": emb_t, "deep": deep_t}
-
-    t0 = time.monotonic()
-    trainer = CollectiveSSPPS(
-        build_fn, staleness=staleness, sync_every=args.sync_every,
-        bus=getattr(watchdog, "bus", None),
-        monitor=getattr(watchdog, "monitor", None),
-        opt_sync=getattr(args, "opt_sync", "local"))
-    # ONE dataset (one ground truth) on every rank; batches sampled with
-    # a shared stream, each rank training on its row slice
-    data = synthetic.criteo_like(8192, seed=args.seed)
-    rng = np.random.default_rng(args.seed)
-    jitter_rng = np.random.default_rng(1000 + rank)
-    losses = []
-    with watchdog.absorbing():  # dead peer ⇒ instant Gloo error in sync
-        for i in range(args.iters):
-            sel = rng.integers(0, data["y"].shape[0], size=args.batch)
-            if args.slow_ms and rank == args.slow_rank:
-                time.sleep(args.slow_ms / 1000.0)
-            if args.jitter_ms and jitter_rng.random() < args.jitter_prob:
-                time.sleep(args.jitter_ms / 1000.0)
-            lo, hi = rank * per, (rank + 1) * per
-            losses.append(trainer.step(
-                {k: v[sel][lo:hi] for k, v in data.items()}))
-        # finalize + fingerprint are collectives too — keep them under
-        # the same death translation
-        trainer.finalize()
-        fp = trainer.fingerprint()
-    hlo = trainer.sync_hlo() if trainer._last_emb_len else ""
-
-    from minips_tpu.comm import cluster
-
-    watchdog.disarm()
-    cluster.barrier("cssp_wd_done")
-    print(json.dumps({
-        "rank": rank, "event": "done", "model": "wd", "mode": args.mode,
-        "wall_s": round(time.monotonic() - t0, 4),
-        "multi": multi, "process_count": nprocs,
-        "global_devices": len(jax.devices()),
-        "local_devices": len(jax.local_devices()),
-        "staleness": (None if staleness == float("inf")
-                      else int(staleness)),
-        "sync_every": args.sync_every,
-        "opt_sync": getattr(args, "opt_sync", "local"),
-        "loss_first": losses[0], "loss_last": losses[-1],
-        "losses": [round(x, 8) for x in losses],
-        "param_fingerprint": fp,
-        "gate_waits": trainer.gate_waits,
-        "max_skew_seen": trainer.max_skew_seen,
-        "sync_rounds": trainer.sync_rounds,
-        "sync_rows_max": trainer.sync_rows_max,
-        "num_slots": int(args.num_slots),
-        "union_wire_bytes": trainer.union_wire_bytes,
-        "sync_hlo_has_all_reduce": "all-reduce" in hlo,
-        "sync_plane_devices": len(trainer.sync_mesh.devices.ravel()),
-    }), flush=True)
-    watchdog.close()
-    return 0
-
-
-def run_lm_cssp(args, rank: int, nprocs: int, multi: bool,
-                watchdog) -> int:
-    """multihost_example ``--model lm --mode bsp|ssp|asp``: the LM family
-    on the collective consistency axis. Each process is a data-parallel
-    ISLAND (its local mesh shards batch rows); the cross-process sync is
-    CollectiveSSP's dense delta psum over the transformer's raveled
-    parameters — sequence parallelism stays intra-island (ring/a2a need
-    one mesh spanning the sequence; under the staleness axis the
-    processes deliberately do NOT share a mesh, that is the point)."""
-    import json
-
-    from minips_tpu.models import transformer as tfm
-    from minips_tpu.train.ssp_spmd import CollectiveSSP
-
-    staleness = staleness_for(args.mode, args.staleness)
-    if args.batch % nprocs:
-        raise SystemExit(f"--batch {args.batch} must divide by {nprocs} "
-                         "processes")
-    per = args.batch // nprocs
-    T = args.seq_len
-    model = dict(vocab=64, dim=32, heads=2, depth=2, max_len=T)
-    template = tfm.init(jax.random.PRNGKey(args.seed), **model)
-
-    def grad(p, b):
-        return tfm.grad_fn(p, b, heads=model["heads"])
-
-    t0 = time.monotonic()
-    trainer = CollectiveSSP(
-        template, grad, updater=args.updater, lr=args.lr,
-        staleness=staleness, sync_every=args.sync_every,
-        bus=getattr(watchdog, "bus", None),
-        monitor=getattr(watchdog, "monitor", None), name="lm_cssp",
-        opt_sync=getattr(args, "opt_sync", "local"),
-        sync_comm=getattr(args, "sync_comm", "float32"))
-    rng = np.random.default_rng(args.seed)
-    jitter_rng = np.random.default_rng(1000 + rank)
-    losses = []
-    with watchdog.absorbing():  # dead peer ⇒ instant Gloo error in sync
-        for i in range(args.iters):
-            toks = rng.integers(0, model["vocab"],
-                                size=(args.batch, T + 1)).astype(np.int32)
-            if args.slow_ms and rank == args.slow_rank:
-                time.sleep(args.slow_ms / 1000.0)
-            if args.jitter_ms and jitter_rng.random() < args.jitter_prob:
-                time.sleep(args.jitter_ms / 1000.0)
-            losses.append(trainer.step(
-                {"tokens": toks[rank * per:(rank + 1) * per]}))
-        # finalize + fingerprint are collectives too — keep them under
-        # the same death translation
-        trainer.finalize()
-
-        from minips_tpu.comm import cluster
-
-        fp = float(cluster.host_copy(trainer.table.params).sum())
-    hlo = trainer.sync_hlo()
-    watchdog.disarm()
-    cluster.barrier("cssp_lm_done")
-    print(json.dumps({
-        "rank": rank, "event": "done", "model": "lm", "mode": args.mode,
-        "wall_s": round(time.monotonic() - t0, 4),
-        "multi": multi, "process_count": nprocs,
-        "global_devices": len(jax.devices()),
-        "local_devices": len(jax.local_devices()),
-        "staleness": (None if staleness == float("inf")
-                      else int(staleness)),
-        "sync_every": args.sync_every,
-        "opt_sync": getattr(args, "opt_sync", "local"),
-        "loss_first": losses[0], "loss_last": losses[-1],
-        "losses": [round(x, 8) for x in losses],
-        "param_fingerprint": fp,
-        "gate_waits": trainer.gate_waits,
-        "max_skew_seen": trainer.max_skew_seen,
-        "sync_rounds": trainer.sync_rounds,
-        "sync_hlo_has_all_reduce": "all-reduce" in hlo,
-        "sync_plane_devices": len(trainer.sync_mesh.devices.ravel()),
-    }), flush=True)
-    watchdog.close()
-    return 0

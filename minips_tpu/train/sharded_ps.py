@@ -108,7 +108,7 @@ cache, rebuilt with the SSP rule as its validity predicate):
   rule (a fully-cached prefetch never touches the wire).
 
 Per-leg timing (issue→reply latency, blocked time, overlap fraction,
-ack latency) runs through ``utils/timing.CommTimers`` — which now also
+ack latency) runs through ``obs/comm_timers.CommTimers`` — which now also
 carries rows-requested vs rows-over-wire and cache hit/lookup counts
 into the done lines; wire bytes both directions count ACTUAL bytes on
 the wire (compressed when compressed).
@@ -217,6 +217,7 @@ from minips_tpu.consistency.gate import (RETIRED_CLOCK, PeerFailureError,
 from minips_tpu.obs import flight as _fl
 from minips_tpu.obs import tracer as _trc
 from minips_tpu.obs import window as _ow
+from minips_tpu.obs.comm_timers import CommTimers
 from minips_tpu.obs.hist import Log2Histogram, merge_counts, \
     summarize_counts
 from minips_tpu.ops.quantized_comm import (HOST_BLOCK,
@@ -229,7 +230,6 @@ from minips_tpu.ops.quantized_comm import (HOST_BLOCK,
                                            quantize_blockwise,
                                            quantize_rows_int8, topk_rows)
 from minips_tpu.parallel.partition import BlockRouter, RangePartitioner
-from minips_tpu.utils.timing import CommTimers
 
 __all__ = ["ShardedTable", "ShardedPSTrainer", "PeerFailureError",
            "PullFuture", "RowCache", "ResidualStore", "table_state_bytes",
@@ -6126,7 +6126,7 @@ class ShardedPSTrainer:
         """Aggregate per-leg wire timing over all tables: pull issue→
         reply latency, blocked time, overlap fraction, push ack latency,
         plus rows-requested/rows-wire and cache hit counters
-        (utils/timing.CommTimers.summary fields)."""
+        (obs/comm_timers.CommTimers.summary fields)."""
         return CommTimers.aggregate(
             [t.timers for t in self.tables.values()])
 

@@ -570,7 +570,7 @@ def run_ssp_spmd(args, rank: int, nprocs: int, multi: bool,
     """
     import json
 
-    from minips_tpu.comm import cluster
+    from minips_tpu.parallel import cluster
     from minips_tpu.models import lr as lr_model
 
     B, D = args.batch, args.dim

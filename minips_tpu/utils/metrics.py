@@ -59,7 +59,7 @@ class MetricsLogger:
 def wire_record(trainer) -> dict:
     """One JSON-able record of a sharded-PS trainer's wire health: bytes
     both directions, loss/drop accounting, and the per-leg timing
-    (utils/timing.CommTimers) the overlapped pipeline exposes, nested
+    (obs/comm_timers.CommTimers) the overlapped pipeline exposes, nested
     under ``"timing"`` — the done-line shape the apps splat into their
     result line (and the bench worker mirrors with per-window deltas),
     so sweep tooling scrapes one layout."""

@@ -81,6 +81,8 @@ LM_MOE_COMBINE = "lm.moe.combine"
 # ---- counters of the routing observer (zaya.routing_stats), per log line
 MOE_TOKENS_HELD = "moe.tokens_held"
 MOE_LOAD_MAX_OVER_MEAN = "moe.load_max_over_mean"
+# ---- a torn checkpoint step that restore() walked past; value: the step
+CKPT_SKIP_TORN = "ckpt.skip_torn"
 # ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names
 FLASH_FWD = "flash_fwd"
 FLASH_DQ = "flash_dq"

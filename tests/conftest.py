@@ -31,26 +31,6 @@ enable_compile_cache()
 
 import pytest  # noqa: E402
 
-# ---- jax_compat quarantine: the pre-existing jax-version failures ride
-# a checked-in manifest (one nodeid per line; '#' comments) and are
-# collected as MARKED XFAILS, so the tier-1 pass/fail signal is clean
-# without touching the tier-1 command. strict=False: a test that starts
-# passing under a newer jax reports XPASS — the cue to DELETE its
-# manifest line (the manifest may only shrink,
-# tests/test_jax_compat_manifest.py pins the ceiling).
-_JAX_COMPAT_MANIFEST = os.path.join(os.path.dirname(__file__),
-                                    "jax_compat_failures.txt")
-
-
-def load_jax_compat_manifest() -> list[str]:
-    try:
-        with open(_JAX_COMPAT_MANIFEST) as f:
-            return [ln.strip() for ln in f
-                    if ln.strip() and not ln.lstrip().startswith("#")]
-    except OSError:
-        return []
-
-
 _BENCH_TESTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "bench", "tests")
 
@@ -86,21 +66,6 @@ class BenchSuite(pytest.File):
 def pytest_collect_file(parent, file_path):
     if file_path.name == "test_bench_suite.py":
         return BenchSuite.from_parent(parent, path=file_path)
-
-
-def pytest_collection_modifyitems(config, items):
-    quarantined = set(load_jax_compat_manifest())
-    if not quarantined:
-        return
-    marker = pytest.mark.xfail(
-        reason="pre-existing jax-version incompatibility "
-               "(tests/jax_compat_failures.txt — fix the test, then "
-               "delete its manifest line)",
-        strict=False)
-    for item in items:
-        if item.nodeid in quarantined:
-            item.add_marker(pytest.mark.jax_compat)
-            item.add_marker(marker)
 
 
 def mk_loopback_buses(n, backend="zmq", settle=0.25, **bus_kw):

@@ -10,6 +10,7 @@ from minips_tpu.ckpt.checkpoint import Checkpointer, _flatten, _unflatten
 from minips_tpu.consistency import SSP
 from minips_tpu.tables.dense import DenseTable
 from minips_tpu.tables.sparse import SparseTable
+from minips_tpu.utils import profiling
 
 
 def test_flatten_unflatten_roundtrip():
@@ -122,9 +123,13 @@ def test_restore_walks_back_past_torn_checkpoint(mesh8, tmp_path, capfd):
     p3.write_bytes(raw[: len(raw) // 2])
     d2, s2 = _trained_tables(mesh8)
     ck2 = Checkpointer(str(tmp_path), {"d": d2, "s": s2})
+    before = profiling.snapshot()[1].get(profiling.CKPT_SKIP_TORN, (0, 0))
     assert ck2.restore() == 2
     err = capfd.readouterr().err
     assert "skipping torn checkpoint" in err and "step_3" in err
+    # ... and on record in the program's ring: one skip, value the step
+    after = profiling.snapshot()[1][profiling.CKPT_SKIP_TORN]
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 3)
     # an EXPLICIT step keeps strict semantics: asking for the torn one
     # raises instead of silently substituting an older step
     with pytest.raises(Exception):

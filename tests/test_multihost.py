@@ -1,4 +1,4 @@
-"""Multi-host SPMD data plane (comm/cluster.py + apps/multihost_example).
+"""Multi-host SPMD data plane (parallel/cluster.py + apps/multihost_example).
 
 VERDICT r2 Missing #1: the reference actually runs N processes on N nodes
 (SURVEY.md §1 L7, §3.1); the rebuild's SPMD equivalent is
@@ -30,7 +30,7 @@ APP = "minips_tpu.apps.multihost_example"
 def test_initialize_single_process_is_noop(monkeypatch):
     """No coordinator anywhere -> False, and jax.distributed is NOT
     touched (calling it twice in-process would raise)."""
-    from minips_tpu.comm import cluster
+    from minips_tpu.parallel import cluster
 
     for var in ("MINIPS_COORDINATOR", "JAX_COORDINATOR_ADDRESS",
                 "MINIPS_NUM_PROCS", "MINIPS_PROC_ID"):
@@ -43,7 +43,7 @@ def test_initialize_single_process_is_noop(monkeypatch):
 def test_initialize_num_procs_one_is_noop(monkeypatch):
     """A coordinator with world size 1 (launcher run with --n 1) must not
     start the distributed runtime either."""
-    from minips_tpu.comm import cluster
+    from minips_tpu.parallel import cluster
 
     monkeypatch.setenv("MINIPS_COORDINATOR", "127.0.0.1:1")
     monkeypatch.setenv("MINIPS_NUM_PROCS", "1")
@@ -58,7 +58,7 @@ def test_initialize_jax_standard_env_passes_through(monkeypatch):
     degrade to N independent single-process runs."""
     import jax
 
-    from minips_tpu.comm import cluster
+    from minips_tpu.parallel import cluster
 
     for var in ("MINIPS_COORDINATOR", "MINIPS_NUM_PROCS",
                 "MINIPS_PROC_ID"):
@@ -74,7 +74,7 @@ def test_initialize_jax_standard_env_passes_through(monkeypatch):
 
 
 def test_barrier_single_process_returns():
-    from minips_tpu.comm import cluster
+    from minips_tpu.parallel import cluster
 
     cluster.barrier("unit")  # must not hang or require a cluster
 
@@ -84,7 +84,7 @@ def test_global_batch_single_process(mesh8):
     the same call sites work on one host and on a pod."""
     import jax
 
-    from minips_tpu.comm import cluster
+    from minips_tpu.parallel import cluster
 
     x = np.arange(32, dtype=np.float32).reshape(16, 2)
     out = cluster.global_batch(mesh8, {"x": x})
@@ -98,7 +98,7 @@ def test_host_copy_addressable(mesh8):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from minips_tpu.comm import cluster
+    from minips_tpu.parallel import cluster
 
     x = jax.device_put(np.arange(8, dtype=np.float32),
                        NamedSharding(mesh8, P("data")))

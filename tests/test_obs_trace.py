@@ -33,7 +33,7 @@ from minips_tpu.obs.merge import (estimate_offsets_us, main as merge_main,
 from minips_tpu.obs.report import attribute, format_table
 from minips_tpu.train.sharded_ps import ShardedPSTrainer, ShardedTable
 from minips_tpu.utils.metrics import MetricsLogger, wire_record
-from minips_tpu.utils.timing import CommTimers
+from minips_tpu.obs.comm_timers import CommTimers
 from tests.conftest import mk_loopback_buses
 
 
