@@ -112,8 +112,9 @@ def _flags(parser):
                                  "hybrid_qkv"],
                         help="with --remat: full = recompute whole "
                              "blocks; attn = save attention outputs; "
-                             "dots = save matmul outputs (see "
-                             "transformer._remat_policy)")
+                             "dots = save matmul outputs; every mode but "
+                             "full keeps the flash forward kernel's out "
+                             "and lse (see transformer._remat_policy)")
     parser.add_argument("--remat", action="store_true",
                         help="recompute block activations in backward "
                              "(jax.checkpoint): depth stops driving peak "

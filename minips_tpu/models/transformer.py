@@ -174,10 +174,11 @@ def _block_tail(h, blk, a, compute_dtype, psum_axis=None, ffn_fn=None,
     from jax.ad_checkpoint import checkpoint_name
 
     B, T, _ = h.shape
-    # named for selective remat: remat="attn" saves exactly this tensor,
-    # so the backward never re-runs the attention itself (the priciest
-    # recompute per byte: flash kernels + T^2 math) while everything else
-    # still recomputes
+    # named for selective remat: remat="attn" saves this tensor (and, on
+    # the kernel path, the forward kernel's own residuals, which carry
+    # their names from ops/flash_attention.py), so the backward never
+    # re-runs the attention itself (the priciest recompute per byte:
+    # flash kernels + T^2 math) while everything else still recomputes
     with jax.named_scope(prof.LM_ATTN):
         a = checkpoint_name(a, "attn_out")
         att = (a.astype(compute_dtype)
@@ -283,17 +284,27 @@ def _forward(params, tokens, pos, heads, attn_fn, compute_dtype,
 def _remat_policy(remat):
     """Rematerialization spectrum for the block checkpoint — the
     FLOPs↔HBM dial (SURVEY brief: jax.checkpoint to trade FLOPs for
-    memory):
+    memory). Every mode but ``True`` also keeps what the flash forward
+    kernel leaves for its backward kernels (``prof.FLASH_RESIDUALS``:
+    ``out`` and the row logsumexp ``lse``, named in
+    ``ops/flash_attention.py``), so with ``attn_impl="flash"`` the
+    kernel runs once a block and step, not again for the backward; off
+    the kernel path those names do not occur and nothing more is kept.
 
     - ``True``  — save only block inputs; backward recomputes the whole
-      block (max memory savings, +1/3 executed FLOPs).
+      block, the flash forward kernel included (max memory savings, +1/3
+      executed FLOPs).
     - ``"attn"`` — additionally save each block's attention output
       (checkpoint_name above): the backward re-runs the matmuls but never
       the attention itself. Costs one [B, T, D] compute_dtype tensor
-      (bf16 in the default mixed-precision run) per block.
-    - ``"dots"`` — save every matmul output, recompute only elementwise
-      (LN/gelu/softmax): near-zero recompute, the memory win is only the
-      elementwise intermediates.
+      (bf16 in the default mixed-precision run) per block; on the kernel
+      path that tensor is the kernel's ``out``, plus ``lse``, one
+      float32 a query row and head.
+    - ``"dots"`` — save what the MXU made: every matmul output and the
+      kernel's ``out`` (the product p v; with the reference attention the
+      same policy keeps that dot itself) with its ``lse``; recompute only
+      elementwise (LN/gelu/softmax): near-zero recompute, the memory win
+      is only the elementwise intermediates.
     - ``"hybrid"`` — save attn_out + the [B*T, 4D] pre-gelu mlp_hidden:
       backward recomputes only qkv + the attention output projection
       (~8 of 24 D^2-units per block, ~1.1x total FLOPs) at a fraction
@@ -304,19 +315,19 @@ def _remat_policy(remat):
     """
     if remat is True:
         return None
-    if remat == "attn":
-        return jax.checkpoint_policies.save_only_these_names("attn_out")
+    policies = jax.checkpoint_policies
     if remat == "dots":
-        return jax.checkpoint_policies.checkpoint_dots
-    if remat == "hybrid":
-        return jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "mlp_hidden")
-    if remat == "hybrid_qkv":
-        return jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "mlp_hidden", "qkv")
-    raise ValueError(f"unknown remat mode {remat!r} "
-                     "(expected True/False, 'attn', 'dots', 'hybrid' "
-                     "or 'hybrid_qkv')")
+        return policies.save_from_both_policies(
+            policies.checkpoint_dots,
+            policies.save_only_these_names(*prof.FLASH_RESIDUALS))
+    names = {"attn": ("attn_out",),
+             "hybrid": ("attn_out", "mlp_hidden"),
+             "hybrid_qkv": ("attn_out", "mlp_hidden", "qkv")}.get(remat)
+    if names is None:
+        raise ValueError(f"unknown remat mode {remat!r} "
+                         "(expected True/False, 'attn', 'dots', 'hybrid' "
+                         "or 'hybrid_qkv')")
+    return policies.save_only_these_names(*names, *prof.FLASH_RESIDUALS)
 
 
 def decay_mask(params):

@@ -31,6 +31,15 @@ score matrix in HBM):
   ON the diagonal as its live 128-wide groups only; training memory stays
   O(T) and the [T, T] matrix never exists in either pass. ``flash_plan``
   is the one place tiles and resident extents are chosen, from the shape.
+  What the forward leaves for those two kernels, ``out`` and the
+  logsumexp, carries checkpoint names (``profiling.FLASH_RESIDUALS``):
+  the kernel is a custom call, which no ``jax.checkpoint`` policy that
+  goes by primitive (``checkpoint_dots``) would keep, so a checkpoint
+  around a caller ran it a second time for the backward, bit for bit the
+  same; a policy that saves both names (every mode of
+  ``transformer._remat_policy`` but ``True``) keeps them, and the second
+  run, dead code then, is dropped. Outside a checkpoint a name is the
+  identity.
 
 Speeds: PERF.md section 5 (the benchmark cell's trace by kernel) and
 section 6, PR 27 (what each part of this design brought on the v5e).
@@ -51,6 +60,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -786,6 +796,16 @@ def _flash_with_lse_fwd(q, k, v, q_off, k_off, masked, scale, block_q,
                         block_k, interpret):
     out, lse = _flash_forward(q, k, v, q_off, k_off, masked, scale,
                               block_q, block_k, interpret)
+    # named BEFORE they part into outputs and residuals, so that a
+    # checkpoint policy that saves both names leaves the rematted forward
+    # no reader of the kernel: its second run is dead code and is dropped
+    # ``out`` is named as [B, T, H D]: what a policy keeps has the shape
+    # of what is named, and a last dimension of D 64 is padded to the 128
+    # lanes of an HBM tile (105 MB a layer at GPT-2 XL's shape, not 52)
+    B, T, H, D = out.shape
+    out = checkpoint_name(out.reshape(B, T, H * D),
+                          prof.FLASH_OUT).reshape(B, T, H, D)
+    lse = checkpoint_name(lse, prof.FLASH_LSE)
     return (out, lse), (q, k, v, q_off, k_off, out, lse)
 
 
@@ -908,9 +928,13 @@ def ring_flash_attention_local(
     per-device memory is O(T/N); training stores each step's visiting K/V
     shard as AD residuals (O(T) per device across the n steps) — wrap the
     caller in jax.checkpoint (the LM family's ``remat=True``) to trade
-    that back to O(T/N). K/V rotate one ICI hop per step (ppermute); XLA
-    overlaps the hop with the kernel. Gradients flow through the kernels'
-    custom VJP at every step.
+    that back to O(T/N). No caller wires the ring to the other remat
+    modes (``lm_example`` refuses ``--remat`` off ``dp``); one who wraps
+    it in a checkpoint whose policy saves ``profiling.FLASH_RESIDUALS``
+    keeps one partial ``out`` ``[B, T/N, H D]`` and ``lse`` a ring step,
+    n of each a device, and runs the forward kernel n times, not 2n. K/V
+    rotate one ICI hop per step (ppermute); XLA overlaps the hop with the
+    kernel. Gradients flow through the kernels' custom VJP at every step.
     """
     n = jax.lax.axis_size(axis_name)
     B, Tq, H, D = q.shape

@@ -87,6 +87,12 @@ CKPT_SKIP_TORN = "ckpt.skip_torn"
 FLASH_FWD = "flash_fwd"
 FLASH_DQ = "flash_dq"
 FLASH_DKV = "flash_dkv"
+# ---- what flash_fwd leaves for the backward kernels, as checkpoint names
+# (jax.ad_checkpoint.checkpoint_name): a block checkpoint whose policy
+# saves both does not run flash_fwd a second time for the backward
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+FLASH_RESIDUALS = (FLASH_OUT, FLASH_LSE)
 GATHER_ROWS = "gather_rows"
 # XLA's own grouped-matmul kernel: what jax.lax.ragged_dot (the dropless
 # expert layer's three products) lowers to on the TPU

@@ -96,6 +96,24 @@ def mk_loopback_buses(n, backend="zmq", settle=0.25, **bus_kw):
     return buses
 
 
+def jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from jaxpr_eqns(inner)
+
+
+def pallas_call_names(jaxpr) -> list:
+    """The ``name=`` of every ``pallas_call`` in a jaxpr, in order."""
+    return [str(e.params["name"]) for e in jaxpr_eqns(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
 @pytest.fixture(scope="session")
 def mesh8():
     from minips_tpu.parallel.mesh import make_mesh

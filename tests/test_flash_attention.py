@@ -4,6 +4,8 @@ The Pallas kernel runs in interpret mode here (no TPU in CI; compiled path
 is exercised by bench.py on the real chip). Oracle equality is the same
 test discipline as ring attention (test_ring_attention.py)."""
 
+import functools
+
 import jax
 
 import jax.numpy as jnp
@@ -17,6 +19,7 @@ from minips_tpu.ops.flash_attention import (blockwise_attention,
                                             flash_attention, flash_plan,
                                             kernel_supported)
 from minips_tpu.parallel.ring_attention import reference_attention
+from minips_tpu.utils import profiling as prof
 
 
 def _qkv(B=2, T=64, H=2, D=16, seed=0, dtype=jnp.float32):
@@ -471,3 +474,75 @@ def test_plan_tiles_divide_and_stay_within_the_blocks(T, bq, bk, fwd, bwd):
     assert (plan.tile_q, plan.tile_k) == fwd
     assert (plan.bwd_q, plan.bwd_k) == bwd
     assert all(T % t == 0 for t in plan[:4])
+
+
+# ------------------------------- the forward's residuals under jax.checkpoint
+_NAMES = jax.checkpoint_policies.save_only_these_names
+# a checkpoint policy around the kernels, and the forward kernel's calls in
+# the whole of grad under it
+RESIDUAL_POLICIES = {
+    "both_names": (_NAMES(*prof.FLASH_RESIDUALS), 1),
+    # either alone leaves the backward a reader of the kernel
+    "out_alone": (_NAMES(prof.FLASH_OUT), 2),
+    "lse_alone": (_NAMES(prof.FLASH_LSE), 2),
+    # the kernel is a custom call, no dot_general: the reason the block
+    # checkpoint's "dots" adds the names
+    "dots_alone": (jax.checkpoint_policies.checkpoint_dots, 2),
+    "nothing": (None, 2),
+}
+
+
+@pytest.mark.parametrize("policy", list(RESIDUAL_POLICIES))
+def test_checkpoint_that_saves_both_residuals_runs_the_forward_once(policy):
+    from tests.conftest import pallas_call_names
+
+    saved, forward_calls = RESIDUAL_POLICIES[policy]
+    q, k, v = _qkv(B=1, T=64)
+
+    def attn(*qkv):
+        return flash_attention(*qkv, causal=True, interpret=True,
+                               block_q=32, block_k=32).sum()
+
+    grad = jax.grad(jax.checkpoint(attn, policy=saved), argnums=(0, 1, 2))
+    names = pallas_call_names(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+    assert names.count(prof.FLASH_FWD) == forward_calls
+    assert names.count(prof.FLASH_DQ) == names.count(prof.FLASH_DKV) == 1
+    for a, b in zip(grad(q, k, v),
+                    jax.grad(attn, argnums=(0, 1, 2))(q, k, v)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_checkpointed_ring_keeps_one_out_and_lse_a_ring_step(capsys):
+    """What ``ring_flash_attention_local``'s docstring says a caller who
+    wraps it in ``jax.checkpoint`` with the two names keeps: each ring
+    step's partial ``out`` and ``lse``, stacked over the steps."""
+    import jax.sharding as shd
+
+    from minips_tpu.ops.flash_attention import ring_flash_attention_local
+    from minips_tpu.parallel.mesh import make_mesh
+
+    n = 4
+    mesh = make_mesh(n)
+    spec = shd.PartitionSpec(None, "data")
+    q, k, v = _qkv(B=1, T=64, H=2, D=16, seed=5)
+
+    @functools.partial(jax.checkpoint,
+                       policy=jax.checkpoint_policies.save_only_these_names(
+                           *prof.FLASH_RESIDUALS))
+    def local(q_, k_, v_):
+        return ring_flash_attention_local(
+            q_, k_, v_, axis_name="data", causal=True, block_q=8,
+            block_k=8, interpret=True)
+
+    # (the interpreter needs check_vma=False, under which this jax cannot
+    # transpose the ring's shard_map: what is kept is read, no gradient)
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda q, k, v: jnp.sum(shard_map(
+            local, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+            check_vma=False)(q, k, v) ** 2), q, k, v)
+    lines = capsys.readouterr().out.splitlines()
+    T_local = 64 // n
+    # seen from outside the shard_map: n steps a device, n devices
+    kept = [l.split(" ")[0] for l in lines if "output of shard_map" in l]
+    assert kept == [f"f32[{n * n},1,{T_local},{2 * 16}]",   # out, as named
+                    f"f32[{n * n},1,2,1,2,8]"]              # lse rows

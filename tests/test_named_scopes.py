@@ -17,6 +17,8 @@ import pytest
 
 from minips_tpu.utils import profiling as prof
 from minips_tpu.utils.trace_analysis import phase_of
+from tests.conftest import jaxpr_eqns as _eqns
+from tests.conftest import pallas_call_names as _pallas_names
 
 
 def _instructions(text: str) -> list[tuple[str, str]]:
@@ -188,23 +190,6 @@ def test_each_row_update_strategy_has_its_own_scope(fn, prefer_dense, want):
     assert (prof.SPARSE_DEDUP in phases) == (not prefer_dense)
 
 
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside its equations."""
-    for e in jaxpr.eqns:
-        yield e
-        for v in e.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                inner = getattr(sub, "jaxpr", sub)
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _eqns(inner)
-
-
-def _pallas_names(jaxpr) -> list:
-    return [str(e.params["name"]) for e in _eqns(jaxpr)
-            if e.primitive.name == "pallas_call"]
-
-
 def test_the_chunked_heads_backward_rule_is_under_the_heads_scope():
     """A custom_vjp's backward rule runs outside the Python scope of its
     call: the one multiply that scales ``dW`` ``[vocab, dim]`` by the
@@ -235,6 +220,39 @@ def test_the_three_flash_kernels_carry_their_names():
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
     assert _pallas_names(jaxpr.jaxpr) == [
         prof.FLASH_FWD, prof.FLASH_DQ, prof.FLASH_DKV]
+
+
+@pytest.mark.parametrize("remat, rematted", [
+    ("dots", []), (True, [prof.FLASH_FWD] * 2)])
+def test_flash_kernels_keep_name_and_scope_with_residuals_named(
+        remat, rematted):
+    """``attn_roofline`` and ``trace_analysis`` find the kernels by name
+    (or opcode) under ``lm.attn``: naming the forward's residuals takes no
+    kernel out of the scope and renames none. Under ``"dots"`` no kernel
+    is in a rematted forward; under ``True`` the forward kernel is."""
+    from minips_tpu.models import transformer as tfm
+    from minips_tpu.ops.flash_attention import flash_attention
+
+    p = tfm.init(jax.random.PRNGKey(0), vocab=32, dim=32, heads=2,
+                 depth=2, max_len=128)
+    toks = jnp.zeros((2, 128), jnp.int32)
+
+    def loss(q):
+        out, _ = tfm._forward(
+            q, toks, jnp.arange(128), 2,
+            lambda *a: flash_attention(*a, causal=True, interpret=True),
+            jnp.float32, remat=remat)
+        return out.sum()
+
+    kernels = [(str(e.params["name"]),
+                phase_of(str(e.source_info.name_stack)))
+               for e in _eqns(jax.make_jaxpr(jax.grad(loss))(p).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert {name for name, _ in kernels} == {
+        prof.FLASH_FWD, prof.FLASH_DQ, prof.FLASH_DKV}
+    assert {phase for _, (phase, _) in kernels} == {prof.LM_ATTN}
+    assert [n for n, (_, part) in kernels if part == "remat"] == rematted
+    assert set(prof.FLASH_RESIDUALS).isdisjoint(prof.KERNELS)
 
 
 def test_the_gather_kernel_carries_its_name():

@@ -765,3 +765,123 @@ def test_dropout_refused_on_parallel_schedule_paths():
                      tfm._attn_fn("reference"), jnp.float32,
                      apply_blocks=lambda h: h, dropout=0.1,
                      rng=jax.random.PRNGKey(1))
+
+
+# ------------------------------------------------ the flash kernel's
+# residuals across the block checkpoint (kernels under interpret=True)
+KEEPING_MODES = ("dots", "attn", "hybrid", "hybrid_qkv")
+
+
+def _flash_attn(q, k, v):
+    from minips_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, causal=True, interpret=True)
+
+
+def _flash_model():
+    p = tfm.init(jax.random.PRNGKey(2), vocab=32, dim=32, heads=2,
+                 depth=2, max_len=128)
+    toks = _toks(2, 128, seed=2, vocab=32)
+
+    def loss_of(remat):
+        def loss(q):
+            logits, _ = tfm._forward(q, toks, jnp.arange(128), 2,
+                                     _flash_attn, jnp.float32, remat=remat)
+            return tfm.nll(logits, jnp.roll(toks, -1, axis=1))
+        return loss
+    return p, loss_of
+
+
+@pytest.mark.parametrize("remat, forward_calls_a_block", [
+    (False, 1), (True, 2)] + [(m, 1) for m in KEEPING_MODES])
+def test_flash_forward_kernel_calls_a_block(remat, forward_calls_a_block):
+    """Every mode that keeps anything keeps the forward kernel's ``out``
+    and ``lse``, so the rematted forward holds no ``flash_fwd``: one call
+    a block in the whole of ``grad``; ``remat=True`` runs it twice."""
+    from minips_tpu.utils import profiling as prof
+
+    from tests.conftest import pallas_call_names
+
+    p, loss_of = _flash_model()
+    names = pallas_call_names(
+        jax.make_jaxpr(jax.grad(loss_of(remat)))(p).jaxpr)
+    blocks = len(p["blocks"])
+    assert names.count(prof.FLASH_FWD) == forward_calls_a_block * blocks
+    assert names.count(prof.FLASH_DQ) == blocks
+    assert names.count(prof.FLASH_DKV) == blocks
+
+
+@pytest.mark.parametrize("remat", KEEPING_MODES)
+def test_flash_residuals_are_saved_by_the_policy(remat, capsys):
+    """What crosses the checkpoint, a block: ``lse`` under its name and
+    ``out`` as it is named, ``[B, T, H hd]``, from the kernel's module
+    (jax prints a residual that the block's own outputs also read as the
+    output of the no-op ``reduce_precision`` it puts behind it, not by
+    its name)."""
+    from minips_tpu.utils import profiling as prof
+
+    p, loss_of = _flash_model()
+    jax.ad_checkpoint.print_saved_residuals(loss_of(remat), p)
+    lines = [l for l in capsys.readouterr().out.splitlines()
+             if "ops/flash_attention.py" in l]
+    blocks = len(p["blocks"])
+    assert sum(f"named '{prof.FLASH_LSE}'" in l for l in lines) == blocks
+    outs = [l for l in lines if l.startswith("f32[2,128,32] ")]
+    assert len(outs) == blocks == len(lines) - blocks
+    assert all(f"named '{prof.FLASH_OUT}'" in l or "reduce_precision" in l
+               for l in outs)
+
+
+def test_flash_residuals_are_not_saved_by_full_remat(capsys):
+    p, loss_of = _flash_model()
+    jax.ad_checkpoint.print_saved_residuals(loss_of(True), p)
+    out = capsys.readouterr().out
+    assert "flash_" not in out and "ops/flash_attention.py" not in out
+
+
+def test_dots_keeping_the_flash_residuals_changes_no_value(monkeypatch):
+    """The kept ``out`` / ``lse`` are what the kernel's second run made:
+    loss and every gradient leaf are bitwise the values of the policy
+    without the two names (``checkpoint_dots`` alone, which runs the
+    kernel again), and equal to no remat."""
+    p, loss_of = _flash_model()
+    l1, g1 = jax.value_and_grad(loss_of("dots"))(p)
+    l0, g0 = jax.value_and_grad(loss_of(False))(p)
+    monkeypatch.setattr(
+        tfm, "_remat_policy",
+        lambda remat: jax.checkpoint_policies.checkpoint_dots)
+    l2, g2 = jax.value_and_grad(loss_of("dots"))(p)
+    assert np.asarray(l1).tobytes() == np.asarray(l2).tobytes()
+    for a, b, c in zip(*(jax.tree.leaves(g) for g in (g1, g2, g0))):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
+
+
+@pytest.mark.parametrize("what", ["forward", "grad"])
+def test_flash_block_without_a_checkpoint_lowers_as_unnamed(what,
+                                                            monkeypatch):
+    """Outside ``jax.checkpoint`` a name is the identity: a block with
+    the kernels lowers to the text it lowers to with the names taken out
+    (ZAYA's step, decode and every caller without remat), but for the
+    serial numbers jax gives its private functions."""
+    import re
+
+    from minips_tpu.ops import flash_attention as fa
+
+    p, _ = _flash_model()
+    h = jnp.ones((2, 128, 32), jnp.float32)
+
+    def run(hh, blk):
+        return tfm._block(hh, blk, 2, _flash_attn, jnp.float32)[0].sum()
+
+    def text():
+        jax.clear_caches()
+        f = run if what == "forward" else jax.grad(run, argnums=(0, 1))
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                      jax.jit(f).lower(h, p["blocks"][0]).as_text())
+
+    named = text()
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    assert text() == named
