@@ -17,6 +17,11 @@ means:
   (``models/zaya.py``: compressed convolutional attention, the dropless
   top-1 expert layer holding 8 of 16 experts) at published widths, T 1,024,
   against the benchmark's plain float32 reference.
+- ``joyai``: one forward and backward of the latent-attention expert
+  decoder (``models/mla_moe.py``: a dense layer, an expert layer holding 8
+  of 256 experts at top-8 beside a shared one, the prediction module, the
+  untied head; the flash kernels at 192/128) at published widths, T 1,024,
+  against the benchmark's plain float32 reference.
 
 It fails (non-zero exit, no result line) if JAX finds no TPU, if a loss is
 non-finite or does not fall, if the LM step holds fewer than three compiled
@@ -320,6 +325,20 @@ def _leg_kernels(on_tpu: bool) -> dict:
     return facts
 
 
+def _grad_norm_gap(grads, want_sums, n: int) -> float:
+    """The worst leaf's gap between the norm of the program's gradient
+    and the reference's (a sum over ``n`` tokens, so divided by it), over
+    the reference's norm of that leaf or of the median leaf, whichever is
+    larger."""
+    import jax
+    import jax.numpy as jnp
+    norm = lambda x: float(jnp.linalg.norm(x.astype(jnp.float32)))  # noqa: E731,E501
+    want = [norm(x) / n for x in jax.tree.leaves(want_sums)]
+    med = sorted(want)[len(want) // 2]
+    return max(abs(norm(g) - w) / max(w, med)
+               for g, w in zip(jax.tree.leaves(grads), want))
+
+
 def _leg_zaya(on_tpu: bool, here: str) -> dict:
     """One forward and backward of a one-layer ZAYA block, bfloat16 worker
     math as the cell runs it, against the plain float32 reference the
@@ -369,15 +388,7 @@ def _leg_zaya(on_tpu: bool, here: str) -> dict:
     # between bfloat16 and float32 moves its expert's leaves, so the
     # tolerance is bfloat16's plus the share of tokens that flipped
     tol = 0.05 + 4.0 * flips / (B * T)
-    worst = 0.0
-    norms = [float(jnp.linalg.norm(x.astype(jnp.float32)))
-             for x in jax.tree.leaves(want_g)]
-    med = sorted(norms)[len(norms) // 2] / (B * T)
-    for g, w in zip(jax.tree.leaves(grads), norms):
-        w = w / (B * T)
-        gap = abs(float(jnp.linalg.norm(g.astype(jnp.float32))) - w) \
-            / max(w, med)
-        worst = max(worst, gap)
+    worst = _grad_norm_gap(grads, want_g, B * T)
     _check(worst < tol, f"zaya: a leaf's gradient norm is {worst:.4f} off "
            f"the reference's (tolerance {tol:.4f}, {flips} tokens flipped)")
     return {"shape": {"B": B, "T": T, "dim": m.dim, "heads": m.heads,
@@ -385,6 +396,77 @@ def _leg_zaya(on_tpu: bool, here: str) -> dict:
                       "experts_held": list(m.held), "experts": m.experts},
             "loss": float(loss), "reference_loss": want,
             "tokens_flipped": flips, "grad_norm_worst_gap": round(worst, 5),
+            "tokens_held": mine["tokens_held"].tolist()}
+
+
+def _leg_joyai(on_tpu: bool, here: str) -> dict:
+    """One forward and backward of the latent-attention expert decoder cut
+    to a dense layer, an expert layer and the prediction module, bfloat16
+    worker math as the cell runs it, against the plain float32 reference
+    the benchmark keeps (published widths on the chip, toy widths off
+    it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from minips_tpu.models import mla_moe
+    from minips_tpu.tables.dense import cast_floating
+
+    bench = os.path.join(here, "bench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from benchlib.reference import joyai_ref
+
+    with open(os.path.join(bench, "configs", "joyai-llm-flash.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=2)
+    B, T = 2, 1024
+    if not on_tpu:
+        config.update(
+            hidden_size=32, num_attention_heads=2, q_lora_rank=24,
+            kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, intermediate_size=48, moe_intermediate_size=16,
+            n_routed_experts=4, held_experts=[0, 4], num_experts_per_tok=2,
+            vocab_size=128, head_chunk=16,
+            published=dict(config["published"], n_routed_experts=8))
+        T = 64
+    m = mla_moe.from_config(config)
+    params = mla_moe.init(jax.random.PRNGKey(0), m)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, T + 1), 0, m.vocab)
+    cd = jnp.bfloat16
+    z = joyai_ref._sizes(config)
+    # the balancing bias both start from: the reference's own centring
+    bias = jnp.asarray(joyai_ref.centred_bias(params, toks, z, B))
+    how = dict(compute_dtype=cd, attn_impl="flash",
+               head_chunk=int(config["head_chunk"]))
+    loss, grads, _ = jax.jit(lambda p, t: mla_moe.grad_fn(
+        cast_floating(p, cd), {"tokens": t}, bias, m, **how))(params, toks)
+    (want, aux), want_g = jax.jit(jax.value_and_grad(
+        lambda p, t: joyai_ref.loss_sums(p, t, bias, z, False),
+        has_aux=True))(params, toks)
+    want = float(want) / (B * T)
+    mine = jax.jit(lambda p, t: mla_moe.routing_stats(
+        p, {"tokens": t}, bias, m, **how))(params, toks)
+    held = aux[0][:, m.held[0]: m.held[1]]
+    moved = int(jnp.sum(jnp.abs(mine["tokens_held"] - held)))
+    _check(math.isfinite(float(loss)) and abs(float(loss) - want)
+           < 2e-3 * want, f"joyai: loss {float(loss)} against the "
+           f"reference's {want}")
+    # the norm of each leaf's gradient; an assignment that bfloat16 sends
+    # to another expert than float32 moves that expert's leaves, so the
+    # tolerance is bfloat16's plus the share of held assignments that moved
+    tol = 0.05 + 4.0 * moved / max(int(jnp.sum(held)), 1)
+    worst = _grad_norm_gap(grads, want_g, B * T)
+    _check(worst < tol, f"joyai: a leaf's gradient norm is {worst:.4f} off "
+           f"the reference's (tolerance {tol:.4f}, {moved} held assignments "
+           "moved)")
+    return {"shape": {"B": B, "T": T, "dim": m.dim, "heads": m.heads,
+                      "qk_head": m.nope + m.rope, "v_head": m.v_dim,
+                      "experts_held": list(m.held), "experts": m.experts,
+                      "top_k": m.top_k, "blocks": m.depth + m.mtp},
+            "loss": float(loss), "reference_loss": want,
+            "lm_nll": float(mine["lm_nll"]),
+            "mtp_nll": float(mine["mtp_nll"]),
+            "held_assignments_moved": moved,
+            "grad_norm_worst_gap": round(worst, 5),
             "tokens_held": mine["tokens_held"].tolist()}
 
 
@@ -432,6 +514,8 @@ def main() -> int:
     legs["kernels"] = _leg_kernels(on_tpu)
     gc.collect()
     legs["zaya"] = _leg_zaya(on_tpu, here)
+    gc.collect()
+    legs["joyai"] = _leg_joyai(on_tpu, here)
     _check(not native_lib.loaded_libs(),
            f"a native library was loaded: {native_lib.loaded_libs()}")
 

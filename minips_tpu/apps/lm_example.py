@@ -23,22 +23,29 @@ demonstrates the long-context/model-parallel paths end-to-end. Layouts:
   ``--capacity``).
 
 ``--model_config FILE`` (dp layout) takes the model from a configuration
-file of published keys instead of the flags: the ZAYA1-shaped decoder of
-``models/zaya.py`` (compressed convolutional attention, a dropless top-1
-expert layer that is told which experts it holds), trained through the
-same ``DenseTable.make_step``; ``bench/configs/zaya1-8b.json`` is such a
-file, and the benchmark's adapter calls :func:`zaya_dp_step` as ``run``
-does.
+file of published keys instead of the flags, by the file's ``model_type``
+(``CONFIG_MODELS``): the ZAYA1-shaped decoder of ``models/zaya.py``
+(compressed convolutional attention, a dropless top-1 expert layer that
+is told which experts it holds) or the latent-attention expert decoder of
+``models/mla_moe.py`` (MLA, sigmoid top-k experts beside a shared one, a
+leading dense layer, an untied head, a multi-token-prediction module),
+trained through the same ``DenseTable.make_step``;
+``bench/configs/zaya1-8b.json`` and ``bench/configs/joyai-llm-flash.json``
+are such files, and the benchmark's adapters call :func:`model_dp_step`
+as ``run`` does.
 
 Usage: python -m minips_tpu.apps.lm_example --num_iters 200 --layout sp
        python -m minips_tpu.apps.lm_example --layout tp --tp 2
        python -m minips_tpu.apps.lm_example --seq_len 8192 --batch_size 4 \
            --model_config bench/configs/zaya1-8b.json
+       python -m minips_tpu.apps.lm_example --seq_len 8192 --batch_size 2 \
+           --model_config bench/configs/joyai-llm-flash.json
 """
 
 from __future__ import annotations
 
 import functools
+import inspect
 import json
 
 import jax
@@ -51,6 +58,7 @@ from minips_tpu.apps.common import app_main, log_tables_built
 from minips_tpu.core.config import Config, TableConfig, TrainConfig
 from minips_tpu.data import synthetic
 from minips_tpu.data.loader import BatchIterator
+from minips_tpu.models import mla_moe
 from minips_tpu.models import transformer as tfm
 from minips_tpu.models import zaya
 from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
@@ -64,6 +72,11 @@ DEFAULT = Config(
 )
 
 MODEL = dict(vocab=256, dim=64, heads=4, depth=2, max_len=1024)
+# --model_config: the file's ``model_type`` names the module, each with
+# ``from_config``, ``init``, ``grad_fn``, ``routing_stats`` and
+# ``centred_bias``; a file without the key is ZAYA's, as before the key
+# was read
+CONFIG_MODELS = {"zaya": zaya, mla_moe.MODEL_TYPE: mla_moe}
 
 
 def _flags(parser):
@@ -85,11 +98,11 @@ def _flags(parser):
     parser.add_argument("--seq_len", type=int, default=128)
     parser.add_argument("--model_config", default=None,
                         help="dp layout: a configuration file of published "
-                             "keys (hidden_size, num_experts, cca_time0, "
-                             "...) names the model, its attention, head "
-                             "chunk and worker precision, in place of "
+                             "keys (model_type, hidden_size, ...) names "
+                             "the model, its attention, head chunk and "
+                             "worker precision, in place of "
                              "--dim/--depth/--heads/--attn/--dtype "
-                             "(models/zaya.py)")
+                             "(models/zaya.py, models/mla_moe.py)")
     parser.add_argument("--tp", type=int, default=2,
                         help="model-axis size for tp/pp layouts")
     parser.add_argument("--microbatches", type=int, default=4,
@@ -253,30 +266,51 @@ def _updater_kwargs(cfg, args, params):
     return kw
 
 
-def zaya_dp_step(config: dict, mesh, params, first_batch, *, updater: str,
-                 lr, name: str = "lm"):
+def config_model(config: dict):
+    """The module that builds a configuration file's model, by its
+    ``model_type``."""
+    kind = config.get("model_type", "zaya")
+    if kind not in CONFIG_MODELS:
+        raise SystemExit(f"--model_config: model_type {kind!r} is not "
+                         f"built (have {sorted(CONFIG_MODELS)})")
+    return CONFIG_MODELS[kind]
+
+
+def model_dp_step(config: dict, mesh, params, first_batch, *, updater: str,
+                  lr, name: str = "lm"):
     """The dp layout's table and fused step for a model taken from a
-    configuration file: ``(model, table, step, stats)``. ``config`` holds
-    the published keys (``zaya.from_config``) and how it is run: ``attn``,
+    configuration file, whichever module builds it (``config_model``):
+    ``(model, table, step, stats)``. ``config`` holds the published keys
+    (the module's ``from_config``) and how it is run: ``attn``,
     ``head_chunk``, ``compute_dtype``, ``router_bias_rate``. ``params`` is
-    the caller's initial tree (the app draws ``zaya.init``, the benchmark
-    makes its own from its seed); the table owns the state from here on,
-    the router's balancing bias with it (``table.state``, started by
-    ``zaya.centred_bias`` over ``first_batch``, as ``prep`` places it).
-    ``stats(table.pull(), batch, table.state)`` is the routing observer
-    (``zaya.routing_stats``), jitted apart from the step."""
-    m = zaya.from_config(config)
+    the caller's initial tree (the app draws the module's ``init``, the
+    benchmark makes its own from its seed); the table owns the state from
+    here on, the routers' balancing bias with it (``table.state``, started
+    by the module's ``centred_bias`` over ``first_batch``, as ``prep``
+    places it). ``stats(table.pull(), batch, table.state)`` is the routing
+    observer (the module's ``routing_stats``), jitted apart from the
+    step."""
+    model = config_model(config)
+    m = model.from_config(config)
     cd = jnp.dtype(config.get("compute_dtype", "float32"))
-    how = dict(compute_dtype=cd, attn_impl=config.get("attn", "flash"))
-    stats = jax.jit(functools.partial(zaya.routing_stats, m=m, **how))
-    bias = zaya.centred_bias(lambda b: stats(params, first_batch, b), m)
+    how = dict(compute_dtype=cd, attn_impl=config.get("attn", "flash"),
+               head_chunk=int(config.get("head_chunk", 0)))
+    # an observer is run as the step is in what it takes (ZAYA's runs no
+    # head, so it takes no ``head_chunk``)
+    takes = inspect.signature(model.routing_stats).parameters
+    stats = jax.jit(functools.partial(
+        model.routing_stats, m=m,
+        **{k: v for k, v in how.items() if k in takes}))
+    bias = model.centred_bias(lambda b: stats(params, first_batch, b), m)
     table = DenseTable(params, mesh, updater=updater, lr=lr, name=name)
     step = table.make_step(
-        functools.partial(zaya.grad_fn, m=m, axis_name=DATA_AXIS, **how,
-                          head_chunk=int(config.get("head_chunk", 0))),
+        functools.partial(model.grad_fn, m=m, axis_name=DATA_AXIS, **how),
         batch_spec=P(DATA_AXIS),
         compute_dtype=None if cd == jnp.float32 else cd, state=bias)
     return m, table, step, stats
+
+
+zaya_dp_step = model_dp_step    # the name the benchmark's ZAYA adapter calls
 
 
 def _run_model_config(cfg, args, mesh, layout, seq_len):
@@ -309,27 +343,37 @@ def _run_model_config(cfg, args, mesh, layout, seq_len):
             jnp.asarray(batch["tokens"]), batch_sharding)}
         return last["batch"]
 
-    params = zaya.init(jax.random.PRNGKey(cfg.train.seed),
-                       zaya.from_config(config))
+    model = config_model(config)
+    params = model.init(jax.random.PRNGKey(cfg.train.seed),
+                        model.from_config(config))
     first = {"tokens": data["tokens"][: cfg.train.batch_size]}
-    _, table, step, stats = zaya_dp_step(
+    _, table, step, stats = model_dp_step(
         config, mesh, params, prep(first), updater=cfg.table.updater,
         lr=_lr_schedule(cfg, args), name=cfg.table.name)
 
     def routing_metrics() -> dict:
-        """The routing counters of the last batch fed, at ``log_every``."""
+        """The observer's counters of the last batch fed, at
+        ``log_every``: the routing and, where the model has a prediction
+        module beside its main head, the two losses apart."""
         with prof.span(prof.LOOP_READBACK):
             st = stats(table.pull(), last["batch"], table.state)
             st = jax.device_get({k: st[k] for k in (
-                "tokens_held", "absent_share", "load_max_over_mean")})
+                "tokens_held", "absent_share", "load_max_over_mean",
+                "lm_nll", "mtp_nll") if k in st})
             prof.counter(prof.MOE_TOKENS_HELD,
                          int(st["tokens_held"].sum()))
             prof.counter(prof.MOE_LOAD_MAX_OVER_MEAN,
                          float(st["load_max_over_mean"].max()))
+            losses = {}
+            for key, name in (("lm_nll", prof.LM_NLL),
+                              ("mtp_nll", prof.MTP_NLL)):
+                if key in st:
+                    losses[key] = float(st[key])
+                    prof.counter(name, losses[key])
         return {"moe_tokens_held": st["tokens_held"].tolist(),
                 "moe_absent_share": st["absent_share"].tolist(),
                 "moe_load_max_over_mean":
-                    st["load_max_over_mean"].tolist()}
+                    st["load_max_over_mean"].tolist(), **losses}
 
     return data, table, step, prep, routing_metrics
 

@@ -27,8 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from minips_tpu.ops.flash_attention import _pcast_varying
-from minips_tpu.parallel.mesh import DATA_AXIS
+from minips_tpu.parallel.mesh import DATA_AXIS, pcast_varying
 from minips_tpu.utils import profiling as prof
 from minips_tpu.parallel.ring_attention import (
     reference_attention,
@@ -691,7 +690,7 @@ def _nll_chunked(h, tok_emb, targets, chunk, compute_dtype):
         return acc + _chunk_logp(xt[0], w, xt[1])[1], None
 
     total, _ = jax.lax.scan(
-        body, _pcast_varying(jnp.zeros((), jnp.float32), jax.typeof(h).vma),
+        body, pcast_varying(jnp.zeros((), jnp.float32), jax.typeof(h).vma),
         _head_chunks(h, targets, chunk))
     return total / targets.size
 
@@ -716,8 +715,8 @@ def _nll_chunked_fwd(h, tok_emb, targets, chunk, compute_dtype):
         return (acc + nll_sum, dw), (dlogits @ w).astype(hc.dtype)
 
     (total, dw), dhs = jax.lax.scan(
-        body, (_pcast_varying(jnp.zeros((), jnp.float32), vma),
-               _pcast_varying(jnp.zeros_like(tok_emb), vma)),
+        body, (pcast_varying(jnp.zeros((), jnp.float32), vma),
+               pcast_varying(jnp.zeros_like(tok_emb), vma)),
         _head_chunks(h, targets, chunk))
     return total / targets.size, (jnp.moveaxis(dhs, 0, 1).reshape(h.shape),
                                   dw)
@@ -755,7 +754,7 @@ def nll_chunked(h, tok_emb, targets, chunk, compute_dtype=jnp.bfloat16):
     # transpose is the psum its gradient needs
     vma = frozenset().union(*(jax.typeof(x).vma
                               for x in (h, tok_emb, targets)))
-    return _nll_chunked(_pcast_varying(h, vma), _pcast_varying(tok_emb, vma),
+    return _nll_chunked(pcast_varying(h, vma), pcast_varying(tok_emb, vma),
                         targets, chunk, compute_dtype)
 
 

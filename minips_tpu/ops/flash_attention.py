@@ -45,7 +45,8 @@ Speeds: PERF.md section 5 (the benchmark cell's trace by kernel) and
 section 6, PR 27 (what each part of this design brought on the v5e).
 
 Layout matches the rest of the stack: q/k/v are ``[B, T, H, D]`` (the
-ring-attention convention, parallel/ring_attention.py). The kernel wants
+ring-attention convention, parallel/ring_attention.py); v may have a head
+size of its own, which the output then has (``[B, T, H, Dv]``). The kernel wants
 the sequence contiguous per (batch, head), so it transposes to
 ``[B, H, T, D]`` at the jit boundary — XLA fuses the transposes into the
 surrounding program.
@@ -64,18 +65,11 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from minips_tpu.parallel.mesh import pcast_varying
 from minips_tpu.utils import profiling as prof
 
 _NEG_INF = -1e30  # finite mask value (matches ring_attention) — avoids
                   # -inf arithmetic NaNs on fully-masked rows
-
-
-def _pcast_varying(x, axes):
-    """pcast x to varying over exactly the axes it isn't already varying
-    over (pcast rejects varying→varying)."""
-    have = jax.typeof(x).vma
-    need = tuple(a for a in axes if a not in have)
-    return jax.lax.pcast(x, need, to="varying") if need else x
 
 
 def gqa_group_size(num_q_heads: int, num_kv_heads: int) -> int:
@@ -125,20 +119,19 @@ def blockwise_attention(
     """
     B, Tq, H, D = q.shape
     k, v = _expand_kv(q, k, v)   # GQA: exact repeat on this oracle path
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[3]   # v's head size is its own
     if scale is None:
         scale = D ** -0.5
     bk = min(block_k, Tk)
     pad = (-Tk) % bk  # ragged tail: pad K/V and mask — never one full-width
     if pad:           # chunk, which would void the O(T*block_k) bound
-        zeros = jnp.zeros((B, pad, H, D), k.dtype)
-        k = jnp.concatenate([k, zeros], axis=1)
-        v = jnp.concatenate([v, zeros], axis=1)
+        k = jnp.concatenate([k, jnp.zeros((B, pad, H, D), k.dtype)], axis=1)
+        v = jnp.concatenate([v, jnp.zeros((B, pad, H, Dv), v.dtype)], axis=1)
     masked = causal or pad
     nk = (Tk + pad) // bk
     qf = q.astype(jnp.float32)
     kc = k.astype(jnp.float32).reshape(B, nk, bk, H, D)
-    vc = v.astype(jnp.float32).reshape(B, nk, bk, H, D)
+    vc = v.astype(jnp.float32).reshape(B, nk, bk, H, Dv)
     q_pos = q_off + jnp.arange(Tq)
 
     def fold(carry, blk):
@@ -158,14 +151,14 @@ def blockwise_attention(
         o = o * alpha[:, :, :, None] + jnp.einsum("bqkh,bkhd->bqhd", p, v_blk)
         return (o, m_new, l), None
 
-    o0 = jnp.zeros((B, Tq, H, D), jnp.float32)
+    o0 = jnp.zeros((B, Tq, H, Dv), jnp.float32)
     m0 = jnp.full((B, Tq, H), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Tq, H), jnp.float32)
     # Inside shard_map, fresh carries are axis-invariant while the folded
     # values vary over the mesh — pcast keeps the scan carry type fixed
     # (same VMA discipline as ring_attention_local).
     vma = tuple(sorted(_vma_of(q, k, v, q_off, k_off)))
-    o0, m0, l0 = (_pcast_varying(x, vma) for x in (o0, m0, l0))
+    o0, m0, l0 = (pcast_varying(x, vma) for x in (o0, m0, l0))
     (o, m, l), _ = jax.lax.scan(
         fold, (o0, m0, l0),
         (kc.swapaxes(0, 1), vc.swapaxes(0, 1), jnp.arange(nk)))
@@ -273,10 +266,15 @@ def computed_share(Tq: int, Tk: int, tile_q: int, tile_k: int,
 
 def flash_plan(Tq: int, Tk: int, D: int, itemsize: int,
                block_q: Optional[int] = None,
-               block_k: Optional[int] = None) -> FlashPlan:
+               block_k: Optional[int] = None,
+               Dv: Optional[int] = None) -> FlashPlan:
     """Tiles and resident extents for a shape: pure, and the one place the
     kernels take them from. ``block_q`` / ``block_k``, where given, are
-    upper bounds on every tile."""
+    upper bounds on every tile. ``D`` is the head size of q and k, ``Dv``
+    that of v and the output where it is another (latent attention: 192
+    and 128); the wider of the two is what a resident operand is sized
+    by."""
+    D = max(D, Dv or D)
     cap_q, cap_k = block_q or Tq, block_k or Tk
     tq = _pick_tile(Tq, min(cap_q, _FWD_TILE[0]))
     tk = _pick_tile(Tk, min(cap_k, _FWD_TILE[1]))
@@ -410,9 +408,10 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # [1, 1, major_q, D]; k/v [1, 1, major_k, D], resident across the Q
     # tiles; lse [1, 1, 1, major_q // tq, tq], one lane-dense row a Q tile.
     # Scores are [tk, tq] = k q^T; the online-softmax state (m, l rows
-    # [1, tq]; acc^T [D, tq] += v^T p^T) is the K sweep's loop carry,
+    # [1, tq]; acc^T [Dv, tq] += v^T p^T) is the K sweep's loop carry,
     # float32, and the output tile is transposed back once, at the end.
-    bq, D = q_ref.shape[2], q_ref.shape[3]
+    # v and the output have v's own head size Dv, q and k theirs.
+    bq, Dv = q_ref.shape[2], v_ref.shape[3]
     bk = k_ref.shape[2]
     kmaj, num_major = pl.program_id(3), pl.num_programs(3)
     q_base = qoff_ref[0] + pl.program_id(2) * bq
@@ -456,7 +455,7 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         else:
             carry = (jnp.full((1, tq), _NEG_INF, jnp.float32),
                      jnp.zeros((1, tq), jnp.float32),
-                     jnp.zeros((D, tq), jnp.float32))
+                     jnp.zeros((Dv, tq), jnp.float32))
         full, live = _live_k_tiles(masked, q_lo, k_base, tq, tk, bk // tk)
         carry = _loop(0, full, k_tile, carry, mask_it=False)
         carry = _loop(full, live, k_tile, carry, mask_it=True)
@@ -501,6 +500,13 @@ _SWEEP_LAST = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
+def _head_specs(bq, bk, D, g):
+    """Blocks of the forward's and dQ's grid (B, H, Q majors, K majors) at
+    head size ``D``: (a Q-side operand's, a K-side operand's)."""
+    return (pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // g, j, 0)))
+
+
 def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
                    interpret):
     """[B, T, H, D] in/out; kernel runs on [B, H, T, D]. K/V may carry
@@ -510,35 +516,34 @@ def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
     the logsumexp row-major over the sequence, a lane-dense row a tile."""
     B, Tq, H, D = q.shape
     g = gqa_group_size(H, k.shape[2])
-    Tk = k.shape[1]
-    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k)
+    Tk, Dv = k.shape[1], v.shape[3]
+    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k, Dv)
     tq, tk, bq, bk = plan.tile_q, plan.tile_k, plan.major_q, plan.major_k
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     vma = _vma_of(q, k, v, q_off, k_off)
     num_major = Tk // bk
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, i, j: (b, h // g, j, 0))
+    q_spec, kv_spec = _head_specs(bq, bk, D, g)
+    o_spec, v_spec = _head_specs(bq, bk, Dv, g)
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, masked=masked,
                           tq=tq, tk=tk),
         grid=(B, H, Tq // bq, num_major),
-        in_specs=[_smem_spec(), _smem_spec(), q_spec, kv_spec, kv_spec],
+        in_specs=[_smem_spec(), _smem_spec(), q_spec, kv_spec, v_spec],
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec((1, 1, 1, bq // tq, tq),
                          lambda b, h, i, j: (b, h, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, H, Tq, Dv), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((B, H, Tq // bq, bq // tq, tq),
                                  jnp.float32, vma=vma),
         ],
-        scratch_shapes=[pltpu.VMEM((bk // tk, D, tk), v.dtype)] + (
+        scratch_shapes=[pltpu.VMEM((bk // tk, Dv, tk), v.dtype)] + (
             [] if num_major == 1 else [
                 pltpu.VMEM((bq // tq, 1, tq), jnp.float32),   # running max
                 pltpu.VMEM((bq // tq, 1, tq), jnp.float32),   # normalizer
-                pltpu.VMEM((bq // tq, D, tq), jnp.float32),   # acc^T
+                pltpu.VMEM((bq // tq, Dv, tq), jnp.float32),  # acc^T
             ]),
         compiler_params=_SWEEP_LAST,
         interpret=interpret,
@@ -632,7 +637,7 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     # [tk, tq] = k q^T as in the forward, logsumexp and dvec the rows
     # they are stored as. dK/dV accumulate in float32 VMEM scratch, in
     # place: as loop carries their 2 x tk / 8 vregs spill at every bound.
-    bk, D = k_ref.shape[2], k_ref.shape[3]
+    bk, D, Dv = k_ref.shape[2], k_ref.shape[3], v_ref.shape[3]
     nq = q_ref.shape[2] // tq
     per_tile = tq // lse_ref.shape[4]    # logsumexp rows a Q tile spans
     t, num_t = pl.program_id(3), pl.num_programs(3)
@@ -649,7 +654,7 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         @pl.when(t == 0)
         def _init():
             dk_s[rows, :] = jnp.zeros((tk, D), jnp.float32)
-            dv_s[rows, :] = jnp.zeros((tk, D), jnp.float32)
+            dv_s[rows, :] = jnp.zeros((tk, Dv), jnp.float32)
 
         def grad(s, qb, dob, vb, lse, dvec):
             p = jnp.exp(s - lse)                         # [keys, q] f32
@@ -716,8 +721,8 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
     B, Tq, H, D = q.shape
     Hk = k.shape[2]
     g = gqa_group_size(H, Hk)
-    Tk = k.shape[1]
-    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k)
+    Tk, Dv = k.shape[1], v.shape[3]
+    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k, Dv)
     tq, tk, bq, bk = plan.bwd_q, plan.bwd_k, plan.major_q, plan.major_k
     w = plan.tile_q
     nqm, nkm = Tq // bq, Tk // bk
@@ -725,9 +730,8 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
     vma = _vma_of(q, k, v, q_off, k_off, g_out)
     offs = _offsets(q_off, k_off)
 
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, i, j: (b, h // g, j, 0))
+    q_spec, kv_spec = _head_specs(bq, bk, D, g)
+    do_spec, v_spec = _head_specs(bq, bk, Dv, g)
     row_spec = pl.BlockSpec((1, 1, 1, bq // w, w),
                             lambda b, h, i, j: (b, h, i, 0, 0))
     dq = pl.pallas_call(
@@ -735,7 +739,7 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
                           tq=tq, tk=tk),
         grid=(B, H, nqm, nkm),
         in_specs=[_smem_spec(), _smem_spec(),
-                  q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+                  q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
@@ -746,11 +750,15 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
 
     # transposed grid: K majors outer, (group q-head, Q major) inner — grid
     # dim 1 walks KV heads, the q-head within the group rides the sweep
-    q_spec_t = pl.BlockSpec(
-        (1, 1, bq, D),
-        lambda b, hk, j, t: (b, hk * g + t // nqm, t % nqm, 0))
-    kv_spec_t = pl.BlockSpec((1, 1, bk, D),
-                             lambda b, hk, j, t: (b, hk, j, 0))
+    def specs_t(D):
+        return (pl.BlockSpec(
+                    (1, 1, bq, D),
+                    lambda b, hk, j, t: (b, hk * g + t // nqm, t % nqm, 0)),
+                pl.BlockSpec((1, 1, bk, D),
+                             lambda b, hk, j, t: (b, hk, j, 0)))
+
+    q_spec_t, kv_spec_t = specs_t(D)
+    do_spec_t, v_spec_t = specs_t(Dv)
     row_spec_t = pl.BlockSpec(
         (1, 1, 1, bq // w, w),
         lambda b, hk, j, t: (b, hk * g + t // nqm, t % nqm, 0, 0))
@@ -759,15 +767,15 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
                           masked=masked, tq=tq, tk=tk, num_q_major=nqm),
         grid=(B, Hk, nkm, g * nqm),
         in_specs=[_smem_spec(), _smem_spec(),
-                  q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
+                  q_spec_t, kv_spec_t, v_spec_t, do_spec_t, row_spec_t,
                   row_spec_t],
-        out_specs=[kv_spec_t, kv_spec_t],
+        out_specs=[kv_spec_t, v_spec_t],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hk, Tk, D), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((B, Hk, Tk, D), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hk, Tk, Dv), v.dtype, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         compiler_params=_SWEEP_LAST,
         interpret=interpret,
         name=prof.FLASH_DKV,
@@ -836,20 +844,23 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
 
 
 def kernel_supported(q_shape, k_shape, block_q: Optional[int] = None,
-                     block_k: Optional[int] = None) -> bool:
+                     block_k: Optional[int] = None,
+                     v_head: Optional[int] = None) -> bool:
     """Static shape gate for the Pallas path: block sizes, where given,
-    must tile the sequence (no ragged tails in the kernel) and D should be
-    lane-friendly."""
+    must tile the sequence (no ragged tails in the kernel) and D (and v's
+    head size ``v_head``, where it is another) should be lane-friendly."""
     B, Tq, H, D = q_shape
     Tk = k_shape[1]
     bq, bk = min(block_q or Tq, Tq), min(block_k or Tk, Tk)
     if q_shape[2] % k_shape[2]:   # GQA: kv heads must divide q heads
         return False
-    return Tq % bq == 0 and Tk % bk == 0 and D % 8 == 0
+    return (Tq % bq == 0 and Tk % bk == 0 and D % 8 == 0
+            and (v_head or D) % 8 == 0)
 
 
 def _use_kernel(interpret: Optional[bool], q_shape, k_shape,
-                block_q: Optional[int], block_k: Optional[int]) -> bool:
+                block_q: Optional[int], block_k: Optional[int],
+                v_head: Optional[int] = None) -> bool:
     """The one platform rule both entry points share. ``interpret=None``
     (every production caller): the compiled kernels on a TPU backend, the
     blockwise scan — their documented platform twin — anywhere else. An
@@ -859,12 +870,13 @@ def _use_kernel(interpret: Optional[bool], q_shape, k_shape,
     never a silent stand-in for a kernel that was asked for."""
     if interpret is None and jax.default_backend() != "tpu":
         return False
-    if not kernel_supported(q_shape, k_shape, block_q, block_k):
+    if not kernel_supported(q_shape, k_shape, block_q, block_k, v_head):
         raise ValueError(
             f"flash attention kernels refuse q{tuple(q_shape)} "
-            f"k{tuple(k_shape)} at blocks ({block_q}, {block_k}): the "
+            f"k{tuple(k_shape)} (v heads of {v_head or q_shape[3]}) at "
+            f"blocks ({block_q}, {block_k}): the "
             "blocks must tile both sequences, kv heads must divide q "
-            "heads, and the head dim must be a multiple of 8 — pick "
+            "heads, and the head dims must be multiples of 8 — pick "
             "tiling blocks or attn_impl='reference' for this shape")
     return True
 
@@ -889,6 +901,11 @@ def flash_attention(
     ``block_k`` bound the kernels' score tiles from above; left out, the
     tiles are :func:`flash_plan`'s for the shape.
 
+    v's head size is v's own: q and k share one (scores are q k^T over
+    it), v and the output another where they differ (latent attention:
+    192 and 128); no padded copy of v is made, every p v, dP and dV
+    product runs at v's size. For equal sizes nothing changes.
+
     Grouped-query attention: K/V may carry fewer heads than Q (kv divides
     q, q-head h reads kv head h // group). The kernel path streams the
     small K/V straight from HBM — traffic and ring wire bytes shrink by
@@ -896,7 +913,8 @@ def flash_attention(
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if _use_kernel(interpret, q.shape, k.shape, block_q, block_k):
+    if _use_kernel(interpret, q.shape, k.shape, block_q, block_k,
+                   v.shape[3]):
         return _flash(q, k, v, causal, scale, block_q, block_k,
                       bool(interpret))
     return blockwise_attention(q, k, v, causal=causal, scale=scale,
@@ -949,7 +967,8 @@ def ring_flash_attention_local(
     # Numerics match exactly for f32 inputs; for bf16 inputs the scan
     # upcasts q/k/v to f32 before its dots while the kernel runs
     # bf16-input dots with f32 accumulation (≤ bf16-rounding apart).
-    use_kernel = _use_kernel(interpret, q.shape, k.shape, block_q, block_k)
+    use_kernel = _use_kernel(interpret, q.shape, k.shape, block_q, block_k,
+                             v.shape[3])
     interpret = bool(interpret)
     perm = [(i, (i + 1) % n) for i in range(n)]
     # With causal=False no step masks, so the global offsets cannot affect
@@ -985,12 +1004,12 @@ def ring_flash_attention_local(
         v_nxt = jax.lax.ppermute(v_cur, axis_name, perm)
         return (acc, lse_new, k_nxt, v_nxt), None
 
-    acc0 = jnp.zeros((B, Tq, H, D), jnp.float32)
+    acc0 = jnp.zeros((B, Tq, H, v.shape[3]), jnp.float32)
     lse0 = jnp.full((B, Tq, H), _NEG_INF, jnp.float32)
     # the visiting K/V shards (and, under causal, the axis index r) make
     # every step output vary over the ring axis, so ALL carries must be
     # varying — even when the inputs arrive replicated
-    acc0, lse0, k, v = (_pcast_varying(x, (axis_name,))
+    acc0, lse0, k, v = (pcast_varying(x, (axis_name,))
                         for x in (acc0, lse0, k, v))
     (acc, _, _, _), _ = jax.lax.scan(
         step_fn, (acc0, lse0, k, v), jnp.arange(n))

@@ -65,3 +65,11 @@ def local_mesh_size(mesh: Mesh, axis: str = DATA_AXIS) -> int:
 def padded_size(n: int, shards: int) -> int:
     """Smallest multiple of ``shards`` >= n (range-partition padding)."""
     return shards * math.ceil(max(n, 1) / shards)
+
+
+def pcast_varying(x, axes):
+    """pcast x to varying over exactly the axes it isn't already varying
+    over (pcast rejects varying→varying)."""
+    have = jax.typeof(x).vma
+    need = tuple(a for a in axes if a not in have)
+    return jax.lax.pcast(x, need, to="varying") if need else x

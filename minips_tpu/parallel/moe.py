@@ -30,9 +30,12 @@ chip's share of a larger expert layer computes (models/zaya.py).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from minips_tpu.parallel.mesh import pcast_varying
 from minips_tpu.utils import profiling as prof
 
 
@@ -202,30 +205,16 @@ def dropless_dispatch(expert, held: tuple[int, int]):
     return order, inverse, sizes
 
 
-def moe_apply_dropless(experts, x, expert, gate, *, held: tuple[int, int],
-                       compute_dtype=jnp.bfloat16):
-    """The part of a top-1 gated-SiLU expert layer that the experts held
-    here give: [N, D] -> [N, D] float32.
+def _grouped_rows(n: int, sizes):
+    """[n, 1] bool: the rows that the groups ``sizes`` cover."""
+    return jnp.arange(n, dtype=jnp.int32)[:, None] < jnp.sum(sizes)
 
-    ``experts`` holds the stacks ``w_gate``, ``w_up`` [n_held, D, F] and
-    ``w_down`` [n_held, F, D] of the experts ``held = (lo, hi)`` out of
-    however many the router knows; ``expert`` [N] is each token's choice
-    over ALL of them and ``gate`` [N] its probability. A token whose
-    expert is held gets ``gate * (silu(x w_gate) * (x w_up)) w_down``, a
-    token whose expert lives elsewhere gets 0: what the absent experts
-    would add is another worker's part (on one chip there is no
-    exchange, and nothing stands in for one). Tokens are sorted by expert
-    and go through three grouped products (``jax.lax.ragged_dot``); rows
-    beyond the groups' sum are kept zero."""
-    n_held = held[1] - held[0]
-    if experts["w_gate"].shape[0] != n_held:
-        raise ValueError(f"held {held} names {n_held} experts but the "
-                         f"stacks hold {experts['w_gate'].shape[0]}")
-    with jax.named_scope(prof.LM_MOE_DISPATCH):
-        order, inverse, sizes = dropless_dispatch(expert, held)
-        xs = _permute(x.astype(compute_dtype), order, inverse)
-        grouped_rows = (jnp.arange(x.shape[0], dtype=jnp.int32)[:, None]
-                        < jnp.sum(sizes))
+
+def _grouped_swiglu(experts, xs, sizes, grouped_rows, compute_dtype):
+    """``(silu(xs w_gate) * (xs w_up)) w_down`` over rows ``xs`` sorted by
+    expert, ``sizes`` rows an expert held: three grouped products
+    (``jax.lax.ragged_dot``); the rows beyond the groups' sum (not in
+    ``grouped_rows``) stay zero."""
 
     def live(a):
         return jnp.where(grouped_rows, a, jnp.zeros((), a.dtype))
@@ -237,11 +226,163 @@ def moe_apply_dropless(experts, x, expert, gate, *, held: tuple[int, int],
         return live(jax.lax.ragged_dot(live(a), w.astype(compute_dtype),
                                        sizes))
 
+    g = grouped(xs, experts["w_gate"])
+    u = grouped(xs, experts["w_up"])
+    act = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    return grouped(act.astype(compute_dtype), experts["w_down"])
+
+
+def _window_sizes(sizes, lo, r: int):
+    """The share of every held expert's group (``sizes`` rows each, one
+    group after another) that lies in the rows [lo, lo + r)."""
+    ends = jnp.cumsum(sizes)
+    return jnp.clip(jnp.minimum(ends, lo + r) - jnp.maximum(ends - sizes, lo),
+                    0, r)
+
+
+def _window(experts, xs, g, sz, compute_dtype):
+    """One window's rows ``xs`` [r, D] (sorted by expert, ``sz`` of each)
+    through the experts, times their gates ``g`` [r]: [r, D] float32."""
+    with jax.named_scope(prof.LM_MOE_DISPATCH):
+        rows = _grouped_rows(xs.shape[0], sz)
     with jax.named_scope(prof.LM_MOE_EXPERTS):
-        g = grouped(xs, experts["w_gate"])
-        u = grouped(xs, experts["w_up"])
-        act = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
-        ys = grouped(act.astype(compute_dtype), experts["w_down"])
+        ys = _grouped_swiglu(experts, xs, sz, rows, compute_dtype)
+    with jax.named_scope(prof.LM_MOE_COMBINE):
+        return ys.astype(jnp.float32) * g[:, None]
+
+
+def _live_windows(sizes, r: int, body, carry):
+    """``body(window's first row, carry)`` over the windows of ``r`` rows
+    that hold an assignment to a held expert: as many turns as the routing
+    asks for and no more (a loop whose length no shape depends on). Under
+    ``shard_map`` every worker runs its own loop: the carries vary where
+    the routing does."""
+    total = jnp.sum(sizes)
+    vma = tuple(jax.typeof(total).vma)
+    return jax.lax.while_loop(
+        lambda c: c[0] < total,
+        lambda c: (c[0] + r, body(c[0], c[1])),
+        jax.tree.map(lambda t: pcast_varying(t, vma),
+                     (jnp.zeros((), jnp.int32), carry)))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _windows(experts, xc, gates, token, sizes, compute_dtype):
+    """Sum over the live windows of the sorted assignments, a window as
+    many rows as ``xc`` has: row i of a window is token ``token[i]``'s row
+    of ``xc`` through its expert, times ``gates[i]``, added onto that
+    token: [N, D] float32."""
+    r = xc.shape[0]
+
+    def body(lo, y):
+        tok = jax.lax.dynamic_slice(token, (lo,), (r,))
+        with jax.named_scope(prof.LM_MOE_DISPATCH):
+            xs = xc[tok]
+        ys = _window(experts, xs, jax.lax.dynamic_slice(gates, (lo,), (r,)),
+                     _window_sizes(sizes, lo, r), compute_dtype)
+        with jax.named_scope(prof.LM_MOE_COMBINE):
+            return y.at[tok].add(ys)
+
+    return _live_windows(sizes, r, body, jnp.zeros(xc.shape, jnp.float32))
+
+
+def _windows_fwd(experts, xc, gates, token, sizes, compute_dtype):
+    return (_windows(experts, xc, gates, token, sizes, compute_dtype),
+            (experts, xc, gates, token, sizes))
+
+
+def _windows_bwd(compute_dtype, res, dy):
+    # the forward's loop again, window by window: a window's products are
+    # made a second time and transposed while they are live, so nothing of
+    # a window outlives it but its sums (float32; cast once, at the end)
+    experts, xc, gates, token, sizes = res
+    r = xc.shape[0]
+    f32 = lambda t: jax.tree.map(                       # noqa: E731
+        lambda a: jnp.zeros(a.shape, jnp.float32), t)
+
+    def body(lo, carry):
+        d_experts, d_xc, d_gates = carry
+        tok = jax.lax.dynamic_slice(token, (lo,), (r,))
+        g = jax.lax.dynamic_slice(gates, (lo,), (r,))
+        sz = _window_sizes(sizes, lo, r)
+        with jax.named_scope(prof.LM_MOE_DISPATCH):
+            xs = xc[tok]
+        _, vjp = jax.vjp(lambda e, x, g: _window(e, x, g, sz, compute_dtype),
+                         experts, xs, g)
+        with jax.named_scope(prof.LM_MOE_COMBINE):
+            de, dxs, dg = vjp(dy[tok])
+        with jax.named_scope(prof.LM_MOE_DISPATCH):
+            d_xc = d_xc.at[tok].add(dxs.astype(jnp.float32))
+        return (jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                             d_experts, de),
+                d_xc, jax.lax.dynamic_update_slice(d_gates, dg, (lo,)))
+
+    d_experts, d_xc, d_gates = _live_windows(
+        sizes, r, body, (f32(experts), f32(xc), f32(gates)))
+    return (jax.tree.map(lambda d, w: d.astype(w.dtype), d_experts, experts),
+            d_xc.astype(xc.dtype), d_gates, None, None)
+
+
+_windows.defvjp(_windows_fwd, _windows_bwd)
+
+
+def _dropless_topk(experts, x, expert, gate, held, compute_dtype):
+    """``moe_apply_dropless`` for ``expert`` / ``gate`` [N, k]. The N * k
+    assignments are sorted by expert, those of held experts first; only
+    that head of the order is worked on, in windows of N rows, as many as
+    there are tokens: a window gathers its tokens' rows (never k copies of
+    every token at once), runs the grouped products, and adds each row,
+    times its gate, onto its token, so that a token which several held
+    experts chose gets their sum. The loop ends with the held assignments
+    (``_windows``: one turn unless the held experts take more than N of
+    the N * k, k at the most): the work follows the routing, no shape
+    does, and nothing is dropped."""
+    n, k = expert.shape
+    with jax.named_scope(prof.LM_MOE_DISPATCH):
+        order, inverse, sizes = dropless_dispatch(expert.reshape(n * k), held)
+        token = order // k
+        gates = _permute(gate.reshape(n * k).astype(jnp.float32), order,
+                         inverse)
+    # one set of varying axes for the inputs, the loops' carries and the
+    # cotangents (a custom_vjp's rule returns cotangents of the primals'
+    # own types); where the weights vary over fewer, this pcast's
+    # transpose is the psum their gradient needs
+    vma = tuple(jax.typeof(token).vma)
+    experts, xc, gates = jax.tree.map(
+        lambda t: pcast_varying(t, vma),
+        (experts, x.astype(compute_dtype), gates))
+    return _windows(experts, xc, gates, token, sizes, compute_dtype)
+
+
+def moe_apply_dropless(experts, x, expert, gate, *, held: tuple[int, int],
+                       compute_dtype=jnp.bfloat16):
+    """The part of a gated-SiLU expert layer that the experts held here
+    give: [N, D] -> [N, D] float32.
+
+    ``experts`` holds the stacks ``w_gate``, ``w_up`` [n_held, D, F] and
+    ``w_down`` [n_held, F, D] of the experts ``held = (lo, hi)`` out of
+    however many the router knows; ``expert`` [N] is each token's choice
+    over ALL of them and ``gate`` [N] its weight (top-1), or [N, k] each
+    for k choices a token. A token gets ``gate * (silu(x w_gate) * (x
+    w_up)) w_down`` of every chosen expert that is held, summed, and 0 of
+    one that lives elsewhere: what the absent experts would add is another
+    worker's part (on one chip there is no exchange, and nothing stands in
+    for one). Top-1: the tokens are sorted by expert and go through three
+    grouped products (``jax.lax.ragged_dot``); rows beyond the groups' sum
+    are kept zero. Top-k: the same over the sorted assignments, N rows at a
+    time (``_dropless_topk``)."""
+    n_held = held[1] - held[0]
+    if experts["w_gate"].shape[0] != n_held:
+        raise ValueError(f"held {held} names {n_held} experts but the "
+                         f"stacks hold {experts['w_gate'].shape[0]}")
+    if expert.ndim == 2:
+        return _dropless_topk(experts, x, expert, gate, held, compute_dtype)
+    with jax.named_scope(prof.LM_MOE_DISPATCH):
+        order, inverse, sizes = dropless_dispatch(expert, held)
+        xs = _permute(x.astype(compute_dtype), order, inverse)
+        rows = _grouped_rows(x.shape[0], sizes)
+    with jax.named_scope(prof.LM_MOE_EXPERTS):
+        ys = _grouped_swiglu(experts, xs, sizes, rows, compute_dtype)
     with jax.named_scope(prof.LM_MOE_COMBINE):
         ys = ys.astype(jnp.float32) * gate.astype(jnp.float32)[order][:, None]
         return _permute(ys, inverse, order)

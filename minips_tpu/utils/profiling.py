@@ -78,9 +78,21 @@ LM_MOE_ROUTER = "lm.moe.router"
 LM_MOE_DISPATCH = "lm.moe.dispatch"
 LM_MOE_EXPERTS = "lm.moe.experts"
 LM_MOE_COMBINE = "lm.moe.combine"
-# ---- counters of the routing observer (zaya.routing_stats), per log line
+# the latent-attention block (models/mla_moe.py): lm.attn.mla lies inside
+# lm.attn (latent projections, their norms, rotary, k from k_nope and the
+# shared k_rope), lm.moe.shared inside lm.moe, and lm.mtp is around the
+# whole prediction module, so that the lm.head inside it (lm.mtp/lm.head)
+# stays apart from the main head's
+LM_ATTN_MLA = "lm.attn.mla"
+LM_MOE_SHARED = "lm.moe.shared"
+LM_MTP = "lm.mtp"
+# ---- counters of the routing observer (zaya.routing_stats,
+# mla_moe.routing_stats), per log line; the two losses where a model has
+# a prediction module beside its main head
 MOE_TOKENS_HELD = "moe.tokens_held"
 MOE_LOAD_MAX_OVER_MEAN = "moe.load_max_over_mean"
+LM_NLL = "lm.nll"
+MTP_NLL = "mtp.nll"
 # ---- a torn checkpoint step that restore() walked past; value: the step
 CKPT_SKIP_TORN = "ckpt.skip_torn"
 # ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names
@@ -106,7 +118,8 @@ PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
           SPARSE_DEDUP, SPARSE_ADAGRAD_SORTED, SPARSE_ADAGRAD_DENSE,
           SPARSE_ADAM_SORTED, SPARSE_ADAM_DENSE,
           LM_EMBED, LM_ATTN, LM_MLP, LM_HEAD, LM_ATTN_CCA, LM_MOE,
-          LM_MOE_ROUTER, LM_MOE_DISPATCH, LM_MOE_EXPERTS, LM_MOE_COMBINE)
+          LM_MOE_ROUTER, LM_MOE_DISPATCH, LM_MOE_EXPERTS, LM_MOE_COMBINE,
+          LM_ATTN_MLA, LM_MOE_SHARED, LM_MTP)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, GATHER_ROWS, RAGGED_DOT)
 
 RING_SPANS = 8192

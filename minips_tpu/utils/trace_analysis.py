@@ -205,13 +205,19 @@ def read_xplane(path: str) -> Trace:
 
 # ------------------------------------------------------------ the reduction
 def phase_of(scope: str) -> tuple[Optional[str], str]:
-    """(the innermost named phase in an op_name path, or None; which pass:
+    """(the innermost named phase in an op_name path, under ``lm.mtp/``
+    where the path lies in the prediction module, or None; which pass:
     ``fwd``, ``bwd`` (inside ``transpose(``) or ``remat`` (the forward run
     again for the backward, inside ``rematted_computation``))."""
     hits = _PHASE.findall(scope)
     part = ("remat" if "rematted_computation" in scope
             else "bwd" if "transpose(" in scope else "fwd")
-    return (hits[-1] if hits else None), part
+    phase = hits[-1] if hits else None
+    if prof.LM_MTP in hits and phase != prof.LM_MTP:
+        # the prediction module runs a block and a head of its own: its
+        # parts stay apart from the main model's (lm.mtp/lm.head)
+        phase = f"{prof.LM_MTP}/{phase}"
+    return phase, part
 
 
 def kernel_of(op: Op) -> Optional[str]:
