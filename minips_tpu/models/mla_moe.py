@@ -35,7 +35,7 @@ Plain-dict parameters like the other models, so the whole LM lives in one
 ``DenseTable`` and trains through ``DenseTable.make_step``; attention goes
 through ``transformer._attn_fn``, the untied head through
 ``transformer.nll_chunked`` and every block is recomputed in the backward
-pass but for what the flash forward kernel leaves for its backward kernels
+pass but for what the flash forward kernel leaves for its backward kernel
 (``transformer._remat_policy("attn")``).
 """
 

@@ -284,7 +284,7 @@ def _remat_policy(remat):
     """Rematerialization spectrum for the block checkpoint — the
     FLOPs↔HBM dial (SURVEY brief: jax.checkpoint to trade FLOPs for
     memory). Every mode but ``True`` also keeps what the flash forward
-    kernel leaves for its backward kernels (``prof.FLASH_RESIDUALS``:
+    kernel leaves for its backward kernel (``prof.FLASH_RESIDUALS``:
     ``out`` and the row logsumexp ``lse``, named in
     ``ops/flash_attention.py``), so with ``attn_impl="flash"`` the
     kernel runs once a block and step, not again for the backward; off

@@ -24,14 +24,26 @@ score matrix in HBM):
   normalizer l: lane-dense rows; accumulator acc^T) is a small loop
   carry; scores exist only in VMEM, and the per-row logsumexp is written
   out for the backward as the rows it is,
-  [B, H, Q majors, tiles, tile_q]. Backward (``jax.custom_vjp``): two
-  kernels that recompute p = exp(s − lse) per tile — dQ over the same
-  sweep, dK/dV owning K rows and sweeping Q tiles, transposed like the
-  forward — accumulating in float32 VMEM scratch, and computing the tile
-  ON the diagonal as its live 128-wide groups only; training memory stays
+  [B, H, Q majors, tiles, tile_q]. Backward (``jax.custom_vjp``): ONE
+  kernel, ``flash_bwd``, that owns K rows and sweeps the live Q tiles,
+  transposed like the forward. For each tile it recomputes
+  p = exp(s − lse) and forms ds once and takes all three gradients from
+  them — five score-sized products a tile (s, dp, dV, dK, dQ), seven a
+  layer with the forward's two — accumulating in float32 VMEM scratch,
+  and computing the tile ON the diagonal as its live 128-wide groups
+  only. dK/dV of a K major cross the inner grid axis (the group's
+  q-heads and Q majors); the dQ of a kv head's WHOLE group crosses the K
+  majors, as [D, tile] tiles (dQ^T += k^T ds is a plain product of the
+  transposed scores), and leaves transposed back at the last of them:
+  0.25 MiB at GPT-2 XL's head (T 1,024, D 64: a head a grid step), 6 MiB
+  at 32 heads of 192 over T 8,192, 16 MiB at 8 q-heads on 2 kv heads of
+  128 over T 8,192. The kernel asks Mosaic for ``_VMEM_BYTES`` of the
+  chip's 128 MiB; where a group's dQ would not fit that
+  (``FlashPlan.span_q`` under the sequence) it runs once a span of Q
+  rows and the spans' dK/dV are summed outside. Training memory stays
   O(T) and the [T, T] matrix never exists in either pass. ``flash_plan``
-  is the one place tiles and resident extents are chosen, from the shape.
-  What the forward leaves for those two kernels, ``out`` and the
+  is the one place tiles, resident extents and spans are chosen, from
+  the shape. What the forward leaves for the backward, ``out`` and the
   logsumexp, carries checkpoint names (``profiling.FLASH_RESIDUALS``):
   the kernel is a custom call, which no ``jax.checkpoint`` policy that
   goes by primitive (``checkpoint_dots``) would keep, so a checkpoint
@@ -41,8 +53,9 @@ score matrix in HBM):
   run, dead code then, is dropped. Outside a checkpoint a name is the
   identity.
 
-Speeds: PERF.md section 5 (the benchmark cell's trace by kernel) and
-section 6, PR 27 (what each part of this design brought on the v5e).
+Speeds: PERF.md section 5 (the benchmark cells' traces by kernel) and
+section 6, PR 27 and PR 34 (what each part of this design brought on the
+v5e; the pair of backward kernels that PR 34 made one).
 
 Layout matches the rest of the stack: q/k/v are ``[B, T, H, D]`` (the
 ring-attention convention, parallel/ring_attention.py); v may have a head
@@ -171,27 +184,29 @@ def blockwise_attention(
 
 # ----------------------------------------------------------- pallas kernel
 #
-# All three kernels mask by GLOBAL positions: row q_off + (local index),
+# Both kernels mask by GLOBAL positions: row q_off + (local index),
 # col k_off + (local index). Plain causal attention passes offsets (0, 0);
 # ring flash attention (ring_flash_attention_local) passes each shard's
 # sequence offsets so the same kernels compute the diagonal, kept, and
 # fully-masked ring steps. Offsets arrive as (1,) int32 arrays in SMEM.
 #
 # A grid step owns a MAJOR block of one sequence axis for one (batch,
-# head) and walks score TILES of it itself: the forward and dQ own Q rows
-# and sweep the K tiles of the resident K/V, dK/dV owns K rows and sweeps
-# the Q tiles of the resident Q/dO. The sweep's bounds come from the
-# offsets, so a tile the causal mask kills costs nothing, and only tiles
-# the diagonal crosses build the mask. A sequence whose head does not fit
-# ``_RESIDENT_BYTES`` is walked in major blocks by a sequential grid axis,
-# the accumulators crossing it in VMEM scratch.
+# head) and walks score TILES of it itself: the forward owns Q rows and
+# sweeps the K tiles of the resident K/V, the backward owns K rows and
+# sweeps the Q tiles of the resident Q/dO. The sweep's bounds come from
+# the offsets, so a tile the causal mask kills costs nothing, and only
+# tiles the diagonal crosses build the mask. A sequence whose head does
+# not fit ``_RESIDENT_BYTES`` is walked in major blocks by sequential grid
+# axes, the accumulators crossing them in VMEM scratch (the backward's
+# dQ, which belongs to the OTHER axis, crosses the outer one whole).
 #
-# The forward and dK/dV compute their scores TRANSPOSED, [K rows, Q lanes]
-# = k q^T: the softmax statistics are then lane-dense rows [1, tile_q]
-# (two vregs where a column [tile_q, 1] takes tile_q / 8), their
-# reductions run down the sublanes on the VPU instead of across lanes on
-# the XLU, p^T and ds^T feed their dots as they are, and the logsumexp
-# leaves and enters the kernels as the rows it is stored in.
+# Both compute their scores TRANSPOSED, [K rows, Q lanes] = k q^T: the
+# softmax statistics are then lane-dense rows [1, tile_q] (two vregs
+# where a column [tile_q, 1] takes tile_q / 8), their reductions run down
+# the sublanes on the VPU instead of across lanes on the XLU, p^T and
+# ds^T feed every dot as they are (the output and dQ accumulate
+# transposed for it), and the logsumexp leaves and enters the kernels as
+# the rows it is stored in.
 
 _LANES = 128
 # VMEM budget for ONE resident operand of a grid step (a head's K, V, Q or
@@ -201,10 +216,16 @@ _RESIDENT_BYTES = 512 * 1024
 # XL's head shape (PERF.md section 6, PR 27). A smaller tile computes less
 # of the causal matrix and loses more than that to the latency of its
 # dots, so the tiles are large and the backward cuts the tile ON the
-# diagonal into groups instead (_diag_groups). block_q / block_k bound
+# diagonal into groups instead (_diag_groups); fused, the backward still
+# reads best at 1,024 x 1,024 (PR 34). block_q / block_k bound
 # every tile too.
 _FWD_TILE = (512, 512)
 _BWD_TILE = (1024, 1024)
+
+# VMEM the backward kernel may take in all (the v5e has 128 MiB; Mosaic
+# hands a kernel 16 MiB of it unless asked): its limit, and what
+# ``flash_plan`` fits the group's dQ accumulator into.
+_VMEM_BYTES = 100 * 1024 * 1024
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T, contraction on both minor dims
 
@@ -213,12 +234,14 @@ class FlashPlan(NamedTuple):
     """What :func:`flash_plan` chose for a shape."""
     tile_q: int    # Q extent of a forward score tile, and the width of a
     tile_k: int    # logsumexp row; K extent of a forward tile
-    bwd_q: int     # the backward kernels' tile (bwd_q a multiple of tile_q)
+    bwd_q: int     # the backward kernel's tile (bwd_q a multiple of tile_q)
     bwd_k: int
-    major_q: int   # Q rows resident in a grid step (what fwd / dQ own)
-    major_k: int   # K rows resident in a grid step (what dK/dV owns)
+    major_q: int   # Q rows resident in a grid step
+    major_k: int   # K rows resident in a grid step
     causal_share: float  # share of the causal scores the forward computes
-    bwd_share: float     # ... and each backward kernel
+    bwd_share: float     # ... and the backward
+    span_q: int    # Q rows whose dQ, a whole group's, a backward call keeps
+    bwd_vmem: int  # bytes of VMEM that call is sized at
 
 
 def _pick_tile(T: int, cap: int, unit: int = 1) -> int:
@@ -264,28 +287,50 @@ def computed_share(Tq: int, Tk: int, tile_q: int, tile_k: int,
     return live * tile_q * tile_k / (Tq * Tk)
 
 
+def _bwd_vmem(rows_q, major_q, major_k, tq, tk, D, Dv, itemsize):
+    """Bytes of VMEM the backward kernel is sized at when it keeps
+    ``rows_q`` rows of dQ: the three float32 accumulators (dQ's as [D, tq]
+    tiles), every operand and result block twice (Pallas double-buffers
+    them), and six float32 copies of a score tile for what Mosaic keeps
+    of s, p, dp and ds. A row of VMEM is whole 128-lane tiles."""
+    d, dv, lanes = (-(-x // _LANES) * _LANES for x in (D, Dv, tq))
+    return (4 * (rows_q // tq * D * lanes + major_k * (d + dv))
+            + 2 * itemsize * (major_q * (2 * d + dv)
+                              + 2 * major_k * (d + dv))
+            + 6 * 4 * tq * tk)
+
+
 def flash_plan(Tq: int, Tk: int, D: int, itemsize: int,
                block_q: Optional[int] = None,
                block_k: Optional[int] = None,
-               Dv: Optional[int] = None) -> FlashPlan:
+               Dv: Optional[int] = None, g: int = 1) -> FlashPlan:
     """Tiles and resident extents for a shape: pure, and the one place the
     kernels take them from. ``block_q`` / ``block_k``, where given, are
     upper bounds on every tile. ``D`` is the head size of q and k, ``Dv``
     that of v and the output where it is another (latent attention: 192
     and 128); the wider of the two is what a resident operand is sized
-    by."""
-    D = max(D, Dv or D)
+    by. ``g`` is the number of q-heads a kv head serves: the backward
+    keeps the dQ of a whole group, ``g * span_q`` rows, in VMEM, and
+    ``span_q`` is the whole sequence wherever that fits ``_VMEM_BYTES``."""
+    Dv = Dv or D
+    wide = max(D, Dv)
     cap_q, cap_k = block_q or Tq, block_k or Tk
     tq = _pick_tile(Tq, min(cap_q, _FWD_TILE[0]))
     tk = _pick_tile(Tk, min(cap_k, _FWD_TILE[1]))
     bq = _pick_tile(Tq, min(cap_q, _BWD_TILE[0]), tq)   # whole lse rows
     bk = _pick_tile(Tk, min(cap_k, _BWD_TILE[1]))
+    major_q = _pick_major(Tq, bq, wide * itemsize, _RESIDENT_BYTES)
+    major_k = _pick_major(Tk, math.lcm(tk, bk), wide * itemsize,
+                          _RESIDENT_BYTES)
+    vmem = functools.partial(_bwd_vmem, major_q=major_q, major_k=major_k,
+                             tq=bq, tk=bk, D=D, Dv=Dv, itemsize=itemsize)
+    span = max((s for s in range(major_q, Tq + 1, major_q)
+                if Tq % s == 0 and vmem(g * s) <= _VMEM_BYTES),
+               default=major_q)
     return FlashPlan(
-        tq, tk, bq, bk,
-        _pick_major(Tq, bq, D * itemsize, _RESIDENT_BYTES),
-        _pick_major(Tk, math.lcm(tk, bk), D * itemsize, _RESIDENT_BYTES),
+        tq, tk, bq, bk, major_q, major_k,
         computed_share(Tq, Tk, tq, tk),
-        computed_share(Tq, Tk, bq, bk, cut=True))
+        computed_share(Tq, Tk, bq, bk, cut=True), span, vmem(g * span))
 
 
 def _scale_folds(scale: float) -> bool:
@@ -303,13 +348,6 @@ def _scores(a, b, scale):
     """a b^T in float32, times the scale where it did not go onto Q."""
     s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
     return s if _scale_folds(scale) else s * scale
-
-
-def _lanes(x, n):
-    """A lane-replicated [rows, 128] statistic at n lanes (a tile's width:
-    at most 128, or a multiple of it)."""
-    return x[:, :n] if n <= _LANES else jnp.concatenate(
-        [x] * (n // _LANES), axis=1)
 
 
 def _get_row(blk, t):
@@ -331,20 +369,6 @@ def _get_rows(row_ref, t, n, start=0):
     return jnp.concatenate(
         [_get_row(row_ref[0, 0, 0, :, max(start - j * w, 0):], t * n + j)
          for j in range(n) if start < (j + 1) * w], axis=1)
-
-
-def _rows_to_col(row_ref, t, n):
-    """The same rows as one [n * w, 128] lane-replicated column (dQ's
-    scores have Q on the sublanes): 128 lanes at a time, keep the
-    diagonal of the row broadcast down the sublanes, sum across lanes."""
-    w = row_ref.shape[4]
-    c = min(w, _LANES)
-    eye = _tile_diff(c, c) == 0
-    return jnp.concatenate(
-        [jnp.broadcast_to(jnp.sum(
-            jnp.where(eye, _get_row(row_ref[0, 0, 0, :, i:i + c], t * n + j),
-                      0.0), axis=1, keepdims=True), (c, _LANES))
-         for j in range(n) for i in range(0, w, c)], axis=0)
 
 
 def _tile_diff(rows, cols):
@@ -400,6 +424,10 @@ def _crossing(lo, hi, off, n_tiles, size, groups, tile, diag, carry):
 
 def _rows(i, size):
     return pl.ds(pl.multiple_of(i * size, size), size)
+
+
+def _span(m, size):
+    return slice(m * size, (m + 1) * size)
 
 
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -501,8 +529,8 @@ _SWEEP_LAST = pltpu.CompilerParams(
 
 
 def _head_specs(bq, bk, D, g):
-    """Blocks of the forward's and dQ's grid (B, H, Q majors, K majors) at
-    head size ``D``: (a Q-side operand's, a K-side operand's)."""
+    """Blocks of the forward's grid (B, H, Q majors, K majors) at head
+    size ``D``: (a Q-side operand's, a K-side operand's)."""
     return (pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // g, j, 0)))
 
@@ -552,122 +580,64 @@ def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
     return out.transpose(0, 2, 1, 3), lse
 
 
-def _flash_bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                         lse_ref, dvec_ref, dq_ref, dq_s, *, scale,
-                         masked, tq, tk):
-    # The forward's grid; scores [tq, tk] = q k^T, Q on the sublanes, so
-    # that dQ [tq, D] += ds k accumulates as it is written, in float32
-    # VMEM scratch, in place (see dK/dV). p is recomputed from the saved
-    # logsumexp — the [T, T] matrix never exists.
-    bq, D = q_ref.shape[2], q_ref.shape[3]
-    nk = k_ref.shape[2] // tk
+def _flash_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, dvec_ref, dq_ref, dk_ref, dv_ref, dq_s, dk_s,
+                      dv_s, *, scale, masked, tq, tk, num_q_major):
+    # Grid (B, Hk, K majors, q_per_kv * Q majors), the last two sequential;
+    # the combined (group q-head, Q major) axis is the inner one: under
+    # GQA every kv head receives gradient from all q-heads of its group. A
+    # grid step walks its K tiles, and for each the live Q tiles of the
+    # resident Q/dO; scores [tk, tq] = k q^T as in the forward, logsumexp
+    # and dvec the rows they are stored as. p and ds of a tile are formed
+    # ONCE, [keys, q lanes], and all three gradients taken from them as
+    # plain products: dV += p dO, dK += ds q, and dQ transposed,
+    # dQ^T [D, tq] += k^T ds, with k^T formed once a K tile (what the
+    # forward does with acc^T += v^T p). All three accumulate in float32
+    # VMEM scratch, in place (as loop carries their vregs spill at every
+    # bound): dK/dV of the K major across the inner axis; dQ^T of the
+    # call's whole group, a [D, tq] tile a Q tile, across the K majors,
+    # transposed back once as it is written out at the last of them.
+    bq, bk, D = q_ref.shape[2], k_ref.shape[2], k_ref.shape[3]
+    nq = bq // tq
     per_tile = tq // lse_ref.shape[4]    # logsumexp rows a Q tile spans
-    kmaj, num_major = pl.program_id(3), pl.num_programs(3)
-    q_base = qoff_ref[0] + pl.program_id(2) * bq
-    k_base = koff_ref[0] + kmaj * k_ref.shape[2]
-    fold = _scale_folds(scale)
-    diff = _tile_diff(tq, tk) if masked else None
-
-    def q_tile(t, _):
-        rows = _rows(t, tq)
-        q_lo = q_base + t * tq
-        # native-dtype dots, f32 accumulation/softmax state (see
-        # _flash_kernel); ds is cast back to the input dtype for its dot
-        qb, dob = _scaled(q_ref[0, 0, rows, :], scale), do_ref[0, 0, rows, :]
-        lse = _rows_to_col(lse_ref, t, per_tile)             # [tq, 128]
-        dvec = _rows_to_col(dvec_ref, t, per_tile)
-
-        @pl.when(kmaj == 0)
-        def _init():
-            dq_s[rows, :] = jnp.zeros((tq, D), jnp.float32)
-
-        def grad(s, dob, kb, vb, lse, dvec):
-            keys = s.shape[1]
-            p = jnp.exp(s - _lanes(lse, keys))               # [q, keys] f32
-            dp = jax.lax.dot_general(dob, vb, _NT,
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - _lanes(dvec, keys))
-            if not fold:
-                ds = ds * scale
-            return jnp.dot(ds.astype(kb.dtype), kb,
-                           preferred_element_type=jnp.float32)
-
-        def k_tile(j, _, mask_it):
-            cols = _rows(j, tk)
-            kb, vb = k_ref[0, 0, cols, :], v_ref[0, 0, cols, :]
-            s = _scores(qb, kb, scale)
-            if mask_it:
-                s = jnp.where(diff >= k_base + j * tk - q_lo, s, _NEG_INF)
-            dq_s[rows, :] += grad(s, dob, kb, vb, lse, dvec)
-
-        def diag_tile(j, _):
-            # Q row group g of the tile ON the diagonal: keys [0, (g+1)*128)
-            u = _LANES
-            for g in range(tq // u):
-                grp, keys = slice(g * u, (g + 1) * u), (g + 1) * u
-                pre = pl.ds(pl.multiple_of(j * tk, tk), keys)
-                kb, vb = k_ref[0, 0, pre, :], v_ref[0, 0, pre, :]
-                s = _scores(qb[grp, :], kb, scale)
-                s = jnp.where(diff[:u, :keys] >= -g * u, s, _NEG_INF)
-                dq_s[pl.ds(pl.multiple_of(t * tq + g * u, u), u), :] += grad(
-                    s, dob[grp, :], kb, vb, lse[grp, :], dvec[grp, :])
-
-        full, live = _live_k_tiles(masked, q_lo, k_base, tq, tk, nk)
-        _loop(0, full, k_tile, None, mask_it=False)
-        if masked:
-            _crossing(full, live, q_lo - k_base, nk, tk,
-                      _diag_groups(tq, tk), k_tile, diag_tile, None)
-
-        @pl.when(kmaj == num_major - 1)
-        def _write():
-            dq = dq_s[rows, :]
-            dq_ref[0, 0, rows, :] = (dq * scale if fold else dq).astype(
-                dq_ref.dtype)
-
-    jax.lax.fori_loop(0, bq // tq, q_tile, None)
-
-
-def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                          lse_ref, dvec_ref, dk_ref, dv_ref, dk_s, dv_s, *,
-                          scale, masked, tq, tk, num_q_major):
-    # Grid (B, Hk, K majors, q_per_kv * Q majors), the combined (group
-    # q-head, Q major) axis sequential: under GQA every kv head receives
-    # gradient from all q-heads of its group. A grid step walks its K
-    # tiles, and for each the live Q tiles of the resident Q/dO; scores
-    # [tk, tq] = k q^T as in the forward, logsumexp and dvec the rows
-    # they are stored as. dK/dV accumulate in float32 VMEM scratch, in
-    # place: as loop carries their 2 x tk / 8 vregs spill at every bound.
-    bk, D, Dv = k_ref.shape[2], k_ref.shape[3], v_ref.shape[3]
-    nq = q_ref.shape[2] // tq
-    per_tile = tq // lse_ref.shape[4]    # logsumexp rows a Q tile spans
+    kmaj, num_major = pl.program_id(2), pl.num_programs(2)
     t, num_t = pl.program_id(3), pl.num_programs(3)
-    q_base = qoff_ref[0] + jax.lax.rem(t, num_q_major) * q_ref.shape[2]
-    k_base = koff_ref[0] + pl.program_id(2) * bk
+    q_base = qoff_ref[0] + jax.lax.rem(t, num_q_major) * bq
+    k_base = koff_ref[0] + kmaj * bk
     fold = _scale_folds(scale)
     diff = _tile_diff(tk, tq) if masked else None
+    mine = pl.ds(t * nq, nq)    # this step's tiles of the group's dQ
+
+    @pl.when(kmaj == 0)
+    def _init_dq():
+        dq_s[mine] = jnp.zeros((nq, D, tq), jnp.float32)
+
+    @pl.when(t == 0)
+    def _init_dkv():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
+
+    def grad(s, qb, dob, kt, vb, lse, dvec):
+        p = jnp.exp(s - lse)                             # [keys, q] f32
+        dv = jnp.dot(p.astype(dob.dtype), dob,
+                     preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(vb, dob, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - dvec)
+        if not fold:
+            ds = ds * scale
+        ds = ds.astype(qb.dtype)
+        # with the scale folded into qb, ds^T qb carries it; dQ takes it
+        # when it is written
+        dk = jnp.dot(ds, qb, preferred_element_type=jnp.float32)
+        dqt = jnp.dot(kt, ds, preferred_element_type=jnp.float32)
+        return dqt, dk, dv
 
     def k_tile(c, _):
         rows = _rows(c, tk)
         k_lo = k_base + c * tk
         kb, vb = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
-
-        @pl.when(t == 0)
-        def _init():
-            dk_s[rows, :] = jnp.zeros((tk, D), jnp.float32)
-            dv_s[rows, :] = jnp.zeros((tk, Dv), jnp.float32)
-
-        def grad(s, qb, dob, vb, lse, dvec):
-            p = jnp.exp(s - lse)                         # [keys, q] f32
-            dv = jnp.dot(p.astype(dob.dtype), dob,
-                         preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(vb, dob, _NT,
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - dvec)
-            if not fold:
-                ds = ds * scale
-            # with the scale folded into qb, ds^T qb carries it
-            return jnp.dot(ds.astype(qb.dtype), qb,
-                           preferred_element_type=jnp.float32), dv
+        kt = kb.T
 
         def q_tile(i, _, mask_it):
             cols = _rows(i, tq)
@@ -675,9 +645,10 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             s = _scores(kb, qb, scale)
             if mask_it:
                 s = jnp.where(diff <= q_base + i * tq - k_lo, s, _NEG_INF)
-            dk, dv = grad(s, qb, do_ref[0, 0, cols, :], vb,
-                          _get_rows(lse_ref, i, per_tile),
-                          _get_rows(dvec_ref, i, per_tile))
+            dqt, dk, dv = grad(s, qb, do_ref[0, 0, cols, :], kt, vb,
+                               _get_rows(lse_ref, i, per_tile),
+                               _get_rows(dvec_ref, i, per_tile))
+            dq_s[t * nq + i] += dqt
             dk_s[rows, :] += dk
             dv_s[rows, :] += dv
 
@@ -689,12 +660,14 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 grp = pl.ds(pl.multiple_of(c * tk + r * u, u), u)
                 suf = pl.ds(pl.multiple_of(i * tq + r * u, u), tq - r * u)
                 qb = _scaled(q_ref[0, 0, suf, :], scale)
-                s = _scores(kb[r * u:(r + 1) * u, :], qb, scale)
+                own = slice(r * u, (r + 1) * u)
+                s = _scores(kb[own, :], qb, scale)
                 s = jnp.where(diff[:u, :tq - r * u] <= 0, s, _NEG_INF)
-                dk, dv = grad(
-                    s, qb, do_ref[0, 0, suf, :], vb[r * u:(r + 1) * u, :],
+                dqt, dk, dv = grad(
+                    s, qb, do_ref[0, 0, suf, :], kt[:, own], vb[own, :],
                     _get_rows(lse_ref, i, per_tile, r * u),
                     _get_rows(dvec_ref, i, per_tile, r * u))
+                dq_s[t * nq + i, :, r * u:] += dqt
                 dk_s[grp, :] += dk
                 dv_s[grp, :] += dv
 
@@ -704,82 +677,95 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                       _diag_groups(tq, tk), q_tile, diag_tile, None)
         _loop(full, nq, q_tile, None, mask_it=False)
 
-        @pl.when(t == num_t - 1)
-        def _write():
-            dk_ref[0, 0, rows, :] = dk_s[rows, :].astype(dk_ref.dtype)
-            dv_ref[0, 0, rows, :] = dv_s[rows, :].astype(dv_ref.dtype)
-
     jax.lax.fori_loop(0, bk // tk, k_tile, None)
+
+    @pl.when(t == num_t - 1)
+    def _write_dkv():
+        dk_ref[0, 0] = dk_s[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
+
+    @pl.when(kmaj == num_major - 1)
+    def _write_dq():
+        for i in range(nq):     # transposed back once, as it leaves
+            dq = dq_s[t * nq + i].T
+            dq_ref[0, 0, i * tq:(i + 1) * tq, :] = (
+                dq * scale if fold else dq).astype(dq_ref.dtype)
 
 
 def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
                     block_q, block_k, interpret):
-    """dQ/dK/dV via the two backward kernels; [B, T, H, D] layout.
+    """dQ/dK/dV via the one backward kernel; [B, T, H, D] layout.
     ``lse`` and ``dvec`` (rowsum(dO*O) minus the lse cotangent) are
     [B, H, Q majors, tiles, tile_q] as the forward leaves the logsumexp.
-    Under GQA dk/dv come back at the kv head count."""
+    Under GQA dk/dv come back at the kv head count. Where the plan's
+    ``span_q`` is less than the sequence, the kernel runs once a span of Q
+    rows (their offset added to ``q_off``) and the spans' dK/dV, float32,
+    are summed here."""
     B, Tq, H, D = q.shape
     Hk = k.shape[2]
     g = gqa_group_size(H, Hk)
     Tk, Dv = k.shape[1], v.shape[3]
-    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k, Dv)
+    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k, Dv, g)
     tq, tk, bq, bk = plan.bwd_q, plan.bwd_k, plan.major_q, plan.major_k
-    w = plan.tile_q
-    nqm, nkm = Tq // bq, Tk // bk
+    w, span = plan.tile_q, plan.span_q
+    nqm, nkm = span // bq, Tk // bk
     qt, kt, vt, dot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, g_out))
     vma = _vma_of(q, k, v, q_off, k_off, g_out)
-    offs = _offsets(q_off, k_off)
+    q_off, k_off = _offsets(q_off, k_off)
+    part = jnp.float32 if span < Tq else None    # dK/dV of a span
 
-    q_spec, kv_spec = _head_specs(bq, bk, D, g)
-    do_spec, v_spec = _head_specs(bq, bk, Dv, g)
-    row_spec = pl.BlockSpec((1, 1, 1, bq // w, w),
-                            lambda b, h, i, j: (b, h, i, 0, 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, scale=scale, masked=masked,
-                          tq=tq, tk=tk),
-        grid=(B, H, nqm, nkm),
-        in_specs=[_smem_spec(), _smem_spec(),
-                  q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_SWEEP_LAST,
-        interpret=interpret,
-        name=prof.FLASH_DQ,
-    )(*offs, qt, kt, vt, dot, lse, dvec)
+    # grid dim 1 walks KV heads; the q-head within the group and its Q
+    # major ride the inner axis t
+    def q_at(b, hk, j, t):
+        return b, hk * g + t // nqm, t % nqm
 
-    # transposed grid: K majors outer, (group q-head, Q major) inner — grid
-    # dim 1 walks KV heads, the q-head within the group rides the sweep
-    def specs_t(D):
-        return (pl.BlockSpec(
-                    (1, 1, bq, D),
-                    lambda b, hk, j, t: (b, hk * g + t // nqm, t % nqm, 0)),
+    def specs(D):
+        return (pl.BlockSpec((1, 1, bq, D),
+                             lambda b, hk, j, t: (*q_at(b, hk, j, t), 0)),
                 pl.BlockSpec((1, 1, bk, D),
                              lambda b, hk, j, t: (b, hk, j, 0)))
 
-    q_spec_t, kv_spec_t = specs_t(D)
-    do_spec_t, v_spec_t = specs_t(Dv)
-    row_spec_t = pl.BlockSpec(
+    def dq_at(b, hk, j, t):
+        # dQ is written out at the last K major only; until then its
+        # block stays on the one that is written first, so that nothing
+        # goes back to HBM for it
+        return (*q_at(b, hk, j, jnp.where(j == nkm - 1, t, 0)), 0)
+
+    q_spec, kv_spec = specs(D)
+    do_spec, v_spec = specs(Dv)
+    row_spec = pl.BlockSpec(
         (1, 1, 1, bq // w, w),
-        lambda b, hk, j, t: (b, hk * g + t // nqm, t % nqm, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, scale=scale,
-                          masked=masked, tq=tq, tk=tk, num_q_major=nqm),
+        lambda b, hk, j, t: (*q_at(b, hk, j, t), 0, 0))
+    call = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=scale, masked=masked,
+                          tq=tq, tk=tk, num_q_major=nqm),
         grid=(B, Hk, nkm, g * nqm),
         in_specs=[_smem_spec(), _smem_spec(),
-                  q_spec_t, kv_spec_t, v_spec_t, do_spec_t, row_spec_t,
-                  row_spec_t],
-        out_specs=[kv_spec_t, v_spec_t],
+                  q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[pl.BlockSpec((1, 1, bq, D), dq_at), kv_spec, v_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hk, Tk, D), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((B, Hk, Tk, Dv), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, H, span, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hk, Tk, D), part or k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hk, Tk, Dv), part or v.dtype, vma=vma),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((g * span // tq, D, tq), jnp.float32),
+                        pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, Dv), jnp.float32)],
-        compiler_params=_SWEEP_LAST,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
-        name=prof.FLASH_DKV,
-    )(*offs, qt, kt, vt, dot, lse, dvec)
+        name=prof.FLASH_BWD,
+    )
+    dq, dk, dv = zip(*(
+        call(q_off + m * span, k_off, qt[:, :, _span(m, span)], kt, vt,
+             dot[:, :, _span(m, span)], lse[:, :, _span(m, nqm)],
+             dvec[:, :, _span(m, nqm)])
+        for m in range(Tq // span)))
+    dq = jnp.concatenate(dq, axis=2)
+    dk = functools.reduce(jnp.add, dk).astype(k.dtype)
+    dv = functools.reduce(jnp.add, dv).astype(v.dtype)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))
 

@@ -97,9 +97,8 @@ MTP_NLL = "mtp.nll"
 CKPT_SKIP_TORN = "ckpt.skip_torn"
 # ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names
 FLASH_FWD = "flash_fwd"
-FLASH_DQ = "flash_dq"
-FLASH_DKV = "flash_dkv"
-# ---- what flash_fwd leaves for the backward kernels, as checkpoint names
+FLASH_BWD = "flash_bwd"
+# ---- what flash_fwd leaves for the backward kernel, as checkpoint names
 # (jax.ad_checkpoint.checkpoint_name): a block checkpoint whose policy
 # saves both does not run flash_fwd a second time for the backward
 FLASH_OUT = "flash_out"
@@ -120,7 +119,7 @@ PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
           LM_EMBED, LM_ATTN, LM_MLP, LM_HEAD, LM_ATTN_CCA, LM_MOE,
           LM_MOE_ROUTER, LM_MOE_DISPATCH, LM_MOE_EXPERTS, LM_MOE_COMBINE,
           LM_ATTN_MLA, LM_MOE_SHARED, LM_MTP)
-KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, GATHER_ROWS, RAGGED_DOT)
+KERNELS = (FLASH_FWD, FLASH_BWD, GATHER_ROWS, RAGGED_DOT)
 
 RING_SPANS = 8192
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

@@ -20,6 +20,7 @@ from minips_tpu.ops.flash_attention import (blockwise_attention,
                                             kernel_supported)
 from minips_tpu.parallel.ring_attention import reference_attention
 from minips_tpu.utils import profiling as prof
+from tests.conftest import pallas_call_names
 
 
 def _qkv(B=2, T=64, H=2, D=16, seed=0, dtype=jnp.float32):
@@ -329,6 +330,97 @@ def test_kernel_lse_cotangent_matches_jnp(step, monkeypatch):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
+# The one backward kernel where both sequence axes are walked in major
+# blocks, so that dQ crosses the K majors in scratch and dK/dV the
+# (group q-head, Q major) axis: (H, Hk, D, Dv, q_off, k_off, rows of a
+# head that fit the resident budget, VMEM the backward may take or None).
+FUSED = {
+    "unequal_heads_majors": (2, 2, 24, 16, 0, 0, 16, None),
+    "unequal_heads_gqa_majors": (4, 2, 24, 16, 0, 0, 16, None),
+    "gqa4_majors": (4, 1, 16, 16, 0, 0, 16, None),
+    "gqa4_majors_ring_crossing": (4, 1, 16, 16, 48, 16, 16, None),
+    "gqa4_majors_ring_unaligned": (4, 1, 16, 16, 40, 12, 16, None),
+    "gqa4_majors_ring_kept": (4, 1, 16, 16, 128, 0, 16, None),
+    "gqa4_majors_ring_masked": (4, 1, 16, 16, 0, 64, 16, None),
+    # a budget that holds the dQ of a quarter of the sequence: the kernel
+    # runs once a span of Q rows and the spans' dK/dV are summed outside
+    "gqa4_majors_in_spans": (4, 1, 16, 16, 48, 16, 16, 200_000),
+    "unequal_heads_in_spans": (2, 2, 24, 16, 0, 0, 16, 160_000),
+}
+
+
+@pytest.mark.parametrize("case", list(FUSED))
+def test_fused_backward_matches_the_blockwise_twin(case, monkeypatch):
+    """dQ, dK and dV of the one backward kernel (interpreted) against AD
+    through the blockwise scan at the same global offsets, under a loss
+    that uses ``out`` AND ``lse``: several majors on both axes, v's own
+    head size, a group of four q-heads on one kv head, ring offsets."""
+    from minips_tpu.ops.flash_attention import _flash_with_lse
+
+    H, Hk, D, Dv, off_q, off_k, rows, vmem = FUSED[case]
+    T, blk = 64, 8
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (1, T, H, D))
+    k = jax.random.normal(ks[1], (1, T, Hk, D))
+    v = jax.random.normal(ks[2], (1, T, Hk, Dv))
+    monkeypatch.setattr(fa, "_RESIDENT_BYTES", rows * max(D, Dv) * 4)
+    if vmem is not None:
+        monkeypatch.setattr(fa, "_VMEM_BYTES", vmem)
+    plan = flash_plan(T, T, D, 4, blk, blk, Dv, H // Hk)
+    assert (plan.major_q, plan.major_k) == (rows, rows)     # 4 x 4 majors
+    assert plan.span_q == (T if vmem is None else rows)
+    assert plan.bwd_vmem <= fa._VMEM_BYTES
+    q_off, k_off = jnp.int32(off_q), jnp.int32(off_k)
+    scale = D ** -0.5
+
+    def loss_kernel(q, k, v):
+        out, lse = _flash_with_lse(q, k, v, q_off, k_off, True, scale,
+                                   blk, blk, True)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    def loss_twin(q, k, v):
+        out, lse = blockwise_attention(q, k, v, causal=True, scale=scale,
+                                       block_k=16, q_off=q_off,
+                                       k_off=k_off, return_lse=True)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    names = pallas_call_names(jax.make_jaxpr(
+        jax.grad(loss_kernel, (0, 1, 2)))(q, k, v).jaxpr)
+    assert names.count(prof.FLASH_BWD) == T // plan.span_q
+    g_k = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
+    g_t = jax.grad(loss_twin, argnums=(0, 1, 2))(q, k, v)
+    if case.endswith("masked"):   # (the twin averages V over masked keys)
+        g_t = [jnp.zeros_like(x) for x in g_t]
+    for a, b in zip(g_k, g_t):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("H, Hk, D, Dv", [(25, 25, 64, 64), (8, 2, 128, 128),
+                                          (4, 4, 192, 128)])
+def test_a_layers_backward_lowers_to_one_flash_bwd_custom_call(H, Hk, D, Dv):
+    """Lowered for the TPU (no chip is needed to lower), the gradient of
+    two layers of attention holds one ``flash_fwd`` and one ``flash_bwd``
+    custom call a layer, and neither kernel of the pair it replaced."""
+    import re
+
+    q = jax.ShapeDtypeStruct((1, 1024, H, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 1024, Hk, D), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 1024, Hk, Dv), jnp.bfloat16)
+
+    def loss(q, k, v):
+        a = fa._flash(q, k, v, True, D ** -0.5, None, None, False)
+        b = fa._flash(q * 2, k, v, True, D ** -0.5, None, None, False)
+        return jnp.sum((a + b).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).trace(q, k, v).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("@tpu_custom_call") == 4
+    kernels = re.findall(r'kernel_name\s*=\s*"(\w+)"', text)
+    assert sorted(kernels) == [prof.FLASH_BWD] * 2 + [prof.FLASH_FWD] * 2
+    assert "flash_dq" not in text and "flash_dkv" not in text
+
+
 def test_ring_flash_default_path_off_tpu():
     """With interpret unset, off-TPU the ring uses the pure-jnp offset
     blockwise path — full VMA checking on, ordinary AD, same numerics.
@@ -449,6 +541,37 @@ def test_plan_at_the_benchmark_shape_beats_the_old_blocks():
     assert plan.bwd_q % plan.tile_q == 0   # whole logsumexp rows a tile
 
 
+# (Tq, D, Dv, q-heads a kv head): majors, the Q rows whose dQ a backward call
+# keeps, and the VMEM it is sized at, at the benchmark's three head shapes
+CELL_PLANS = {
+    "gpt2-xl": ((1024, 64, 64, 1), (1024, 1024, 1024, 30_146_560)),
+    "zaya1-8b": ((8192, 128, 128, 4), (2048, 2048, 8192, 51_380_224)),
+    "joyai-llm-flash": ((8192, 192, 128, 1), (1024, 1024, 8192, 38_797_312)),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_PLANS))
+def test_plan_of_the_fused_backward_at_the_cells_shapes(cell):
+    """The group's whole dQ stays in VMEM at every shape a cell runs (one
+    call a layer), in what the v5e has; the float32 accumulators are the
+    larger part of it only where the sequence is long."""
+    (T, D, Dv, g), want = CELL_PLANS[cell]
+    plan = flash_plan(T, T, D, 2, Dv=Dv, g=g)
+    assert (plan.major_q, plan.major_k, plan.span_q, plan.bwd_vmem) == want
+    assert plan.bwd_vmem <= fa._VMEM_BYTES < 128 * 2 ** 20
+    assert plan.bwd_vmem > 4 * g * plan.span_q * D      # dQ^T, float32
+
+
+def test_plan_cuts_the_backward_into_spans_where_a_groups_dq_does_not_fit():
+    # 64 q-heads on one kv head at T 32,768, D 128: 1 GiB of float32 dQ
+    plan = flash_plan(32768, 32768, 128, 2, g=64)
+    assert plan.major_q <= plan.span_q < 32768
+    assert 32768 % plan.span_q == 0 and plan.span_q % plan.major_q == 0
+    assert plan.bwd_vmem <= fa._VMEM_BYTES
+    # half the group keeps twice the rows
+    assert flash_plan(32768, 32768, 128, 2, g=32).span_q == 2 * plan.span_q
+
+
 @pytest.mark.parametrize("T, D, itemsize", [
     (32768, 64, 2), (8192, 128, 2), (16384, 64, 4)])
 def test_plan_walks_a_long_sequence_in_major_blocks(T, D, itemsize):
@@ -494,8 +617,6 @@ RESIDUAL_POLICIES = {
 
 @pytest.mark.parametrize("policy", list(RESIDUAL_POLICIES))
 def test_checkpoint_that_saves_both_residuals_runs_the_forward_once(policy):
-    from tests.conftest import pallas_call_names
-
     saved, forward_calls = RESIDUAL_POLICIES[policy]
     q, k, v = _qkv(B=1, T=64)
 
@@ -506,7 +627,8 @@ def test_checkpoint_that_saves_both_residuals_runs_the_forward_once(policy):
     grad = jax.grad(jax.checkpoint(attn, policy=saved), argnums=(0, 1, 2))
     names = pallas_call_names(jax.make_jaxpr(grad)(q, k, v).jaxpr)
     assert names.count(prof.FLASH_FWD) == forward_calls
-    assert names.count(prof.FLASH_DQ) == names.count(prof.FLASH_DKV) == 1
+    assert names.count(prof.FLASH_BWD) == 1
+    assert set(names) == {prof.FLASH_FWD, prof.FLASH_BWD}   # no pair
     for a, b in zip(grad(q, k, v),
                     jax.grad(attn, argnums=(0, 1, 2))(q, k, v)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()

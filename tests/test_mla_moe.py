@@ -472,32 +472,34 @@ def test_flash_kernels_at_unequal_head_sizes_in_the_interpreter(H, Hk, D,
         _plain_attention(q, k, v), atol=2e-5, rtol=2e-5)
 
 
-# the jaxprs of value_and_grad of the kernels at the accepted cells' head
-# sizes as the PARENT of the PR that brought v's own size (1fc21d6) traced
-# them: for equal sizes the kernels are the parent's. (See TOP1_JAXPR.)
+# the jaxprs of the forward kernel at the accepted cells' head sizes as
+# the PARENT of the PR that brought v's own size (1fc21d6) traced them:
+# for equal sizes the forward is the parent's. (See TOP1_JAXPR.) The
+# backward of that tree, two kernels, became one in PR 34 and is pinned by
+# its gradients (tests/test_flash_attention.py), not by its text.
 EQUAL_SIZES = {
     (2, 1024, 25, 25, 64):
-        "74bfaf5a3d3ee40166f63f88f76af87a8d30da05dddec6f85f92c4b43db24556",
+        "db9794cbd708dfe29b0894da97400ac0c5505c36288fa8214619fac754269cf0",
     (1, 8192, 8, 2, 128):
-        "ef418c9ac8a877716aa7c2bbbf9a8d62d21a33d64a3d93ee8d8f80b9092e46ba",
+        "ab15bb8e1605685085502b2b2c4481369c7e2f023602d2a9ee8b381534c32705",
 }
+# tiles, majors and computed shares: what the plan held before PR 34
 PLANS = {
-    (1024, 64): fa.FlashPlan(512, 512, 1024, 1024, 1024, 1024, 0.75, 0.5625),
-    (8192, 128): fa.FlashPlan(512, 512, 1024, 1024, 2048, 2048, 0.53125,
-                              0.5078125),
+    (1024, 64): (512, 512, 1024, 1024, 1024, 1024, 0.75, 0.5625),
+    (8192, 128): (512, 512, 1024, 1024, 2048, 2048, 0.53125, 0.5078125),
 }
 
 
 @pytest.mark.parametrize("shape", sorted(EQUAL_SIZES))
 def test_for_equal_head_sizes_the_plan_and_the_kernels_are_unchanged(shape):
     Bq, Tq, H, Hk, D = shape
-    assert fa.flash_plan(Tq, Tq, D, 2) == PLANS[Tq, D]
-    assert fa.flash_plan(Tq, Tq, D, 2, Dv=D) == PLANS[Tq, D]
+    assert fa.flash_plan(Tq, Tq, D, 2)[:8] == PLANS[Tq, D]
+    assert fa.flash_plan(Tq, Tq, D, 2, Dv=D) == fa.flash_plan(Tq, Tq, D, 2)
     q = jax.ShapeDtypeStruct((Bq, Tq, H, D), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((Bq, Tq, Hk, D), jnp.bfloat16)
     f = lambda q, k, v: jnp.sum(fa._flash(     # noqa: E731
         q, k, v, True, D ** -0.5, None, None, False).astype(jnp.float32))
-    text = str(jax.make_jaxpr(jax.value_and_grad(f, (0, 1, 2)))(q, k, k))
+    text = str(jax.make_jaxpr(f)(q, k, k))
     assert hashlib.sha256(text.encode()).hexdigest() == EQUAL_SIZES[shape]
 
 
@@ -505,7 +507,7 @@ def test_the_plan_at_192_128_sizes_a_resident_operand_by_the_wider():
     plan = fa.flash_plan(8192, 8192, 192, 2, Dv=128)
     assert (plan.major_q, plan.major_k) == (1024, 1024)
     assert plan[:4] == (512, 512, 1024, 1024)
-    assert fa.flash_plan(8192, 8192, 128, 2, Dv=192) == plan
+    assert fa.flash_plan(8192, 8192, 128, 2, Dv=192)[:8] == plan[:8]
     assert fa.kernel_supported((2, 8192, 32, 192), (2, 8192, 32, 192),
                                v_head=128)
     assert not fa.kernel_supported((2, 256, 2, 24), (2, 256, 2, 24),

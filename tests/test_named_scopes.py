@@ -208,7 +208,7 @@ def test_the_chunked_heads_backward_rule_is_under_the_heads_scope():
     assert phase_of(scopes[0]) == (prof.LM_HEAD, "bwd")
 
 
-def test_the_three_flash_kernels_carry_their_names():
+def test_the_two_flash_kernels_carry_their_names():
     from minips_tpu.ops.flash_attention import flash_attention
 
     q = jnp.ones((1, 128, 2, 64), jnp.float32)
@@ -219,7 +219,7 @@ def test_the_three_flash_kernels_carry_their_names():
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
     assert _pallas_names(jaxpr.jaxpr) == [
-        prof.FLASH_FWD, prof.FLASH_DQ, prof.FLASH_DKV]
+        prof.FLASH_FWD, prof.FLASH_BWD]
 
 
 @pytest.mark.parametrize("remat, rematted", [
@@ -249,7 +249,7 @@ def test_flash_kernels_keep_name_and_scope_with_residuals_named(
                for e in _eqns(jax.make_jaxpr(jax.grad(loss))(p).jaxpr)
                if e.primitive.name == "pallas_call"]
     assert {name for name, _ in kernels} == {
-        prof.FLASH_FWD, prof.FLASH_DQ, prof.FLASH_DKV}
+        prof.FLASH_FWD, prof.FLASH_BWD}
     assert {phase for _, (phase, _) in kernels} == {prof.LM_ATTN}
     assert [n for n, (_, part) in kernels if part == "remat"] == rematted
     assert set(prof.FLASH_RESIDUALS).isdisjoint(prof.KERNELS)
@@ -274,7 +274,7 @@ def test_names_are_defined_in_profiling_only():
              if k.isupper() and isinstance(getattr(prof, k), str)
              and re.fullmatch(r"[a-z_]+(\.[a-z_]+)+|flash_\w+|ps_\w+_step",
                               getattr(prof, k))]
-    assert prof.STEP in names and prof.FLASH_DKV in names
+    assert prof.STEP in names and prof.FLASH_BWD in names
     quoted = re.compile("|".join(
         r"[\"']" + re.escape(n) + r"[\"']" for n in names))
     offenders = []
