@@ -22,6 +22,13 @@ means:
   of 256 experts at top-8 beside a shared one, the prediction module, the
   untied head; the flash kernels at 192/128) at published widths, T 1,024,
   against the benchmark's plain float32 reference.
+- ``olmo``: one forward and backward of the hybrid decoder
+  (``models/olmo_hybrid.py``) cut to one linear-attention layer (the
+  chunked gated delta rule of ``ops/delta_rule.py`` at 30 heads of 96 / 192
+  channels) and one full-attention layer (the flash kernels at 30 heads of
+  128, no position signal) at published widths, T 1,024, against the
+  benchmark's plain float32 reference, which is the token-by-token
+  recurrence.
 
 It fails (non-zero exit, no result line) if JAX finds no TPU, if a loss is
 non-finite or does not fall, if the LM step holds fewer than three compiled
@@ -470,6 +477,70 @@ def _leg_joyai(on_tpu: bool, here: str) -> dict:
             "tokens_held": mine["tokens_held"].tolist()}
 
 
+def _leg_olmo(on_tpu: bool, here: str) -> dict:
+    """One forward and backward of the hybrid decoder cut to a
+    linear-attention layer and a full-attention layer, bfloat16 worker math
+    as the cell runs it, against the plain float32 reference the benchmark
+    keeps (published widths on the chip, toy widths off it), and the
+    observer's reading of the linear layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from minips_tpu.models import olmo_hybrid
+    from minips_tpu.tables.dense import cast_floating
+
+    bench = os.path.join(here, "bench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from benchlib.reference import olmo_hybrid_ref
+
+    with open(os.path.join(bench, "configs", "olmo-hybrid-7b.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=2, layer_types=[
+            olmo_hybrid.LINEAR, olmo_hybrid.FULL])
+    B, T = 2, 1024
+    if not on_tpu:
+        config.update(
+            hidden_size=32, num_attention_heads=2, num_key_value_heads=2,
+            intermediate_size=48, linear_num_key_heads=2,
+            linear_num_value_heads=2, linear_key_head_dim=8,
+            linear_value_head_dim=16, vocab_size=128, head_chunk=16)
+        T = 128
+    m = olmo_hybrid.from_config(config)
+    params = olmo_hybrid.init(jax.random.PRNGKey(0), m)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, T + 1), 0, m.vocab)
+    cd = jnp.bfloat16
+    how = dict(compute_dtype=cd, attn_impl="flash",
+               head_chunk=int(config["head_chunk"]))
+    # the step's loss and gradients and the observer's reading: one program
+    (loss, grads), seen = jax.jit(lambda p, t: (
+        olmo_hybrid.grad_fn(cast_floating(p, cd), {"tokens": t}, m, **how),
+        olmo_hybrid.observe(p, {"tokens": t}, m, **how)))(params, toks)
+    seen = jax.device_get(seen)
+    z = olmo_hybrid_ref._sizes(config)
+    want, want_g = jax.jit(jax.value_and_grad(
+        lambda p, t: olmo_hybrid_ref.loss_sum(p, t, z, False)))(params, toks)
+    want = float(want) / (B * T)
+    _check(math.isfinite(float(loss)) and abs(float(loss) - want)
+           < 2e-3 * want, f"olmo: loss {float(loss)} against the "
+           f"reference's {want}")
+    worst = _grad_norm_gap(grads, want_g, B * T)
+    _check(worst < 0.05, f"olmo: a leaf's gradient norm is {worst:.4f} off "
+           "the reference's (tolerance 0.05)")
+    _check(0.0 < float(seen["beta_mean"][0]) < 2.0
+           and 0.0 < float(seen["decay_mean"][0]) <= 1.0
+           and math.isfinite(float(seen["state_absmax"][0])),
+           f"olmo: the observer reads {seen}")
+    return {"shape": {"B": B, "T": T, "dim": m.dim, "heads": m.heads,
+                      "linear_heads": m.lin_heads, "key_head": m.lin_dk,
+                      "value_head": m.lin_dv, "layers": list(m.layer_types)},
+            "loss": float(loss), "reference_loss": want,
+            "lm_nll": float(seen["lm_nll"]),
+            "grad_norm_worst_gap": round(worst, 5),
+            "decay_mean": seen["decay_mean"].tolist(),
+            "beta_mean": seen["beta_mean"].tolist(),
+            "state_absmax": seen["state_absmax"].tolist()}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse-cpu", action="store_true",
@@ -516,6 +587,8 @@ def main() -> int:
     legs["zaya"] = _leg_zaya(on_tpu, here)
     gc.collect()
     legs["joyai"] = _leg_joyai(on_tpu, here)
+    gc.collect()
+    legs["olmo"] = _leg_olmo(on_tpu, here)
     _check(not native_lib.loaded_libs(),
            f"a native library was loaded: {native_lib.loaded_libs()}")
 

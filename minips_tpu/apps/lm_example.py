@@ -26,12 +26,14 @@ demonstrates the long-context/model-parallel paths end-to-end. Layouts:
 file of published keys instead of the flags, by the file's ``model_type``
 (``CONFIG_MODELS``): the ZAYA1-shaped decoder of ``models/zaya.py``
 (compressed convolutional attention, a dropless top-1 expert layer that
-is told which experts it holds) or the latent-attention expert decoder of
+is told which experts it holds), the latent-attention expert decoder of
 ``models/mla_moe.py`` (MLA, sigmoid top-k experts beside a shared one, a
-leading dense layer, an untied head, a multi-token-prediction module),
-trained through the same ``DenseTable.make_step``;
-``bench/configs/zaya1-8b.json`` and ``bench/configs/joyai-llm-flash.json``
-are such files, and the benchmark's adapters call :func:`model_dp_step`
+leading dense layer, an untied head, a multi-token-prediction module)
+or the hybrid of gated delta-rule linear-attention and full-attention
+layers of ``models/olmo_hybrid.py``, trained through the same
+``DenseTable.make_step``;
+``bench/configs/zaya1-8b.json``, ``bench/configs/joyai-llm-flash.json``
+and ``bench/configs/olmo-hybrid-7b.json`` are such files, and the benchmark's adapters call :func:`model_dp_step`
 as ``run`` does.
 
 Usage: python -m minips_tpu.apps.lm_example --num_iters 200 --layout sp
@@ -40,6 +42,8 @@ Usage: python -m minips_tpu.apps.lm_example --num_iters 200 --layout sp
            --model_config bench/configs/zaya1-8b.json
        python -m minips_tpu.apps.lm_example --seq_len 8192 --batch_size 2 \
            --model_config bench/configs/joyai-llm-flash.json
+       python -m minips_tpu.apps.lm_example --seq_len 8192 --batch_size 4 \
+           --model_config bench/configs/olmo-hybrid-7b.json     # 4 chips
 """
 
 from __future__ import annotations
@@ -58,7 +62,7 @@ from minips_tpu.apps.common import app_main, log_tables_built
 from minips_tpu.core.config import Config, TableConfig, TrainConfig
 from minips_tpu.data import synthetic
 from minips_tpu.data.loader import BatchIterator
-from minips_tpu.models import mla_moe
+from minips_tpu.models import mla_moe, olmo_hybrid
 from minips_tpu.models import transformer as tfm
 from minips_tpu.models import zaya
 from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
@@ -73,10 +77,12 @@ DEFAULT = Config(
 
 MODEL = dict(vocab=256, dim=64, heads=4, depth=2, max_len=1024)
 # --model_config: the file's ``model_type`` names the module, each with
-# ``from_config``, ``init``, ``grad_fn``, ``routing_stats`` and
-# ``centred_bias``; a file without the key is ZAYA's, as before the key
-# was read
-CONFIG_MODELS = {"zaya": zaya, mla_moe.MODEL_TYPE: mla_moe}
+# ``from_config``, ``init``, ``grad_fn`` and an observer: ``routing_stats``
+# with ``centred_bias`` where the model has routers and carries their
+# balancing bias from step to step, ``observe`` where it carries nothing;
+# a file without the key is ZAYA's, as before the key was read
+CONFIG_MODELS = {"zaya": zaya, mla_moe.MODEL_TYPE: mla_moe,
+                 olmo_hybrid.MODEL_TYPE: olmo_hybrid}
 
 
 def _flags(parser):
@@ -285,27 +291,44 @@ def model_dp_step(config: dict, mesh, params, first_batch, *, updater: str,
     ``head_chunk``, ``compute_dtype``, ``router_bias_rate``. ``params`` is
     the caller's initial tree (the app draws the module's ``init``, the
     benchmark makes its own from its seed); the table owns the state from
-    here on, the routers' balancing bias with it (``table.state``, started
-    by the module's ``centred_bias`` over ``first_batch``, as ``prep``
-    places it). ``stats(table.pull(), batch, table.state)`` is the routing
-    observer (the module's ``routing_stats``), jitted apart from the
-    step."""
+    here on. A model with routers carries their balancing bias from step
+    to step (``table.state``, started by the module's ``centred_bias``
+    over ``first_batch``, as ``prep`` places it) and
+    ``stats(table.pull(), batch, table.state)`` is its routing observer
+    (the module's ``routing_stats``); a model without carries nothing
+    (``table.state`` is None) and ``stats(table.pull(), batch)`` is the
+    module's ``observe``, run on every worker's shard of the batch and
+    reduced over them. Either is jitted apart from the step."""
     model = config_model(config)
     m = model.from_config(config)
     cd = jnp.dtype(config.get("compute_dtype", "float32"))
     how = dict(compute_dtype=cd, attn_impl=config.get("attn", "flash"),
-               head_chunk=int(config.get("head_chunk", 0)))
-    # an observer is run as the step is in what it takes (ZAYA's runs no
-    # head, so it takes no ``head_chunk``)
-    takes = inspect.signature(model.routing_stats).parameters
-    stats = jax.jit(functools.partial(
-        model.routing_stats, m=m,
-        **{k: v for k, v in how.items() if k in takes}))
-    bias = model.centred_bias(lambda b: stats(params, first_batch, b), m)
+               head_chunk=int(config.get("head_chunk", 0)),
+               axis_name=DATA_AXIS)
+
+    def run_as_the_step(fn):
+        # a function is run as the step is in what it takes (ZAYA's
+        # observer runs no head, so it takes no ``head_chunk``; the
+        # routing observers do not reduce over the workers)
+        takes = inspect.signature(fn).parameters
+        return functools.partial(
+            fn, m=m, **{k: v for k, v in how.items() if k in takes})
+
+    routed = hasattr(model, "routing_stats")
+    if routed:
+        stats = jax.jit(run_as_the_step(model.routing_stats))
+    else:
+        # ``observe`` reduces over the workers itself: each reads its own
+        # shard of the batch, as in the step (a kernel cannot be
+        # partitioned by the compiler: it has to stand in a ``shard_map``)
+        stats = jax.jit(jax.shard_map(
+            run_as_the_step(model.observe), mesh=mesh,
+            in_specs=(P(), P(DATA_AXIS)), out_specs=P()))
+    bias = model.centred_bias(
+        lambda b: stats(params, first_batch, b), m) if routed else None
     table = DenseTable(params, mesh, updater=updater, lr=lr, name=name)
     step = table.make_step(
-        functools.partial(model.grad_fn, m=m, axis_name=DATA_AXIS, **how),
-        batch_spec=P(DATA_AXIS),
+        run_as_the_step(model.grad_fn), batch_spec=P(DATA_AXIS),
         compute_dtype=None if cd == jnp.float32 else cd, state=bias)
     return m, table, step, stats
 
@@ -375,7 +398,26 @@ def _run_model_config(cfg, args, mesh, layout, seq_len):
                 "moe_load_max_over_mean":
                     st["load_max_over_mean"].tolist(), **losses}
 
-    return data, table, step, prep, routing_metrics
+    def linattn_metrics() -> dict:
+        """The same for a model that carries no state (``observe``): the
+        loss and, a linear-attention layer, the mean decay, the mean write
+        strength and the state's largest entry; the counters take the
+        worst layer of each."""
+        with prof.span(prof.LOOP_READBACK):
+            st = jax.device_get(stats(table.pull(), last["batch"]))
+            prof.counter(prof.LM_NLL, float(st["lm_nll"]))
+            prof.counter(prof.LINATTN_DECAY_MEAN,
+                         float(st["decay_mean"].min()))
+            prof.counter(prof.LINATTN_BETA_MEAN,
+                         float(st["beta_mean"].max()))
+            prof.counter(prof.LINATTN_STATE_ABSMAX,
+                         float(st["state_absmax"].max()))
+        return {"lm_nll": float(st["lm_nll"]),
+                **{"linattn_" + k: st[k].tolist() for k in (
+                    "decay_mean", "beta_mean", "state_absmax")}}
+
+    return data, table, step, prep, (
+        linattn_metrics if table.state is None else routing_metrics)
 
 
 def run(cfg: Config, args, metrics) -> dict:

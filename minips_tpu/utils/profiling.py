@@ -86,6 +86,16 @@ LM_MOE_COMBINE = "lm.moe.combine"
 LM_ATTN_MLA = "lm.attn.mla"
 LM_MOE_SHARED = "lm.moe.shared"
 LM_MTP = "lm.mtp"
+# the linear-attention block (models/olmo_hybrid.py): lm.linattn stands
+# where a full-attention layer has lm.attn; inside it the six projections
+# of the input and the output's, the three causal convolutions with their
+# SiLU, the gated delta rule (ops/delta_rule.py: norms of q and k, decay
+# and write strength, chunk terms, both scans) and the normed output gate
+LM_LINATTN = "lm.linattn"
+LM_LINATTN_PROJ = "lm.linattn.proj"
+LM_LINATTN_CONV = "lm.linattn.conv"
+LM_LINATTN_SCAN = "lm.linattn.scan"
+LM_LINATTN_GATE = "lm.linattn.gate"
 # ---- counters of the routing observer (zaya.routing_stats,
 # mla_moe.routing_stats), per log line; the two losses where a model has
 # a prediction module beside its main head
@@ -93,6 +103,14 @@ MOE_TOKENS_HELD = "moe.tokens_held"
 MOE_LOAD_MAX_OVER_MEAN = "moe.load_max_over_mean"
 LM_NLL = "lm.nll"
 MTP_NLL = "mtp.nll"
+# ---- counters of the linear-attention observer (olmo_hybrid.observe),
+# per log line: over the layers, the smallest mean decay exp(g), the
+# largest mean write strength beta (up to 2) and the largest entry of a
+# final state (a state that grows is the first sign of a wrong sign or a
+# missing norm)
+LINATTN_DECAY_MEAN = "linattn.decay_mean"
+LINATTN_BETA_MEAN = "linattn.beta_mean"
+LINATTN_STATE_ABSMAX = "linattn.state_absmax"
 # ---- a torn checkpoint step that restore() walked past; value: the step
 CKPT_SKIP_TORN = "ckpt.skip_torn"
 # ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names
@@ -104,6 +122,11 @@ FLASH_BWD = "flash_bwd"
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
 FLASH_RESIDUALS = (FLASH_OUT, FLASH_LSE)
+# ---- the same for the gated delta rule's forward scan
+# (ops/delta_rule.py): its output and the state at the start of every chunk
+GDN_OUT = "gdn_out"
+GDN_STATES = "gdn_states"
+GDN_RESIDUALS = (GDN_OUT, GDN_STATES)
 GATHER_ROWS = "gather_rows"
 # XLA's own grouped-matmul kernel: what jax.lax.ragged_dot (the dropless
 # expert layer's three products) lowers to on the TPU
@@ -118,7 +141,8 @@ PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
           SPARSE_ADAM_SORTED, SPARSE_ADAM_DENSE,
           LM_EMBED, LM_ATTN, LM_MLP, LM_HEAD, LM_ATTN_CCA, LM_MOE,
           LM_MOE_ROUTER, LM_MOE_DISPATCH, LM_MOE_EXPERTS, LM_MOE_COMBINE,
-          LM_ATTN_MLA, LM_MOE_SHARED, LM_MTP)
+          LM_ATTN_MLA, LM_MOE_SHARED, LM_MTP, LM_LINATTN, LM_LINATTN_PROJ,
+          LM_LINATTN_CONV, LM_LINATTN_SCAN, LM_LINATTN_GATE)
 KERNELS = (FLASH_FWD, FLASH_BWD, GATHER_ROWS, RAGGED_DOT)
 
 RING_SPANS = 8192
