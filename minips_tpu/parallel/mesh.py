@@ -62,6 +62,14 @@ def local_mesh_size(mesh: Mesh, axis: str = DATA_AXIS) -> int:
     return mesh.shape[axis]
 
 
+# The tile a one-dimensional array has on the TPU: the compiled text lays a
+# vector out as ``{0:T(1024)}`` (float32; bfloat16 packs ``(128)(2,1)``
+# inside the same 1,024 elements). A shard that does not end on a tile
+# cannot be placed by an all-gather: the compiler then builds the gather
+# as an all-reduce of zero-padded shards (PERF.md section 6, PR 36).
+SHARD_TILE = 1024
+
+
 def padded_size(n: int, shards: int) -> int:
     """Smallest multiple of ``shards`` >= n (range-partition padding)."""
     return shards * math.ceil(max(n, 1) / shards)

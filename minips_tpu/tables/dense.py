@@ -24,6 +24,7 @@ vector — the same world view as the reference's integer key space.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Optional
 
 import jax
@@ -34,7 +35,7 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from minips_tpu.parallel.mesh import DATA_AXIS, padded_size
+from minips_tpu.parallel.mesh import DATA_AXIS, SHARD_TILE
 from minips_tpu.parallel.partition import RangePartitioner
 from minips_tpu.tables.updaters import (Adam8bitState, LearningRate,
                                         make_updater, masked_merge_adam8)
@@ -90,9 +91,15 @@ class DenseTable:
         # range padding instead of erroring — padding keys are zeros with
         # zero grads, so they quantize to zero codes and never move
         align = int(kw.get("block", 256)) if updater == "adam8" else 1
+        if self.num_shards > 1:
+            # a shard that ends on the chip's tile is one the pull's
+            # all-gather can place; a table on one shard has no
+            # collective, and padding it would only make `full[:n]` a copy
+            align = math.lcm(align, SHARD_TILE)
         self.partitioner = RangePartitioner(self.num_keys, self.num_shards,
                                             align=align)
         self.padded = self.partitioner.padded
+        prof.counter(prof.TABLE_PAD_KEYS, self.padded - self.num_keys)
         self._shard_shape = (self.padded // self.num_shards,)
         # clip-by-global-norm must see the GLOBAL gradient, but the optax
         # transform runs on one owner shard inside shard_map — intercept
@@ -321,6 +328,7 @@ class DenseTable:
         (``step_inplace`` passes it through).
         """
         n, padded = self.num_keys, self.padded
+        pad = padded - n
         num_workers = self.num_shards
         clip_norm = self._clip_norm
         unravel, tx, reduce = self._unravel, self.tx, self.grad_reduce
@@ -340,11 +348,12 @@ class DenseTable:
             user_grad_fn = grad_fn
 
             def grad_fn(params, batch, *state):  # noqa: F811 - a wrap
-                # params arrive cast already: the pull phase casts them
+                # params arrive cast already: the pull phase casts them;
+                # gradients that are padded go up after it (the push phase)
                 loss, grads, *state = user_grad_fn(
                     params, cast_floating(batch, cd), *state)
-                return (loss.astype(jnp.float32),
-                        cast_floating(grads, jnp.float32), *state)
+                return (loss.astype(jnp.float32), grads if pad
+                        else cast_floating(grads, jnp.float32), *state)
 
         def _grads_flat(params, batch, *state):
             if accum == 1:
@@ -391,7 +400,18 @@ class DenseTable:
             with jax.named_scope(prof.GRAD):
                 loss, gflat, *state = _grads_flat(params, batch, *state)
             with jax.named_scope(prof.PUSH):
-                gpad = jnp.zeros(padded, gflat.dtype).at[:n].set(gflat)
+                if pad:
+                    # the padding keys' zeros ravel in with the leaves, in
+                    # the workers' dtype, and the cast comes last, where
+                    # the collective folds it in: a pad of the float32
+                    # vector is a pass over it of its own
+                    gpad = jnp.concatenate(
+                        [gflat, jnp.zeros(pad, gflat.dtype)]
+                    ).astype(jnp.float32)
+                else:
+                    # nothing to pad (one shard): the trace such a step
+                    # has always had, pinned in tests/test_olmo_hybrid.py
+                    gpad = jnp.zeros(padded, gflat.dtype).at[:n].set(gflat)
                 g_shard = quantized_psum_scatter(gpad, DATA_AXIS, comm)
                 if reduce == "mean":
                     g_shard = g_shard / num_workers
@@ -458,9 +478,28 @@ class DenseTable:
         the globally-sharded checkpoint path (SURVEY.md §5.4)."""
         return {"params": self.params, "opt_state": self.opt_state}
 
+    def _at_own_padding(self, new, cur: jax.Array) -> jax.Array:
+        """A checkpoint's range-sharded vector laid out as this table's
+        ``cur``. Where the lengths differ the padding was the writer's
+        (another shard count, or a table from before shards ended on a
+        tile): what covers keys ``< num_keys`` is kept and the tail is
+        this table's own: zeros, or adam8's codes and scales that stand
+        for zero. ``cur`` has one entry a key or one a block of keys."""
+        if np.shape(new) != cur.shape:
+            keep = -(-self.num_keys // (self.padded // cur.shape[0]))
+            if np.ndim(new) != 1 or np.shape(new)[0] < keep:
+                raise ValueError(
+                    f"checkpoint leaf of shape {np.shape(new)} does not "
+                    f"cover the {keep} entries this table's "
+                    f"{self.num_keys} keys need")
+            from minips_tpu.parallel.cluster import host_copy
+
+            new = np.concatenate([np.asarray(new)[:keep],
+                                  host_copy(cur[keep:])])
+        return jax.device_put(jnp.asarray(new), cur.sharding)
+
     def load_state_dict(self, state: dict) -> None:
-        self.params = jax.device_put(
-            jnp.asarray(state["params"]), self._sharding)
+        self.params = self._at_own_padding(state["params"], self.params)
         if self.state is not None and "state" in state:
             self.state = jax.tree.unflatten(
                 jax.tree.structure(self.state),
@@ -469,6 +508,8 @@ class DenseTable:
         # optax's namedtuple states into plain lists, but leaf order is
         # deterministic either way.
         cur_leaves, treedef = jax.tree.flatten(self.opt_state)
+        specs = jax.tree.leaves(self._opt_specs,
+                                is_leaf=lambda x: isinstance(x, P))
         # A leafless opt state (sgd: all EmptyState) writes no npz entry at
         # all, so the key may be legitimately absent from the checkpoint.
         new_leaves = jax.tree.leaves(state.get("opt_state", ()))
@@ -478,6 +519,7 @@ class DenseTable:
                 f"{len(cur_leaves)}, checkpoint has {len(new_leaves)} "
                 "(different updater?)")
         self.opt_state = jax.tree.unflatten(treedef, [
-            jax.device_put(jnp.asarray(new), cur.sharding)
-            for cur, new in zip(cur_leaves, new_leaves)
+            self._at_own_padding(new, cur) if spec == self._pspec
+            else jax.device_put(jnp.asarray(new), cur.sharding)
+            for cur, new, spec in zip(cur_leaves, new_leaves, specs)
         ])

@@ -111,6 +111,9 @@ MTP_NLL = "mtp.nll"
 LINATTN_DECAY_MEAN = "linattn.decay_mean"
 LINATTN_BETA_MEAN = "linattn.beta_mean"
 LINATTN_STATE_ABSMAX = "linattn.state_absmax"
+# ---- under ps.table.init: the zero keys a DenseTable's range padding
+# added (shards on a mesh end on the chip's tile, mesh.SHARD_TILE)
+TABLE_PAD_KEYS = "ps.table.pad_keys"
 # ---- a torn checkpoint step that restore() walked past; value: the step
 CKPT_SKIP_TORN = "ckpt.skip_torn"
 # ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names

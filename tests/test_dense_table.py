@@ -10,12 +10,13 @@ from minips_tpu.tables.dense import DenseTable
 
 
 def _template():
-    return {"w": jnp.zeros((3, 4)), "b": jnp.zeros(5)}  # 17 keys -> pads to 24
+    # 17 keys -> eight shards of one tile (mesh.SHARD_TILE) each
+    return {"w": jnp.zeros((3, 4)), "b": jnp.zeros(5)}
 
 
 def test_init_pull_roundtrip(mesh8):
     t = DenseTable(_template(), mesh8)
-    assert t.num_keys == 17 and t.padded == 24
+    assert t.num_keys == 17 and t.padded == 8 * 1024
     pulled = t.pull()
     assert pulled["w"].shape == (3, 4) and pulled["b"].shape == (5,)
     np.testing.assert_allclose(np.asarray(pulled["w"]), 0.0)
@@ -31,7 +32,7 @@ def test_sharded_init_keeps_the_template_values(mesh8):
                            np.asarray(tmpl["w"]).ravel()])  # sorted keys
     np.testing.assert_array_equal(np.asarray(t.params)[:17], flat)
     np.testing.assert_array_equal(np.asarray(t.params)[17:], 0.0)
-    assert {s.data.shape for s in t.params.addressable_shards} == {(3,)}
+    assert {s.data.shape for s in t.params.addressable_shards} == {(1024,)}
 
 
 def test_push_sgd_matches_oracle(mesh8):
@@ -441,13 +442,14 @@ def test_adam8_blockwise_matches_adam_trajectory(mesh8):
 def test_adam8_odd_size_aligns_padding(mesh8):
     """A param count that doesn't divide into whole blocks per shard must
     ALIGN the range padding (RangePartitioner align=block), not error and
-    not mis-slice: 65 keys over 8 shards with block 8 pads to 128 (16 per
-    shard = 2 whole blocks), trains, and padding stays zero."""
+    not mis-slice: 65 keys over 8 shards with block 8 pads to whole blocks
+    AND whole tiles a shard (lcm(8, 1,024) keys each), trains, and padding
+    stays zero."""
     from minips_tpu.models import lr as lr_model
 
     t = DenseTable(lr_model.init(64), make_mesh(8), name="odd8",
                    updater="adam8", lr=0.05, updater_kwargs={"block": 8})
-    assert t.padded == 128 and t.partitioner.shard_size == 16
+    assert t.padded == 8192 and t.partitioner.shard_size == 1024
     bs = _lr_batches(10, d=64)
     step = t.make_step(lr_model.grad_fn_dense)
     losses = [float(t.step_inplace(step, b)) for b in bs]
@@ -526,11 +528,12 @@ def test_custom_tx_adam8_scales_shard_and_misalign_raises(mesh8):
     assert scales and all(x.sharding.spec == P("data") for x in scales)
     t.push({"w": jnp.ones(64)})
     assert float(np.abs(np.asarray(t.pull()["w"])).sum()) > 0
-    # 64 keys / 8 shards = 8 per shard; block 16 divides padded (adam8's
-    # own init check passes) but not the shard — must refuse loudly
+    # 64 keys / 8 shards = a tile of 1,024 per shard; block 2,048 divides
+    # padded (adam8's own init check passes) but not the shard — must
+    # refuse loudly
     with pytest.raises(ValueError, match="whole blocks"):
-        DenseTable({"w": jnp.zeros(64)}, mesh8, name="ctx16",
-                   tx=make_updater("adam8", 0.01, block=16))
+        DenseTable({"w": jnp.zeros(64)}, mesh8, name="ctx2048",
+                   tx=make_updater("adam8", 0.01, block=2048))
 
 
 def test_quantize_roundtrip_log_codebook_relative_error():

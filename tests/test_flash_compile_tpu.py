@@ -2,27 +2,38 @@
 cells' head shapes: no chip is attached and nothing runs, but the chip's
 own compiler is what accepts or refuses a kernel's VMEM (the backward keeps
 a group's whole dQ there, under ``vmem_limit_bytes``), its tiling and its
-slices, which the Pallas interpreter cannot. All such compiles live in this
-one file: the process that describes the topology holds the TPU library
-until it exits."""
+slices, which the Pallas interpreter cannot. And the fused PS step compiled
+for the four chips of the described host: which collectives the chip's
+compiler builds for the pull and the push is in the compiled text alone.
+All such compiles live in this one file: the process that describes the
+topology holds the TPU library until it exits."""
+
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 from minips_tpu.ops import flash_attention as fa
+from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
+from minips_tpu.tables.dense import DenseTable
 from minips_tpu.utils import profiling as prof
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:   # no TPU compiler here, or another holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -67,3 +78,71 @@ def test_the_kernels_compile_for_the_v5e_at_a_cells_shape(
     for name, there in ((prof.FLASH_FWD, True), (prof.FLASH_BWD, True),
                         ("flash_dq", False), ("flash_dkv", False)):
         assert (name in text) == there
+
+
+# ------------------------------------------- the fused PS step's collectives
+def _compiled_step(topo, shards: int, comm: str):
+    """``(table, compiled text)`` of a small fused step for ``shards`` of
+    the described chips: the table is built on as many CPU devices (its
+    own constructor decides the padding), then stands on the described
+    mesh, where only shapes can be handed in."""
+    def grad_fn(p, b):
+        def loss(p):
+            return jnp.mean(
+                (b["x"] @ p["w"] + jnp.sum(p["b"]) - b["y"]) ** 2)
+        return jax.value_and_grad(loss)(p)
+
+    t = DenseTable({"w": jnp.zeros(3000), "b": jnp.zeros(7)},
+                   make_mesh(shards, devices=jax.devices()[:shards]),
+                   updater="adam", lr=0.1)
+    t.mesh = make_mesh(shards, devices=topo.devices[:shards])
+
+    def on(spec):
+        return NamedSharding(t.mesh, spec)
+
+    def shape_on(x, spec):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on(spec))
+
+    opt = jax.tree.map(shape_on, t.opt_state, t._opt_specs)
+    batch = {"x": jax.ShapeDtypeStruct((8, 3000), jnp.float32,
+                                       sharding=on(P(DATA_AXIS))),
+             "y": jax.ShapeDtypeStruct((8,), jnp.float32,
+                                       sharding=on(P(DATA_AXIS)))}
+    step = t.make_step(grad_fn, compute_dtype=jnp.bfloat16, comm=comm)
+    return t, step.lower(shape_on(t.params, P(DATA_AXIS)), opt,
+                         batch).compile().as_text()
+
+
+_COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(-start)?\(")
+
+
+@pytest.mark.parametrize("comm", ["float32", "bfloat16"])
+def test_the_pull_of_a_sharded_table_compiles_to_an_all_gather(
+        comm, topo, no_compile_cache):
+    """3,007 keys over four chips: shards of 1,024, so that the pull is
+    the ``all-gather`` the step asks for. (A shard of 752, the length
+    divided by four, is what the compiler gathers as an ``all-reduce`` of
+    zero-padded shards in the workers' dtype, twice the bytes on the
+    wire.) The push of ``comm`` float32 stays the all-reduce this compiler
+    builds for a ``psum_scatter``; the compressed tier's is an
+    all-to-all."""
+    t, text = _compiled_step(topo, 4, comm)
+    assert t.padded == 4096
+    lines = [ln for ln in text.splitlines() if _COLLECTIVE.search(ln)]
+    assert any(re.search(r"= bf16\[4096\]\S* all-gather\(", ln)
+               for ln in lines), lines
+    assert not any(re.search(r"bf16\[\d+\]\S* all-reduce\(", ln)
+                   for ln in lines), lines
+    pushes = [ln for ln in lines if "f32[4096]" in ln and "all-reduce(" in ln]
+    assert len(pushes) == (comm == "float32"), lines
+    assert any("all-to-all(" in ln for ln in lines) == (comm == "bfloat16")
+
+
+@pytest.mark.parametrize("comm", ["float32", "bfloat16"])
+def test_a_table_on_one_shard_compiles_to_no_collective(
+        comm, topo, no_compile_cache):
+    t, text = _compiled_step(topo, 1, comm)
+    assert t.padded == t.num_keys == 3007
+    assert not [ln for ln in text.splitlines() if _COLLECTIVE.search(ln)]

@@ -12,6 +12,7 @@ compiled step; the app.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import re
@@ -21,7 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.sharding import PartitionSpec as P
+
 from minips_tpu.models import mla_moe, olmo_hybrid, zaya
+from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
 from minips_tpu.utils import profiling as prof
 from tests.conftest import add_bench_paths
 
@@ -153,7 +157,6 @@ def _worst_grad_gap(got: dict, want: dict) -> float:
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_three_fused_steps_agree_with_the_reference(ref, mesh4, dtype):
-    from minips_tpu.parallel.mesh import make_mesh
     config = dict(CONFIG, compute_dtype=dtype, attn="flash", head_chunk=32,
                   updater="adam")
     batches = _batches()
@@ -185,7 +188,6 @@ def test_the_step_on_four_devices_is_the_step_on_one(mesh4):
     """The same three batches through the table sharded four ways and
     through one shard: the state after three steps to the band
     ``test_transformer.py`` holds dp against one device to."""
-    from minips_tpu.parallel.mesh import make_mesh
     config = dict(CONFIG, compute_dtype="float32", attn="flash",
                   head_chunk=32)
     batches, p0 = _batches(), _params(2)
@@ -276,35 +278,54 @@ def test_beta_is_doubled_only_where_the_file_allows_negative_eigenvalues():
 
 
 # ---------------------------------------------- the one builder of steps
-# the jaxprs of ZAYA's and JoyAI's fused steps on the four-device mesh at
-# the PARENT of the PR that let the builder serve a model without a state
-# (66ccca4), as ``_step_digest`` builds them: the builder still traces the
-# programs it traced then. After a change of jax's printing: check out
-# that commit, print the digests there, and compare.
+# the jaxprs of ZAYA's and JoyAI's fused steps as the builder traces them,
+# and of the dense transformer's as the benchmark's ``lm`` adapter builds
+# it, on ONE shard, as the one-chip cells run them, at the PARENT of the PR
+# that ended a sharded table's shards on the chip's tile (012858a; a table
+# on one shard pads nothing, so its programs are the ones it traced then,
+# and so are the builder's since it came to serve a model without a state,
+# PR 35, which pinned these on four shards). After a change of jax's
+# printing: check out that commit, print the digests there, and compare.
 STEP_JAXPR = {
     "zaya":
-    "a831b8e3a819efe74219958a926cc766c2cc1722d9c39d15ecf613082fb1ae8b",
+    "4c9d09776bae9cd9d97ceef6e100cad84b5513bf9331cc72d94b5ec955fe6ffa",
     "joyai_llm_flash":
-    "545910338711afd607a055775e662f45c8f45427fc2b796d40c179bc807c126e"}
+    "e03b1c092ac7d7667cf9118873bd78490a6a92fb78edd72211fd2dff39f6380d",
+    "lm":
+    "5fcdc69ab19dd990068f28b413a2f9c5d09e9f22f9ce4e3e93a708f38db696f3"}
 
 
 def _step_digest(kind: str, mesh) -> str:
     from minips_tpu.apps.lm_example import model_dp_step
-    if kind == "zaya":
-        from tests.test_zaya import CONFIG as C
-        config, mod = dict(C, model_type="zaya"), zaya
+    first = {"tokens": jnp.asarray(np.arange(4 * 17).reshape(4, 17) % 64,
+                                   jnp.int32)}
+    if kind == "lm":
+        from minips_tpu.models import transformer as tfm
+        from minips_tpu.tables.dense import DenseTable
+        p = tfm.init(jax.random.PRNGKey(0), vocab=64, dim=16, heads=2,
+                     depth=2, max_len=16)
+        table = DenseTable(p, mesh, name="lm", updater="adam", lr=1e-3)
+        step = table.make_step(
+            functools.partial(tfm.grad_fn, heads=2, attn_impl="flash",
+                              remat="dots", head_chunk=8),
+            batch_spec=P(DATA_AXIS), accum=1, compute_dtype=jnp.bfloat16,
+            comm="float32")
+        args = (table.params, table.opt_state, first)
     else:
-        from tests.test_mla_moe import CONFIG as C
-        config, mod = dict(C), mla_moe
-    config = dict(config, compute_dtype="bfloat16", attn="flash",
-                  head_chunk=8)
-    p = mod.init(jax.random.PRNGKey(0), mod.from_config(config))
-    first = {"tokens": jnp.asarray(
-        np.arange(4 * 17).reshape(4, 17) % config["vocab_size"], jnp.int32)}
-    _, table, step, _ = model_dp_step(config, mesh, p, first,
-                                      updater="adam", lr=1e-3)
-    text = str(jax.make_jaxpr(step)(table.params, table.opt_state, first,
-                                    table.state))
+        if kind == "zaya":
+            from tests.test_zaya import CONFIG as C
+            config, mod = dict(C, model_type="zaya"), zaya
+        else:
+            from tests.test_mla_moe import CONFIG as C
+            config, mod = dict(C), mla_moe
+        config = dict(config, compute_dtype="bfloat16", attn="flash",
+                      head_chunk=8)
+        first["tokens"] = first["tokens"] % config["vocab_size"]
+        p = mod.init(jax.random.PRNGKey(0), mod.from_config(config))
+        _, table, step, _ = model_dp_step(config, mesh, p, first,
+                                          updater="adam", lr=1e-3)
+        args = (table.params, table.opt_state, first, table.state)
+    text = str(jax.make_jaxpr(step)(*args))
     text = re.sub(r" at 0x[0-9a-f]+", "", text)     # a function's address
     text = re.sub(                  # a set prints in the order of its hashes
         r"frozenset\(\{([^}]*)\}\)", lambda s: "frozenset({%s})" % ", ".join(
@@ -313,8 +334,9 @@ def _step_digest(kind: str, mesh) -> str:
 
 
 @pytest.mark.parametrize("kind", sorted(STEP_JAXPR))
-def test_the_builder_traces_the_routed_models_steps_as_before(mesh4, kind):
-    assert _step_digest(kind, mesh4) == STEP_JAXPR[kind]
+def test_a_one_shard_step_traces_to_the_program_it_traced(kind):
+    mesh = make_mesh(1, devices=jax.devices()[:1])
+    assert _step_digest(kind, mesh) == STEP_JAXPR[kind]
 
 
 def test_the_scopes_are_in_the_compiled_step(mesh4):
