@@ -149,6 +149,7 @@ class DenseTable:
             self.tx.init, out_shardings=opt_shardings
         )(self.params)
         self.state = None       # see make_step(state=...)
+        self._staged = None     # the step whose program step_inplace staged
 
     def _opt_specs_tree(self, opt_state) -> PyTree:
         """Spec tree for the opt state: params-length 1-D leaves range-
@@ -306,7 +307,7 @@ class DenseTable:
         reduced precision — the MXU-native mixed-precision recipe: float32
         master weights and optimizer update on the owner shard, with
         params AND floating batch leaves cast down before ``grad_fn`` and
-        the gradients cast back up before the push, so the loss surface is
+        the gradients cast back up in the push, so the loss surface is
         evaluated in bf16 but the update path never loses master-weight
         precision. Composes with ``comm`` (wire) and ``accum`` (the f32
         microbatch fold).
@@ -349,16 +350,24 @@ class DenseTable:
 
             def grad_fn(params, batch, *state):  # noqa: F811 - a wrap
                 # params arrive cast already: the pull phase casts them;
-                # gradients that are padded go up after it (the push phase)
+                # the gradients go back up in the push phase
                 loss, grads, *state = user_grad_fn(
                     params, cast_floating(batch, cd), *state)
-                return (loss.astype(jnp.float32), grads if pad
-                        else cast_floating(grads, jnp.float32), *state)
+                return (loss.astype(jnp.float32), grads, *state)
 
-        def _grads_flat(params, batch, *state):
+        def to_wire(grads):
+            # the push's own work on the workers' gradients: the cast back
+            # up (one that is padded goes up after the pad) and the ravel
+            # into the table's one vector
+            if cd is not None and not pad:
+                grads = cast_floating(grads, jnp.float32)
+            return ravel_pytree(grads)[0]
+
+        def _grads(params, batch, *state):
+            # (loss, gradients, *state): the gradients as the workers' tree
+            # where accum is 1 (the push ravels it), else the folded vector
             if accum == 1:
-                loss, grads, *state = grad_fn(params, batch, *state)
-                return (loss, ravel_pytree(grads)[0], *state)
+                return grad_fn(params, batch, *state)
 
             def to_micro(x):
                 if x.shape[0] % accum:
@@ -372,7 +381,7 @@ class DenseTable:
             def fold(carry, mb):
                 loss_sum, gsum = carry
                 loss, grads = grad_fn(params, mb)
-                return (loss_sum + loss, gsum + ravel_pytree(grads)[0]), None
+                return (loss_sum + loss, gsum + to_wire(grads)), None
 
             # fresh carries are axis-invariant but fold outputs vary
             # wherever params OR batch do (a replicated batch still yields
@@ -398,8 +407,9 @@ class DenseTable:
                 full = quantized_all_gather(p_shard, DATA_AXIS, comm)
                 params = cast_floating(unravel(full[:n]), cd)
             with jax.named_scope(prof.GRAD):
-                loss, gflat, *state = _grads_flat(params, batch, *state)
+                loss, grads, *state = _grads(params, batch, *state)
             with jax.named_scope(prof.PUSH):
+                gflat = to_wire(grads) if accum == 1 else grads
                 if pad:
                     # the padding keys' zeros ravel in with the leaves, in
                     # the workers' dtype, and the cast comes last, where
@@ -446,6 +456,12 @@ class DenseTable:
     def step_inplace(self, step, batch) -> jnp.ndarray:
         """Run a fused step against the table's own state."""
         with prof.span(prof.STEP):
+            if step is not self._staged or prof.stale(prof.DENSE_STEP_FN):
+                # the step's first call, or its program was built anew
+                prof.stage(prof.DENSE_STEP_FN, step, self.params,
+                           self.opt_state, batch,
+                           *(() if self.state is None else (self.state,)))
+                self._staged = step
             if self.state is None:
                 self.params, self.opt_state, loss = step(
                     self.params, self.opt_state, batch)

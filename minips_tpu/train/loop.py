@@ -13,6 +13,7 @@ import os
 from typing import Any, Callable, Iterable, Optional
 
 from minips_tpu.utils import profiling as prof
+from minips_tpu.utils import trace_analysis
 from minips_tpu.utils.metrics import MetricsLogger
 from minips_tpu.utils.timing import StepTimer
 
@@ -73,8 +74,11 @@ class TrainLoop:
         finally:
             if self.profiler is not None:
                 self.profiler.close()  # an open trace must flush even on error
-                # the run's spans and counters, beside the trace
+                # the run's spans and counters, and the account of the
+                # step's program, beside the trace
                 prof.dump(os.path.join(self.profiler.log_dir, "spans.json"))
+                trace_analysis.dump_programs(
+                    os.path.join(self.profiler.log_dir, "programs.json"))
 
     def _run(self, num_iters: int) -> list[float]:
         losses: list[float] = []

@@ -90,6 +90,7 @@ class PSTrainStep:
         self._mesh = (dense.mesh if dense is not None
                       else next(iter(self.sparse.values())).mesh)
         self._jit_step = self._build()
+        self._staged = False    # its program's account is kept
 
     # ------------------------------------------------------------------ build
     def _collect_state(self) -> dict:
@@ -182,6 +183,10 @@ class PSTrainStep:
             with prof.span(prof.STEP_COLLECT):
                 state = self._collect_state()
             with prof.span(prof.STEP_DISPATCH):
+                if not self._staged or prof.stale(prof.FUSED_STEP_FN):
+                    prof.stage(prof.FUSED_STEP_FN, self._jit_step, state,
+                               batch)
+                    self._staged = True
                 new_state, loss = self._jit_step(state, batch)
             with prof.span(prof.STEP_RESTORE):
                 self._restore_state(new_state)

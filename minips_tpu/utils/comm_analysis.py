@@ -48,9 +48,10 @@ _DTYPE_BYTES = {
 }
 
 # e.g.:  %all-reduce.1 = f32[1024,64]{1,0} all-reduce(%fusion), ...
-#        %ag = (s32[8]{0}, s32[8]{0}) all-gather(...)   (tuple results)
+#        %ag = (s32[8]{0}, s32[8]{0}) all-gather(...)   (tuple results;
+#        the chip's layouts hold parentheses: f32[8]{0:T(1024)S(1)})
 _OP_RE = re.compile(
-    r"=\s*(?P<result>\([^)]*\)|\S+?)\s+"
+    r"(?:%?(?P<name>[\w.\-]+)\s*)?=\s*(?P<result>\(.*?\)|\S+?)\s+"
     r"(?P<op>" + "|".join(_COLLECTIVES) + r")(?:-start|-done)?\(")
 # full HLO primitive-type names (f8e4m3fn, bf16, u4, ...): letters and
 # digits interleave, so the name is letter-led alphanumeric — anchored by
@@ -68,6 +69,7 @@ class CollectiveOp:
     # result — guards compare these as INTEGERS (substring matching on
     # `shape` false-positives, e.g. 16384 inside f32[163840])
     dims: tuple = ()
+    name: str = ""     # the instruction's own, e.g. "all-reduce.99"
 
     def has_dim(self, n: int) -> bool:
         return any(n in d for d in self.dims)
@@ -116,6 +118,10 @@ def collective_ops(hlo_text: str) -> list[CollectiveOp]:
     """
     ops: list[CollectiveOp] = []
     for line in hlo_text.splitlines():
+        # a compiled step's text is megabytes of long lines: the pattern
+        # is tried only on those that name a collective at all
+        if not any(c in line for c in _COLLECTIVES):
+            continue
         m = _OP_RE.search(line)
         if m is None or f"{m.group('op')}-done(" in line:
             continue
@@ -123,7 +129,7 @@ def collective_ops(hlo_text: str) -> list[CollectiveOp]:
         nbytes, shapes, dims = _shape_bytes(m.group("result"),
                                             largest=is_start)
         ops.append(CollectiveOp(m.group("op"), " ".join(shapes), nbytes,
-                                dims))
+                                dims, m.group("name") or ""))
     return ops
 
 

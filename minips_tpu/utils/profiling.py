@@ -13,8 +13,18 @@ and the ring is the cost that is always paid.
 
 Compilations are counted at the same boundaries: every program built or
 read back from the persistent cache is a ``ps.compile`` record with its
-duration, its parent span and its step; every cache miss a
-``ps.cache_miss``.
+duration, its parent span and its step, every function traced to a jaxpr
+a ``ps.trace`` (the outermost: one traced inside another's trace is part
+of the outer's seconds) and every module lowered a ``ps.lower``, each with
+the program's name (jax's ``fun_name``); every cache miss a
+``ps.cache_miss``. Counters and these records are kept apart from the step
+spans, so that what set-up recorded is still there after any number of
+steps.
+
+A fused step keeps an account of its own program (``stage``, ``programs``):
+the compiler's count of its memory and the compiled text, from which
+``trace_analysis.account`` reads, when somebody asks, the PS phase of every
+instruction and the collectives the compiler built.
 
 The names below are the only definition of the host spans and of the
 ``jax.named_scope`` phases inside the jitted steps: call sites and the
@@ -22,7 +32,7 @@ reduction (``utils/trace_analysis.py``) import them from here.
 
 ``profile_trace`` / ``StepWindowProfiler`` capture the profiler's trace;
 ``TrainLoop(profile_dir=, profile_range=)`` is the operator's way to one,
-and writes ``spans.json`` beside it.
+and writes ``spans.json`` and ``programs.json`` beside it.
 """
 
 from __future__ import annotations
@@ -54,6 +64,9 @@ LOOP_CHECKPOINT = "loop.checkpoint"
 # ---- counters, recorded in the ring like spans, under the span open then
 COMPILE = "ps.compile"
 CACHE_MISS = "ps.cache_miss"
+# ---- the two stages before a compilation, counted like it
+TRACE = "ps.trace"
+LOWER = "ps.lower"
 # ---- phases of the jitted steps (jax.named_scope -> an HLO op's op_name)
 PULL = "ps.pull"
 GRAD = "ps.grad"
@@ -139,6 +152,7 @@ FUSED_STEP_FN = "ps_fused_step"
 
 # an op_name path may hold several (ps.grad/lm.attn/...): a reduction
 # takes the innermost
+PS_PHASES = (PULL, GRAD, PUSH, UPDATE)      # in the order a step runs them
 PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
           SPARSE_DEDUP, SPARSE_ADAGRAD_SORTED, SPARSE_ADAGRAD_DENSE,
           SPARSE_ADAM_SORTED, SPARSE_ADAM_DENSE,
@@ -149,14 +163,21 @@ PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
 KERNELS = (FLASH_FWD, FLASH_BWD, GATHER_ROWS, RAGGED_DOT)
 
 RING_SPANS = 8192
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": LOWER,
+    "/jax/core/compile/backend_compile_duration": COMPILE,
+}
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
 class Span(NamedTuple):
     """One closed span. ``parent`` is the id of the span that caused it
     (None at top level), ``step`` the ordinal of the enclosing
-    ``ps.step`` (None outside one); times are ``perf_counter_ns``."""
+    ``ps.step`` (None outside one); times are ``perf_counter_ns``;
+    ``fun_name`` is the program a ``ps.trace`` / ``ps.lower`` /
+    ``ps.compile`` record is of (``jit(ps_dense_step)``), None for a
+    span."""
     id: int
     parent: Optional[int]
     name: str
@@ -164,11 +185,16 @@ class Span(NamedTuple):
     start_ns: int
     end_ns: int
     step: Optional[int]
+    fun_name: Optional[str] = None
 
 
 _ring: collections.deque = collections.deque(maxlen=RING_SPANS)
+# counters and the stage events: one-off records, mostly of set-up, that
+# the step spans of a long window must not push out
+_marks: collections.deque = collections.deque(maxlen=RING_SPANS)
 _counters: dict[str, list] = {}      # name -> [count, total_ns]
-_lock = threading.Lock()             # guards _ring, _counters, _listening
+_programs: dict[str, "Program"] = {}
+_lock = threading.Lock()     # guards the four above and _listening
 _ids = itertools.count()
 _steps = itertools.count()
 _open = threading.local()            # .stack: the spans open on a thread
@@ -183,13 +209,14 @@ def _stack() -> list:
         return _open.stack
 
 
-def _record(name: str, start_ns: int, end_ns: int, sid: int,
-            parent: Optional["span"], step: Optional[int]) -> None:
+def _record(into: collections.deque, name: str, start_ns: int, end_ns: int,
+            sid: int, parent: Optional["span"], step: Optional[int],
+            fun_name: Optional[str] = None) -> None:
     rec = Span(sid, None if parent is None else parent.id, name,
                None if parent is None else parent.name,
-               start_ns, end_ns, step)
+               start_ns, end_ns, step, fun_name)
     with _lock:
-        _ring.append(rec)
+        into.append(rec)
         c = _counters.get(name)
         if c is None:
             _counters[name] = [1, end_ns - start_ns]
@@ -198,13 +225,14 @@ def _record(name: str, start_ns: int, end_ns: int, sid: int,
             c[1] += end_ns - start_ns
 
 
-def _record_under_open_span(name: str, duration_ns: int) -> None:
+def _record_under_open_span(name: str, duration_ns: int,
+                            fun_name: Optional[str] = None) -> None:
     """A counter's record, ending now, under the span open on this thread."""
     end = time.perf_counter_ns()
     stack = _stack()
     parent = stack[-1] if stack else None
-    _record(name, end - duration_ns, end, next(_ids), parent,
-            None if parent is None else parent.step)
+    _record(_marks, name, end - duration_ns, end, next(_ids), parent,
+            None if parent is None else parent.step, fun_name)
 
 
 def counter(name: str, value: float) -> None:
@@ -218,8 +246,23 @@ def counter(name: str, value: float) -> None:
 
 
 def _on_duration(event: str, duration_secs: float, **kw) -> None:
-    if event == _COMPILE_EVENT:
-        _record_under_open_span(COMPILE, int(duration_secs * 1e9))
+    name = _STAGE_EVENTS.get(event)
+    if name is None:
+        return
+    if name == TRACE and not jax.core.trace_ctx.is_top_level():
+        # a function traced INSIDE another's trace (thousands a model) is
+        # part of the outer one's seconds: only the outermost is recorded
+        return
+    fun_name = kw.get("fun_name")
+    _record_under_open_span(name, int(duration_secs * 1e9), fun_name)
+    if name == COMPILE and fun_name:
+        # the program an account is kept of, compiled AGAIN (other
+        # arguments: a state that was uncommitted on the first call and
+        # lies on the mesh on the second): the account is of a program
+        # that no longer runs, and the step's next call stages anew
+        kept = _programs.get(fun_name[len("jit("):-1])
+        if kept is not None:
+            kept.stale = True
 
 
 def _on_event(event: str, **kw) -> None:
@@ -272,26 +315,32 @@ class span(contextlib.ContextDecorator):
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
         _stack().pop()
-        _record(self.name, self._t0, t1, self.id, self._parent, self.step)
+        _record(_ring, self.name, self._t0, t1, self.id, self._parent,
+                self.step)
         self._ann.__exit__(*exc)
         return False
 
 
 def snapshot() -> tuple[tuple, dict]:
-    """A copy of what was recorded: (the ring's spans, oldest first;
-    ``{name: (count, total_ns)}`` over every span since the start or the
-    last ``clear``, also those the ring has dropped)."""
+    """A copy of what was recorded: (the spans the ring holds and the
+    counters' and stage events' records, as one tuple, oldest first by
+    when each ended; ``{name: (count, total_ns)}`` over every record since
+    the start or the last ``clear``, also those since dropped)."""
     with _lock:
-        return (tuple(_ring),
-                {k: (v[0], v[1]) for k, v in _counters.items()})
+        recs = list(_ring) + list(_marks)
+        counters = {k: (v[0], v[1]) for k, v in _counters.items()}
+    recs.sort(key=lambda s: s.end_ns)
+    return tuple(recs), counters
 
 
 def clear() -> None:
-    """Empty the ring and the counters (tests, tools); ids and step
-    ordinals keep counting."""
+    """Empty the ring, the counters and the programs' accounts (tests,
+    tools); ids and step ordinals keep counting."""
     with _lock:
         _ring.clear()
+        _marks.clear()
         _counters.clear()
+        _programs.clear()
 
 
 def self_time(spans: Iterable[Span]) -> dict[int, int]:
@@ -326,6 +375,80 @@ def dump(path: str) -> None:
                    "spans": [list(s) for s in spans],
                    "counters": {k: list(v) for k, v in counters.items()}},
                   f)
+
+
+class Program:
+    """The account a fused step keeps of its own program, from the one
+    ``Compiled`` that ``stage`` made before the step's call.
+
+    ``memory`` is the compiler's own count (``memory_analysis()``), bytes a
+    chip: ``argument_bytes``, ``output_bytes``, ``alias_bytes`` (outputs
+    that live in donated arguments), ``temp_bytes``, ``code_bytes`` and
+    ``total_bytes``, what the step holds while it runs (arguments + outputs
+    - aliases + temporaries + code); None where the backend counts nothing.
+    ``text()`` is the compiled module's text, made when somebody first asks
+    (a reader after the window, the operator's dump) and never in set-up:
+    ``trace_analysis.account`` reads the phase of every instruction and the
+    collectives the compiler built from it. ``stale`` turns True when the
+    program is compiled again after this account was kept."""
+
+    def __init__(self, name: str, compiled):
+        self.name = name
+        self.stale = False
+        self._compiled = compiled
+        self._text: Optional[str] = None
+        m = compiled.memory_analysis()
+        self.memory = None if m is None else {
+            "argument_bytes": int(m.argument_size_in_bytes),
+            "output_bytes": int(m.output_size_in_bytes),
+            "alias_bytes": int(m.alias_size_in_bytes),
+            "temp_bytes": int(m.temp_size_in_bytes),
+            "code_bytes": int(m.generated_code_size_in_bytes),
+            "total_bytes": int(
+                m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes
+                + m.generated_code_size_in_bytes)}
+
+    def text(self) -> str:
+        """The compiled module's text. Once it is read the ``Compiled`` is
+        let go, and with it the account's hold on the executable."""
+        if self._text is None:
+            self._text = self._compiled.as_text()
+            self._compiled = None
+        return self._text
+
+
+def stage(name: str, step, *args) -> None:
+    """Called before a call of a jitted ``step`` on ``args``, under the
+    caller's ``ps.step``: the step's FIRST call, and the first after
+    ``stale(name)``. Lowers and compiles the program here, where the call
+    would have, and keeps its account under ``name``. The two share jax's
+    caches either way round: the call that follows finds the executable
+    (one ``ps.compile`` record a program, as before), and a program that a
+    call has compiled already is staged without a record. A step that is
+    not jitted has no ``lower`` and keeps no account."""
+    lower = getattr(step, "lower", None)
+    if lower is None:
+        return
+    program = Program(name, lower(*args).compile())
+    with _lock:
+        _programs[name] = program
+
+
+def stale(name: str) -> bool:
+    """True where the account kept under ``name`` is of a program that has
+    been compiled again since (the listener saw a ``ps.compile`` of
+    ``jit(<name>)`` after the account was kept): the steps run another
+    executable than the account describes until the step is staged anew."""
+    kept = _programs.get(name)
+    return kept is not None and kept.stale
+
+
+def programs() -> dict[str, Program]:
+    """``{program name: its account}``: one for ``ps_dense_step``, one for
+    ``ps_fused_step``, each of the step staged last under that name."""
+    with _lock:
+        return dict(_programs)
 
 
 @contextlib.contextmanager

@@ -22,8 +22,15 @@ Planes, lines and events come from ``jax.profiler.ProfileData``. An op's
 scope path (its HLO ``op_name``) is the ``tf_op`` stat of the event's
 METADATA, which ``ProfileData`` does not hand out (an event's ``stats`` are
 its own: offsets and durations), so ``read_metadata`` walks the file's
-protobuf wire format for that one map. A CPU trace carries no such stat:
-its ops are reported unnamed, from the host plane.
+protobuf wire format for that one map. A CPU trace carries no such stat,
+and the compiler's own instructions carry none on any backend: where
+``programs.json`` lies in ``log_dir`` (``TrainLoop(profile_dir=)`` writes
+the account the step keeps of its own program there:
+``dump_programs``), such an op takes its phase from the account
+by its instruction's name (``instruction_phases``: by scope, else by its
+neighbours), and the step's memory by the compiler's count and the
+collectives the compiler built under each PS phase are printed with the
+rest. Without the file such ops are reported unnamed.
 
   python -m minips_tpu.utils.trace_analysis <log_dir> [--top N]
 """
@@ -36,8 +43,9 @@ import json
 import os
 import re
 from dataclasses import dataclass, field
-from typing import Iterable, Optional
+from typing import Iterable, NamedTuple, Optional
 
+from minips_tpu.utils import comm_analysis
 from minips_tpu.utils import profiling as prof
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)")
@@ -220,6 +228,179 @@ def phase_of(scope: str) -> tuple[Optional[str], str]:
     return phase, part
 
 
+def ps_phase_of(scope: str) -> Optional[str]:
+    """The PS phase an op_name path lies in: the OUTERMOST of ``ps.pull``,
+    ``ps.grad``, ``ps.push``, ``ps.update`` in it (``ps.push.dense`` and
+    ``ps.push.sparse/<table>`` are ``ps.push``), or None."""
+    for hit in _PHASE.findall(scope):
+        for ps in prof.PS_PHASES:
+            if hit == ps or hit.startswith(ps + "."):
+                return ps
+    return None
+
+
+# ---------------------------------------------- a compiled program's phases
+class Instruction(NamedTuple):
+    """Where one instruction of a compiled step belongs."""
+    ps_phase: Optional[str]    # ps.pull | ps.grad | ps.push | ps.update
+    phase: Optional[str]       # the innermost named phase (``phase_of``)
+    part: str                  # fwd | bwd | remat
+    how: Optional[str]         # "scope", "neighbours", or None: not found
+
+
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*)$")
+_HLO_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_HLO_CALLS = re.compile(r"\bcalls=%([\w.\-]+)")
+_HLO_PARAMETER = re.compile(r" parameter\((\d+)\)")
+
+
+class _Hlo(NamedTuple):
+    """One instruction line of a compiled module's text."""
+    operands: list             # instruction names, in order
+    scope: Optional[str]       # its op_name
+    calls: Optional[str]       # the computation a fusion calls
+    parameter: Optional[int]   # a parameter's number
+
+
+def _computations(hlo_text: str) -> Iterable[tuple[str, dict]]:
+    """(name, ``{instruction name: _Hlo}``) of every computation of a
+    compiled module's text as jax prints it (names with ``%``), in the
+    text's order: callees before callers, instructions as scheduled."""
+    name, body = None, {}
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            head = line.split("(", 1)[0].split()
+            name, body = head[-1].lstrip("%") if head else "", {}
+            continue
+        if line == "}":
+            if name is not None:
+                yield name, body
+            name = None
+            continue
+        m = _HLO_INSTRUCTION.match(line) if name is not None else None
+        if m is None:
+            continue
+        rest = m.group(2)
+        op = _HLO_OPCODE.search(rest)
+        opcode, operands = (op.group(1) if op else ""), []
+        if op:
+            depth, i = 1, op.end()
+            while i < len(rest) and depth:
+                depth += (rest[i] == "(") - (rest[i] == ")")
+                i += 1
+            operands = _HLO_OPERAND.findall(rest[op.end():i])
+        scope = _HLO_OP_NAME.search(rest)
+        calls = _HLO_CALLS.search(rest) if opcode == "fusion" else None
+        number = (_HLO_PARAMETER.search(rest) if opcode == "parameter"
+                  else None)
+        body[m.group(1)] = _Hlo(
+            operands, scope.group(1) if scope else None,
+            calls.group(1) if calls else None,
+            int(number.group(1)) if number else None)
+
+
+def instruction_phases(hlo_text: str) -> dict[str, Instruction]:
+    """``{instruction name: Instruction}`` for every instruction of a
+    compiled module's text (``compiled.as_text()``): the names a device
+    trace's op events carry.
+
+    ``scope``: the instruction's own ``op_name`` lies in a PS phase
+    (``ps_phase_of``). ``neighbours``: it does not (the compiler's own
+    instructions carry no ``op_name``, the partitioner's one without a
+    phase: the cast of the pulled vector, the push's all-reduce, copies
+    between memories), and the dataflow leaves one choice. An instruction
+    cannot run before the LATEST phase among its operands, and is made for
+    the EARLIEST phase among its users; where the two are one phase it
+    takes it, between two phases it stays without; where it has no operand
+    in a phase (it reads the step's arguments) it runs with its first
+    user, where no user, with its last operand. Operands and users without
+    a phase of their own are looked through to theirs, and a user that is
+    a fusion is looked INTO: what reads the value there is the fused
+    instruction on that parameter, which has kept its own ``op_name`` (the
+    slice of the pulled vector fused into the matmul that uses it is still
+    ``ps.pull/split``)."""
+    order = {p: i for i, p in enumerate(prof.PS_PHASES)}
+    out: dict[str, Instruction] = {}
+    readers: dict[str, dict] = {}    # computation -> {parameter no: phases}
+    for comp, body in _computations(hlo_text):
+        own = {n: ps_phase_of(v.scope) if v.scope else None
+               for n, v in body.items()}
+        users: dict[str, list] = {n: [] for n in body}
+        for n, v in body.items():
+            for k, o in enumerate(v.operands):
+                if o in users:
+                    users[o].append((n, k))
+        before: dict[str, frozenset] = {}    # phases that feed n
+        for n, v in body.items():
+            before[n] = frozenset().union(*(
+                (own[o],) if own[o] else before[o]
+                for o in v.operands if o in before))
+        after: dict[str, frozenset] = {}     # phases that read n
+        for n in reversed(body):
+            got = set()
+            for u, k in users[n]:
+                inside = readers.get(body[u].calls, {}).get(k)
+                if inside:
+                    got |= inside
+                elif own[u]:
+                    got.add(own[u])
+                else:
+                    got |= after[u]
+            after[n] = frozenset(got)
+        readers[comp] = {v.parameter: after[n] for n, v in body.items()
+                         if v.parameter is not None}
+        for n, v in body.items():
+            phase, part = phase_of(v.scope or "")
+            if own[n]:
+                out[n] = Instruction(own[n], phase, part, "scope")
+                continue
+            last = max(before[n], key=order.get, default=None)
+            first = min(after[n], key=order.get, default=None)
+            if last is None or first is None:
+                placed = last or first
+            else:
+                placed = last if last == first else None
+            out[n] = Instruction(placed, phase or placed, part,
+                                 "neighbours" if placed else None)
+    return out
+
+
+def account(text: str, memory: Optional[dict] = None) -> dict:
+    """What ``programs.json`` holds of one program, read from its compiled
+    text (``profiling.Program.text()``; ``memory`` is its ``memory``):
+    ``instructions`` (``instruction_phases``, each a row of
+    ``instruction_fields``) and ``collectives``, what the compiler BUILT
+    for pull and push: every collective of the text
+    (``comm_analysis.collective_ops``) as ``{"name", "kind", "shape",
+    "bytes", "ps_phase"}``."""
+    known = instruction_phases(text)
+    return {"memory": memory, "text_bytes": len(text),
+            "collectives": [
+                {"name": op.name, "kind": op.kind, "shape": op.shape,
+                 "bytes": op.bytes,
+                 "ps_phase": getattr(known.get(op.name), "ps_phase", None)}
+                for op in comm_analysis.collective_ops(text)],
+            "instruction_fields": list(Instruction._fields),
+            "instructions": {k: list(v) for k, v in known.items()}}
+
+
+def accounts() -> dict:
+    """``{program name: account(...)}`` of the programs this process's
+    steps have staged (``profiling.programs()``); reads and parses each
+    one's compiled text."""
+    return {name: account(p.text(), p.memory)
+            for name, p in prof.programs().items()}
+
+
+def dump_programs(path: str) -> None:
+    """Write ``accounts()`` as JSON: ``programs.json``."""
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump(accounts(), f)
+
+
 def kernel_of(op: Op) -> Optional[str]:
     """The kernel's name where ``op`` is a call of one of the program's
     Pallas kernels (``pl.pallas_call(name=...)`` names the instruction)."""
@@ -294,8 +475,40 @@ def attribute_gaps(gaps: Iterable, spans: Iterable[HostSpan]) -> dict:
     return out
 
 
-def reduce(tr: Trace, top: int = 15) -> dict:
-    """The numbers of the module's docstring from a ``Trace``."""
+def read_programs(log_dir: str) -> dict:
+    """``programs.json`` of ``log_dir`` (``dump_programs``), or
+    ``{}`` where there is none."""
+    try:
+        with open(os.path.join(log_dir, "programs.json")) as f:
+            return json.load(f)
+    except FileNotFoundError:
+        return {}
+
+
+def programs_summary(programs: dict) -> dict:
+    """Of each program's account its memory and, under each PS phase, the
+    collectives the compiler built: ``"all-gather bf16[4096] 8192 B
+    (all-gather.4)"``."""
+    out = {}
+    for name, acc in programs.items():
+        built: dict = {}
+        for c in acc["collectives"]:
+            built.setdefault(c["ps_phase"] or "no phase", []).append(
+                f"{c['kind']} {c['shape']} {c['bytes']} B ({c['name']})")
+        out[name] = {"memory": acc["memory"], "collectives": built}
+    return out
+
+
+def reduce(tr: Trace, top: int = 15, programs: Optional[dict] = None
+           ) -> dict:
+    """The numbers of the module's docstring from a ``Trace``;
+    ``programs`` is ``read_programs``' (an op without a scope of its own
+    is looked up there by its instruction's name)."""
+    known = {}
+    for acc in (programs or {}).values():
+        at = {f: i for i, f in enumerate(acc["instruction_fields"])}
+        for name, row in acc["instructions"].items():
+            known[name] = (row[at["phase"]], row[at["part"]])
     all_ops = [o for ops in tr.devices.values() for o in ops]
     if not all_ops:
         return {"error": "the trace holds no device op"}
@@ -316,7 +529,8 @@ def reduce(tr: Trace, top: int = 15) -> dict:
         by_phase: dict = {}
         loose = []          # what no phase names
         for o in ops:
-            phase, part = phase_of(o.scope)
+            phase, part = (phase_of(o.scope) if o.scope
+                           else known.get(o.name, (None, "fwd")))
             if phase is None:
                 loose.append(o)
             else:
@@ -353,6 +567,7 @@ def reduce(tr: Trace, top: int = 15) -> dict:
     calls_per = max(len(steps), 1) * n
     return {
         "source": tr.source, "devices": n,
+        "programs": programs_summary(programs or {}),
         "window_s": window, "busy_s": busy,
         "idle_share_pct": pct(idle, window),
         "named_share_pct": pct(named, busy),
@@ -374,7 +589,8 @@ def summarize(log_dir: str, *, top: int = 15) -> dict:
     path = latest_xplane(log_dir)
     if path is None:
         return {"error": f"no *.xplane.pb under {log_dir}"}
-    out = reduce(read_xplane(path), top=top)
+    out = reduce(read_xplane(path), top=top,
+                 programs=read_programs(log_dir))
     out["trace_file"] = path
     return out
 
@@ -384,7 +600,9 @@ def main(argv: Optional[list[str]] = None) -> None:
 
     ap = argparse.ArgumentParser(
         description="Time by named phase, kernel, step and host span "
-                    "from a captured profiler trace dir")
+                    "from a captured profiler trace dir, with the step's "
+                    "memory and collectives where programs.json lies "
+                    "beside the trace")
     ap.add_argument("log_dir")
     ap.add_argument("--top", type=int, default=15)
     args = ap.parse_args(argv)

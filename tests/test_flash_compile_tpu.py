@@ -146,3 +146,32 @@ def test_a_table_on_one_shard_compiles_to_no_collective(
     t, text = _compiled_step(topo, 1, comm)
     assert t.padded == t.num_keys == 3007
     assert not [ln for ln in text.splitlines() if _COLLECTIVE.search(ln)]
+
+
+def test_the_steps_account_places_what_the_chips_compiler_built(
+        topo, no_compile_cache):
+    """The text the chip's compiler writes for a step over four chips,
+    through the rule the step's own account uses
+    (``trace_analysis.instruction_phases``): the pull's all-gather by its
+    scope; the push's all-reduce, which the compiler leaves without an
+    ``op_name`` and whose scatter it fuses into Adam, and the shard's cast
+    before the gather, which reads the step's arguments alone, by their
+    neighbours; all four PS phases are there."""
+    from minips_tpu.utils import profiling as prof
+    from minips_tpu.utils.comm_analysis import collective_ops
+    from minips_tpu.utils.trace_analysis import instruction_phases
+
+    _, text = _compiled_step(topo, 4, "float32")
+    placed = instruction_phases(text)
+    assert {v.ps_phase for v in placed.values()} >= set(prof.PS_PHASES)
+    (pull,) = [placed[op.name] for op in collective_ops(text)
+               if op.kind == "all-gather"]
+    assert (pull.ps_phase, pull.how) == (prof.PULL, "scope")
+    # one variadic all-reduce: the gradient with the loss's mean beside
+    # it, its result a tuple in the chip's layouts
+    (push,) = [placed[op.name] for op in collective_ops(text)
+               if op.kind == "all-reduce" and op.has_dim(4096)]
+    assert (push.ps_phase, push.how) == (prof.PUSH, "neighbours")
+    casts = [v for n, v in placed.items() if n.startswith("convert")
+             and v.how == "neighbours"]
+    assert casts and {v.ps_phase for v in casts} <= set(prof.PS_PHASES)

@@ -9,6 +9,7 @@ import os
 import pytest
 
 from minips_tpu.utils import profiling as prof
+from minips_tpu.utils import trace_analysis
 from minips_tpu.utils.trace_analysis import (
     HostSpan,
     Op,
@@ -146,3 +147,169 @@ def test_roundtrip_real_capture(tmp_path):
     assert out["busy_s"] > 0 and out["unnamed_ops"], out
     assert len(out["steps"]) == 2
     assert out["steps"][1]["step"] == out["steps"][0]["step"] + 1
+
+
+# ------------------------------------ a compiled program's phases, from text
+# A compiled step's text in small: instructions as scheduled, callees first.
+# The compiler's own instructions carry no ``op_name``.
+_STEP = "jit(ps_dense_step)/shard_map"
+_HLO = f'''HloModule jit_ps_dense_step, is_scheduled=true
+
+%fused_slice (param_0.1: bf16[4096], param_1.1: f32[64]) -> f32[64] {{
+  %param_0.1 = bf16[4096]{{0}} parameter(0)
+  %param_1.1 = f32[64]{{0}} parameter(1)
+  %split.1 = bf16[64]{{0}} slice(%param_0.1), slice={{[0:64]}}, metadata={{op_name="{_STEP}/ps.pull/split" stack_frame_id=3}}
+  %convert.2 = f32[64]{{0}} convert(%split.1), metadata={{op_name="{_STEP}/ps.grad/jvp(lm.mlp)/convert_element_type"}}
+  ROOT %mul.3 = f32[64]{{0}} multiply(%convert.2, %param_1.1), metadata={{op_name="{_STEP}/ps.grad/jvp(lm.mlp)/mul"}}
+}}
+
+%fused_dot (param_0.3: bf16[4096], param_1.3: f32[64]) -> f32[64] {{
+  %param_0.3 = bf16[4096]{{0}} parameter(0)
+  %param_1.3 = f32[64]{{0}} parameter(1)
+  ROOT %dot.8 = f32[64]{{0}} dot(%param_0.3, %param_1.3), metadata={{op_name="{_STEP}/ps.grad/transpose(jvp(lm.mlp))/dot_general"}}
+}}
+
+%fused_adam (param_0.2: f32[4096], param_1.2: f32[1024]) -> f32[1024] {{
+  %param_0.2 = f32[4096]{{0}} parameter(0)
+  %param_1.2 = f32[1024]{{0}} parameter(1)
+  %dynamic-slice.4 = f32[1024]{{0}} dynamic-slice(%param_0.2), dynamic_slice_sizes={{1024}}
+  %div.5 = f32[1024]{{0}} multiply(%dynamic-slice.4, %dynamic-slice.4), metadata={{op_name="{_STEP}/ps.push/div"}}
+  %mul.6 = f32[1024]{{0}} multiply(%dynamic-slice.4, %param_1.2), metadata={{op_name="{_STEP}/ps.update/mul"}}
+  ROOT %add.7 = f32[1024]{{0}} add(%div.5, %mul.6), metadata={{op_name="{_STEP}/ps.update/add"}}
+}}
+
+ENTRY %main.9 (p_shard.1: f32[1024], batch.1: f32[64]) -> f32[1024] {{
+  %p_shard.1 = f32[1024]{{0}} parameter(0), metadata={{op_name="p_shard"}}
+  %batch.1 = f32[64]{{0}} parameter(1), metadata={{op_name="batch"}}
+  %convert.260 = bf16[1024]{{0:T(1024)(128)(2,1)}} convert(%p_shard.1)
+  %all-gather.4 = bf16[4096]{{0}} all-gather(%convert.260), channel_id=1, replica_groups={{{{0,1,2,3}}}}, dimensions={{0}}, metadata={{op_name="{_STEP}/ps.pull/all_gather" stack_frame_id=3}}
+  %reshape.8 = bf16[4096]{{0}} reshape(%all-gather.4)
+  %copy-start.1 = (bf16[4096], bf16[4096], u32[]) copy-start(%reshape.8)
+  %copy-done.1 = bf16[4096]{{0:S(1)}} copy-done(%copy-start.1)
+  %fusion.10 = f32[64]{{0}} fusion(%reshape.8, %batch.1), kind=kLoop, calls=%fused_slice, metadata={{op_name="{_STEP}/ps.grad/jvp(lm.mlp)/mul"}}
+  %fusion.11 = f32[64]{{0}} fusion(%copy-done.1, %fusion.10), kind=kOutput, calls=%fused_dot, metadata={{op_name="{_STEP}/ps.grad/transpose(jvp(lm.mlp))/mul"}}
+  %copy.12 = f32[64]{{0}} copy(%fusion.11)
+  %fusion.13 = f32[64]{{0}} fusion(%copy.12), kind=kLoop, calls=%fused_other, metadata={{op_name="{_STEP}/ps.grad/transpose(jvp(lm.mlp))/checkpoint/rematted_computation/lm.mlp/add"}}
+  %concatenate.27 = bf16[4096]{{0}} concatenate(%fusion.13), dimensions={{0}}, metadata={{op_name="{_STEP}/ps.push/concatenate"}}
+  %all-reduce.99 = f32[4096]{{0}} all-reduce(%concatenate.27), channel_id=2, replica_groups={{{{0,1,2,3}}}}, to_apply=%region_1
+  %bitcast.14 = f32[4096]{{0}} bitcast(%all-reduce.99)
+  %sparse.15 = f32[8]{{0}} fusion(%batch.1), kind=kLoop, calls=%fused_other, metadata={{op_name="jit(ps_fused_step)/ps.push.sparse/emb/sparse.dedup/sort"}}
+  %loose.16 = f32[8]{{0}} negate(%batch.1), metadata={{op_name="{_STEP}/reshape.976"}}
+  %alone.17 = f32[] constant(0)
+  ROOT %multiply_add_fusion = f32[1024]{{0}} fusion(%bitcast.14, %p_shard.1), kind=kLoop, calls=%fused_adam, metadata={{op_name="{_STEP}/ps.update/add" stack_frame_id=9}}
+}}
+'''
+
+
+@pytest.fixture(scope="module")
+def placed():
+    from minips_tpu.utils.trace_analysis import instruction_phases
+
+    return instruction_phases(_HLO)
+
+
+@pytest.mark.parametrize("name,want", [
+    # by its own op_name: the outermost PS phase, the innermost named one
+    ("all-gather.4", (prof.PULL, prof.PULL, "fwd", "scope")),
+    ("fusion.10", (prof.GRAD, prof.LM_MLP, "fwd", "scope")),
+    ("fusion.11", (prof.GRAD, prof.LM_MLP, "bwd", "scope")),
+    ("fusion.13", (prof.GRAD, prof.LM_MLP, "remat", "scope")),
+    ("multiply_add_fusion", (prof.UPDATE, prof.UPDATE, "fwd", "scope")),
+    ("sparse.15", (prof.PUSH, prof.SPARSE_DEDUP, "fwd", "scope")),
+    ("split.1", (prof.PULL, prof.PULL, "fwd", "scope")),    # in a fusion
+    # between two of one phase it takes it
+    ("copy.12", (prof.GRAD, prof.GRAD, "fwd", "neighbours")),
+    # it reads the step's arguments alone: with its first user
+    ("convert.260", (prof.PULL, prof.PULL, "fwd", "neighbours")),
+    # a fusion is looked INTO: what reads the vector there is the slice,
+    # which kept ``ps.pull/split``, not the fusion's own ``ps.grad``
+    ("reshape.8", (prof.PULL, prof.PULL, "fwd", "neighbours")),
+    # fed by the push; its scatter, fused into Adam, feeds ``ps.push/div``
+    # first and ``ps.update/mul`` after it
+    ("all-reduce.99", (prof.PUSH, prof.PUSH, "fwd", "neighbours")),
+    ("bitcast.14", (prof.PUSH, prof.PUSH, "fwd", "neighbours")),
+    ("dynamic-slice.4", (prof.PUSH, prof.PUSH, "fwd", "neighbours")),
+    # between two phases it stays without: a copy of the pulled vector
+    # into the memory the gradient's matmul reads it from
+    ("copy-start.1", (None, None, "fwd", None)),
+    ("copy-done.1", (None, None, "fwd", None)),
+    # nothing around it has a phase
+    ("alone.17", (None, None, "fwd", None)),
+])
+def test_an_instruction_is_placed_by_scope_or_by_its_neighbours(
+        placed, name, want):
+    assert tuple(placed[name]) == want
+
+
+def test_an_op_name_without_a_phase_is_placed_by_its_neighbours(placed):
+    """The partitioner names its own instructions ``.../reshape.976``:
+    that is no scope; this one reads an argument and nothing reads it."""
+    assert placed["loose.16"].how is None
+    assert set(placed) >= {"param_0.1", "mul.6", "batch.1"}   # every one
+
+
+def test_ps_phase_of_takes_the_outermost_of_the_four():
+    from minips_tpu.utils.trace_analysis import phase_of, ps_phase_of
+
+    path = ("jit(ps_dense_step)/ps.grad/transpose(jvp(ps.grad))/jvp()/"
+            "checkpoint/rematted_computation/lm.attn/flash_fwd/pallas_call")
+    assert ps_phase_of(path) == prof.GRAD
+    assert phase_of(path) == (prof.LM_ATTN, "remat")
+    assert ps_phase_of("jit(ps_fused_step)/ps.push.dense/add") == prof.PUSH
+    assert ps_phase_of(
+        "jit(ps_fused_step)/ps.push.sparse/emb/sparse.adagrad_sorted/mul"
+    ) == prof.PUSH
+    assert ps_phase_of("jit(ps_dense_step)/shard_map/psum_invariant") is None
+    assert ps_phase_of("jit(f)/lm.mlp/dot_general") is None
+    assert ps_phase_of("") is None
+
+
+def test_collective_ops_carry_their_instructions_names():
+    from minips_tpu.utils.comm_analysis import collective_ops
+
+    ops = collective_ops(_HLO)
+    assert [(o.name, o.kind, o.shape, o.bytes) for o in ops] == [
+        ("all-gather.4", "all-gather", "bf16[4096]", 8192),
+        ("all-reduce.99", "all-reduce", "f32[4096]", 16384)]
+
+
+def test_a_cpu_trace_is_named_through_the_programs_account(tmp_path, mesh4):
+    """A CPU trace's ops carry ``hlo_op`` names and no scope: with
+    ``programs.json`` beside the trace they are reported by phase, and
+    the step's memory and the collectives built under each PS phase are
+    printed; without it they are unnamed, as before."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from minips_tpu.parallel.mesh import DATA_AXIS
+    from minips_tpu.tables.dense import DenseTable
+
+    def grad_fn(p, b):
+        return jax.value_and_grad(
+            lambda p: jnp.mean((b["x"] @ p["w"]) ** 2))(p)
+
+    table = DenseTable({"w": jnp.full((48, 3), 0.48)}, mesh4,
+                       updater="adam", lr=0.1)
+    step = table.make_step(grad_fn)
+    batch = {"x": jax.device_put(jnp.ones((8, 48)),
+                                 NamedSharding(mesh4, P(DATA_AXIS)))}
+    prof.clear()
+    table.step_inplace(step, batch).block_until_ready()
+    with prof.profile_trace(str(tmp_path)):
+        for _ in range(2):
+            table.step_inplace(step, batch).block_until_ready()
+    bare = summarize(str(tmp_path))
+    assert bare["source"] == "host" and bare["programs"] == {}
+    assert not bare["phases"] and bare["unnamed_ops"]
+    trace_analysis.dump_programs(str(tmp_path / "programs.json"))
+    out = summarize(str(tmp_path))
+    assert {r["phase"] for r in out["phases"]} >= {
+        prof.PULL, prof.PUSH, prof.UPDATE}
+    assert out["named_share_pct"] > bare["named_share_pct"] == 0.0
+    acc = out["programs"][prof.DENSE_STEP_FN]
+    assert acc["memory"]["total_bytes"] > 0
+    assert acc["collectives"][prof.PULL][0].startswith("all-gather f32[")
+    assert acc["collectives"][prof.PUSH][0].split()[0] in (
+        "reduce-scatter", "all-reduce")
