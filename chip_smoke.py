@@ -29,6 +29,12 @@ means:
   128, no position signal) at published widths, T 1,024, against the
   benchmark's plain float32 reference, which is the token-by-token
   recurrence.
+- ``laguna``: one forward and backward of the window/full hybrid
+  (``models/laguna.py``) cut to a window layer (the windowed flash kernels
+  at 36 heads over 4 key/value heads, window 512) and a full layer (24
+  heads, YaRN on half the channels), each with 8 of 256 experts at top-10
+  beside a shared one and the per-head gate, at published widths, T 4,096,
+  against the benchmark's plain float32 reference.
 
 It fails (non-zero exit, no result line) if JAX finds no TPU, if a loss is
 non-finite or does not fall, if the LM step holds fewer than three compiled
@@ -541,6 +547,75 @@ def _leg_olmo(on_tpu: bool, here: str) -> dict:
             "state_absmax": seen["state_absmax"].tolist()}
 
 
+def _leg_laguna(on_tpu: bool, here: str) -> dict:
+    """One forward and backward of the window/full hybrid cut to a window
+    layer and a full layer, both with experts, bfloat16 worker math as the
+    cell runs it (the windowed kernels walk two majors of 2,048 rows at
+    T 4,096), against the plain float32 reference the benchmark keeps
+    (published widths and the cell's head share on the chip, toy widths
+    off it), and the observer's reading of the routing and the gates."""
+    import jax
+    import jax.numpy as jnp
+
+    from minips_tpu.models import laguna
+    from minips_tpu.tables.dense import cast_floating
+
+    bench = os.path.join(here, "bench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from benchlib.reference import laguna_ref
+
+    with open(os.path.join(bench, "configs", "laguna-s-2.1.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=2,
+                      layer_types=[laguna.WINDOW, laguna.FULL],
+                      mlp_layer_types=["sparse", "sparse"],
+                      mlp_only_layers=[], gating_types=["per_head"] * 2,
+                      num_attention_heads_per_layer=[36, 24])
+    B, T = 1, 4096
+    if not on_tpu:
+        config.update(
+            hidden_size=32, head_dim=16, num_key_value_heads=2,
+            num_attention_heads_per_layer=[6, 4], moe_intermediate_size=16,
+            shared_expert_intermediate_size=16, sliding_window=8,
+            vocab_size=128, head_chunk=16, reference_query_rows=16)
+        T = 64
+    m = laguna.from_config(config)
+    params = laguna.init(jax.random.PRNGKey(0), m)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, T + 1), 0, m.vocab)
+    cd = jnp.bfloat16
+    how = dict(compute_dtype=cd, attn_impl="flash",
+               head_chunk=int(config["head_chunk"]))
+    bias = jnp.zeros((m.routers, m.experts), jnp.float32)
+    (loss, grads, _), seen = jax.jit(lambda p, t: (
+        laguna.grad_fn(cast_floating(p, cd), {"tokens": t}, bias, m, **how),
+        laguna.routing_stats(p, {"tokens": t}, bias, m, **how)))(params,
+                                                                 toks)
+    seen = jax.device_get(seen)
+    z = laguna_ref._sizes(config)
+    want, want_g = jax.jit(jax.value_and_grad(
+        lambda p, t: laguna_ref.loss_sum(p, t, bias, z, False)[0]))(params,
+                                                                    toks)
+    want = float(want) / (B * T)
+    _check(math.isfinite(float(loss)) and abs(float(loss) - want)
+           < 2e-3 * want, f"laguna: loss {float(loss)} against the "
+           f"reference's {want}")
+    worst = _grad_norm_gap(grads, want_g, B * T)
+    _check(worst < 0.05, f"laguna: a leaf's gradient norm is {worst:.4f} "
+           "off the reference's (tolerance 0.05)")
+    gates = seen["gate_mean"]
+    _check(all(0.0 < float(g) < 1.0 for g in gates)
+           and int(seen["tokens_held"].sum()) > 0,
+           f"laguna: the observer reads {seen}")
+    return {"shape": {"B": B, "T": T, "dim": m.dim, "heads": list(m.heads),
+                      "kv_heads": m.kv_heads, "window": m.window,
+                      "layers": list(m.layer_types)},
+            "loss": float(loss), "reference_loss": want,
+            "lm_nll": float(seen["lm_nll"]),
+            "grad_norm_worst_gap": round(worst, 5),
+            "tokens_held": seen["tokens_held"].tolist(),
+            "gate_mean": gates.tolist()}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse-cpu", action="store_true",
@@ -589,6 +664,8 @@ def main() -> int:
     legs["joyai"] = _leg_joyai(on_tpu, here)
     gc.collect()
     legs["olmo"] = _leg_olmo(on_tpu, here)
+    gc.collect()
+    legs["laguna"] = _leg_laguna(on_tpu, here)
     _check(not native_lib.loaded_libs(),
            f"a native library was loaded: {native_lib.loaded_libs()}")
 

@@ -29,12 +29,15 @@ file of published keys instead of the flags, by the file's ``model_type``
 is told which experts it holds), the latent-attention expert decoder of
 ``models/mla_moe.py`` (MLA, sigmoid top-k experts beside a shared one, a
 leading dense layer, an untied head, a multi-token-prediction module)
-or the hybrid of gated delta-rule linear-attention and full-attention
-layers of ``models/olmo_hybrid.py``, trained through the same
-``DenseTable.make_step``;
-``bench/configs/zaya1-8b.json``, ``bench/configs/joyai-llm-flash.json``
-and ``bench/configs/olmo-hybrid-7b.json`` are such files, and the benchmark's adapters call :func:`model_dp_step`
-as ``run`` does.
+the hybrid of gated delta-rule linear-attention and full-attention
+layers of ``models/olmo_hybrid.py`` or the window/full hybrid expert
+decoder of ``models/laguna.py`` (windowed and full attention at their own
+head counts, a per-head output gate, YaRN, sigmoid top-k experts beside a
+shared one), trained through the same ``DenseTable.make_step``;
+``bench/configs/zaya1-8b.json``, ``bench/configs/joyai-llm-flash.json``,
+``bench/configs/olmo-hybrid-7b.json`` and
+``bench/configs/laguna-s-2.1.json`` are such files, and the benchmark's
+adapters call :func:`model_dp_step` as ``run`` does.
 
 Usage: python -m minips_tpu.apps.lm_example --num_iters 200 --layout sp
        python -m minips_tpu.apps.lm_example --layout tp --tp 2
@@ -44,6 +47,8 @@ Usage: python -m minips_tpu.apps.lm_example --num_iters 200 --layout sp
            --model_config bench/configs/joyai-llm-flash.json
        python -m minips_tpu.apps.lm_example --seq_len 8192 --batch_size 4 \
            --model_config bench/configs/olmo-hybrid-7b.json     # 4 chips
+       python -m minips_tpu.apps.lm_example --seq_len 16384 --batch_size 1 \
+           --model_config bench/configs/laguna-s-2.1.json
 """
 
 from __future__ import annotations
@@ -62,7 +67,7 @@ from minips_tpu.apps.common import app_main, log_tables_built
 from minips_tpu.core.config import Config, TableConfig, TrainConfig
 from minips_tpu.data import synthetic
 from minips_tpu.data.loader import BatchIterator
-from minips_tpu.models import mla_moe, olmo_hybrid
+from minips_tpu.models import laguna, mla_moe, olmo_hybrid
 from minips_tpu.models import transformer as tfm
 from minips_tpu.models import zaya
 from minips_tpu.parallel.mesh import DATA_AXIS, make_mesh
@@ -82,7 +87,8 @@ MODEL = dict(vocab=256, dim=64, heads=4, depth=2, max_len=1024)
 # balancing bias from step to step, ``observe`` where it carries nothing;
 # a file without the key is ZAYA's, as before the key was read
 CONFIG_MODELS = {"zaya": zaya, mla_moe.MODEL_TYPE: mla_moe,
-                 olmo_hybrid.MODEL_TYPE: olmo_hybrid}
+                 olmo_hybrid.MODEL_TYPE: olmo_hybrid,
+                 laguna.MODEL_TYPE: laguna}
 
 
 def _flags(parser):
@@ -324,8 +330,16 @@ def model_dp_step(config: dict, mesh, params, first_batch, *, updater: str,
         stats = jax.jit(jax.shard_map(
             run_as_the_step(model.observe), mesh=mesh,
             in_specs=(P(), P(DATA_AXIS)), out_specs=P()))
-    bias = model.centred_bias(
-        lambda b: stats(params, first_batch, b), m) if routed else None
+    bias = None
+    if routed:
+        # the observer is handed here what the steps hand it later, the
+        # pulled weights and the carried bias replicated on the mesh, so
+        # that it compiles once
+        rep = NamedSharding(mesh, P())
+        placed = jax.device_put(params, rep)
+        bias = model.centred_bias(
+            lambda b: stats(placed, first_batch, jax.device_put(b, rep)), m)
+        del placed
     table = DenseTable(params, mesh, updater=updater, lr=lr, name=name)
     step = table.make_step(
         run_as_the_step(model.grad_fn), batch_spec=P(DATA_AXIS),
@@ -377,12 +391,14 @@ def _run_model_config(cfg, args, mesh, layout, seq_len):
     def routing_metrics() -> dict:
         """The observer's counters of the last batch fed, at
         ``log_every``: the routing and, where the model has a prediction
-        module beside its main head, the two losses apart."""
+        module beside its main head, the two losses apart; where it has
+        per-head output gates, their mean by layer and by layer kind."""
         with prof.span(prof.LOOP_READBACK):
             st = stats(table.pull(), last["batch"], table.state)
             st = jax.device_get({k: st[k] for k in (
                 "tokens_held", "absent_share", "load_max_over_mean",
-                "lm_nll", "mtp_nll") if k in st})
+                "lm_nll", "mtp_nll", "gate_mean", "gate_mean_window",
+                "gate_mean_full") if k in st})
             prof.counter(prof.MOE_TOKENS_HELD,
                          int(st["tokens_held"].sum()))
             prof.counter(prof.MOE_LOAD_MAX_OVER_MEAN,
@@ -393,10 +409,16 @@ def _run_model_config(cfg, args, mesh, layout, seq_len):
                 if key in st:
                     losses[key] = float(st[key])
                     prof.counter(name, losses[key])
+            for kind in ("window", "full"):
+                if f"gate_mean_{kind}" in st:
+                    prof.counter(f"{prof.ATTN_GATE_MEAN}.{kind}",
+                                 float(st[f"gate_mean_{kind}"]))
+        gates = {"attn_gate_mean": st["gate_mean"].tolist()} \
+            if "gate_mean" in st else {}
         return {"moe_tokens_held": st["tokens_held"].tolist(),
                 "moe_absent_share": st["absent_share"].tolist(),
                 "moe_load_max_over_mean":
-                    st["load_max_over_mean"].tolist(), **losses}
+                    st["load_max_over_mean"].tolist(), **losses, **gates}
 
     def linattn_metrics() -> dict:
         """The same for a model that carries no state (``observe``): the

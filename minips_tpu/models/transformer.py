@@ -364,17 +364,23 @@ def _rope_wrap(attn_fn, pos):
                                    rope_rotate(k, pos), v)
 
 
-def _attn_fn(attn_impl: str):
+def _attn_fn(attn_impl: str, window=None):
     """Causal attention implementation by name: ``reference`` (full [T, T]
     scores, XLA-fused) or ``flash`` (ops/flash_attention.py — Pallas kernel
-    on TPU, exact blockwise scan elsewhere; O(T) memory either way)."""
+    on TPU, exact blockwise scan elsewhere; O(T) memory either way).
+    ``window`` W: the query at t sees the keys t - W < s <= t alone (the
+    windowed kernels; ``reference`` masks its full scores)."""
+    from minips_tpu.ops.flash_attention import (blockwise_attention,
+                                                flash_attention)
     if attn_impl == "flash":
-        from minips_tpu.ops.flash_attention import flash_attention
-
-        return lambda q, k, v: flash_attention(q, k, v, causal=True)
+        return lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                               window=window)
     if attn_impl != "reference":
         raise ValueError(f"unknown attn_impl {attn_impl!r} "
                          "(expected 'reference' or 'flash')")
+    if window is not None:      # one block of keys: the full scores
+        return lambda q, k, v: blockwise_attention(
+            q, k, v, causal=True, block_k=k.shape[1], window=window)
     return lambda q, k, v: reference_attention(q, k, v, causal=True)
 
 

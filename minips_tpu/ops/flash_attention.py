@@ -118,6 +118,7 @@ def blockwise_attention(
     q_off=0,
     k_off=0,
     return_lse: bool = False,
+    window: Optional[int] = None,
 ):
     """Exact attention, scanning K/V in chunks of ``block_k``.
 
@@ -128,9 +129,12 @@ def blockwise_attention(
     ``q_off``/``k_off`` shift causal masking to global positions (the ring
     path passes each shard's sequence offset); ``return_lse=True`` also
     returns the per-row logsumexp [B, Tq, H] for shard merging. This is
-    the pure-jnp twin of the Pallas kernels.
+    the pure-jnp twin of the Pallas kernels. ``window`` (with ``causal``)
+    keeps, for the query at t, the keys t - window < s <= t alone.
     """
     B, Tq, H, D = q.shape
+    if window is not None and not causal:
+        raise ValueError("a window bounds causal attention: causal=True")
     k, v = _expand_kv(q, k, v)   # GQA: exact repeat on this oracle path
     Tk, Dv = k.shape[1], v.shape[3]   # v's head size is its own
     if scale is None:
@@ -156,6 +160,9 @@ def blockwise_attention(
             keep = k_local[None, :] < Tk  # padding keys attend to nothing
             if causal:
                 keep = keep & (q_pos[:, None] >= (k_off + k_local)[None, :])
+            if window is not None:
+                keep = keep & (q_pos[:, None]
+                               < (k_off + k_local)[None, :] + window)
             s = jnp.where(keep[None, :, :, None], s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=2))        # [B, Tq, H]
         p = jnp.exp(s - m_new[:, :, None, :])
@@ -275,16 +282,33 @@ def _diag_groups(tq: int, tk: int) -> int:
 
 
 def computed_share(Tq: int, Tk: int, tile_q: int, tile_k: int,
-                   cut: bool = False) -> float:
+                   cut: bool = False, window: Optional[int] = None) -> float:
     """Share of the Tq x Tk score matrix inside tiles that causal masking
     (offsets 0) leaves live — what the kernels compute; 0.5 is the floor.
-    ``cut``: the tiles on the diagonal count their live groups only."""
+    ``cut``: the tiles on the diagonal count their live groups only.
+    ``window``: the tiles wholly before a Q tile's band are dead too, and
+    the diagonal is cut only where the window spans a tile."""
     live = sum(min(-(-((i + 1) * tile_q) // tile_k), Tk // tile_k)
+               - (0 if window is None else
+                  min(max(i * tile_q - window + 1, 0) // tile_k,
+                      Tk // tile_k))
                for i in range(Tq // tile_q))
-    n = _diag_groups(tile_q, tile_k) if cut else 0
+    n = _diag_groups(tile_q, tile_k) \
+        if cut and (window is None or window >= tile_q) else 0
     if n:   # min(Tq, Tk) / tile tiles lie on the diagonal
         live -= min(Tq, Tk) // tile_q * (n - 1) / (2 * n)
     return live * tile_q * tile_k / (Tq * Tk)
+
+
+def window_live_share(Tq: int, Tk: int, window: int,
+                      block_q: Optional[int] = None,
+                      block_k: Optional[int] = None) -> float:
+    """Of the (Q tile, K tile) pairs the causal forward kernel visits at a
+    shape, the share the windowed one visits (``flash.win_live_share``)."""
+    plan = flash_plan(Tq, Tk, 8, 2, block_q, block_k)
+    tq, tk = plan.tile_q, plan.tile_k
+    return computed_share(Tq, Tk, tq, tk, window=window) \
+        / computed_share(Tq, Tk, tq, tk)
 
 
 def _bwd_vmem(rows_q, major_q, major_k, tq, tk, D, Dv, itemsize):
@@ -303,7 +327,8 @@ def _bwd_vmem(rows_q, major_q, major_k, tq, tk, D, Dv, itemsize):
 def flash_plan(Tq: int, Tk: int, D: int, itemsize: int,
                block_q: Optional[int] = None,
                block_k: Optional[int] = None,
-               Dv: Optional[int] = None, g: int = 1) -> FlashPlan:
+               Dv: Optional[int] = None, g: int = 1,
+               window: Optional[int] = None) -> FlashPlan:
     """Tiles and resident extents for a shape: pure, and the one place the
     kernels take them from. ``block_q`` / ``block_k``, where given, are
     upper bounds on every tile. ``D`` is the head size of q and k, ``Dv``
@@ -311,14 +336,17 @@ def flash_plan(Tq: int, Tk: int, D: int, itemsize: int,
     and 128); the wider of the two is what a resident operand is sized
     by. ``g`` is the number of q-heads a kv head serves: the backward
     keeps the dQ of a whole group, ``g * span_q`` rows, in VMEM, and
-    ``span_q`` is the whole sequence wherever that fits ``_VMEM_BYTES``."""
+    ``span_q`` is the whole sequence wherever that fits ``_VMEM_BYTES``.
+    Under a ``window`` the backward's tiles are the forward's: a band as
+    narrow as a tile leaves most of a larger one dead."""
     Dv = Dv or D
     wide = max(D, Dv)
     cap_q, cap_k = block_q or Tq, block_k or Tk
     tq = _pick_tile(Tq, min(cap_q, _FWD_TILE[0]))
     tk = _pick_tile(Tk, min(cap_k, _FWD_TILE[1]))
-    bq = _pick_tile(Tq, min(cap_q, _BWD_TILE[0]), tq)   # whole lse rows
-    bk = _pick_tile(Tk, min(cap_k, _BWD_TILE[1]))
+    bwd = _BWD_TILE if window is None else _FWD_TILE
+    bq = _pick_tile(Tq, min(cap_q, bwd[0]), tq)   # whole lse rows
+    bk = _pick_tile(Tk, min(cap_k, bwd[1]))
     major_q = _pick_major(Tq, bq, wide * itemsize, _RESIDENT_BYTES)
     major_k = _pick_major(Tk, math.lcm(tk, bk), wide * itemsize,
                           _RESIDENT_BYTES)
@@ -329,8 +357,9 @@ def flash_plan(Tq: int, Tk: int, D: int, itemsize: int,
                default=major_q)
     return FlashPlan(
         tq, tk, bq, bk, major_q, major_k,
-        computed_share(Tq, Tk, tq, tk),
-        computed_share(Tq, Tk, bq, bk, cut=True), span, vmem(g * span))
+        computed_share(Tq, Tk, tq, tk, window=window),
+        computed_share(Tq, Tk, bq, bk, cut=True, window=window), span,
+        vmem(g * span))
 
 
 def _scale_folds(scale: float) -> bool:
@@ -402,6 +431,75 @@ def _live_q_tiles(masked, k_lo, q_base, tq, tk, nq):
     return lo, _cdiv_clip(k_lo + tk - 1 - q_base, tq, nq)
 
 
+# ------------------------------------------------------------ the window
+#
+# A window of W keeps, for the query at t, the keys t - W < s <= t. The
+# windowed kernels visit only the band: on the grid, a Q major (forward) or
+# K major (backward) walks the majors of the other side that its band
+# reaches, its index map clamped so that a grid step past the band names
+# the block the step before it named (no DMA) and computes nothing; inside
+# a major, the tiles wholly outside the band are not visited and those its
+# edges cross are masked. Every query row must see at least one key (true
+# of self-attention at equal offsets): a Q major that sees none is never
+# visited by the backward, and its dQ is not written.
+
+
+def _window_k_tiles(live, window, q_lo, k_base, tq, tk, nk):
+    """For the Q tile whose first global row is q_lo, over the K tiles of a
+    major: masked [lo, a), kept whole [a, b), masked [b, hi); every range
+    empty on a dead grid step (``live`` false)."""
+    n = jnp.where(live, nk, 0)
+    hi = _cdiv_clip(q_lo + tq - k_base, tk, n)
+    lo = jnp.minimum(jnp.clip(jax.lax.div(
+        jnp.maximum(q_lo - window + 1 - k_base, 0), tk), 0, n), hi)
+    a = jnp.clip(_cdiv_clip(q_lo + tq - window - k_base, tk, n), lo, hi)
+    full = jnp.clip(jax.lax.div(jnp.maximum(q_lo - k_base + 1, 0), tk), 0, n)
+    return lo, a, jnp.clip(full, a, hi), hi
+
+
+def _window_q_tiles(window, full, k_lo, q_base, tq, tk, nq):
+    """For the K tile whose first global column is k_lo, past the tiles the
+    diagonal crosses (which end at ``full``): Q tiles [full, b) are kept
+    whole, [b, hi) are crossed by the window's far edge, the rest dead."""
+    hi = jnp.maximum(_cdiv_clip(k_lo + tk + window - 1 - q_base, tq, nq),
+                     full)
+    b = jnp.clip(jax.lax.div(jnp.maximum(k_lo + window - q_base, 0), tq),
+                 full, hi)
+    return b, hi
+
+
+def _band(lo, hi, size, n):
+    """(first, last) of the blocks of ``size`` (n of them) that hold the
+    positions [lo, hi], clipped to the blocks there are; and whether any
+    position of [lo, hi] lies in them."""
+    first = jax.lax.div(jnp.clip(lo, 0, n * size - 1), size)
+    last = jax.lax.div(jnp.clip(hi, 0, n * size - 1), size)
+    return first, last, (hi >= 0) & (lo <= n * size - 1)
+
+
+def _fwd_band(i, j, q_off, k_off, *, bq, bk, nkm, window):
+    """The forward's grid step (Q major i, band step j): (the K major it
+    works on, whether it is live)."""
+    q_lo = q_off + i * bq - k_off
+    first, last, some = _band(q_lo - window + 1, q_lo + bq - 1, bk, nkm)
+    return jnp.minimum(first + j, last), some & (first + j <= last)
+
+
+def _bwd_band(j, t, q_off, k_off, *, bq, bk, nqm, nkm, nb, window):
+    """The backward's grid step (K major j, inner step t = the group's
+    q-head times ``nb`` + band step): (q-head in the group, the Q major it
+    works on, whether it is live, whether the Q major's dQ is whole after
+    it: j holds the last key the major's queries see)."""
+    head, b = jax.lax.div(t, nb), jax.lax.rem(t, nb)
+    k_lo = k_off + j * bk - q_off
+    first, last, some = _band(k_lo, k_lo + bk + window - 2, bq, nqm)
+    qm = jnp.minimum(first + b, last)
+    live = some & (first + b <= last)
+    done = jax.lax.div(jnp.clip(q_off + qm * bq + bq - 1 - k_off, 0,
+                                nkm * bk - 1), bk) == j
+    return head, qm, live, live & done
+
+
 def _loop(lo, hi, tile, carry, **kw):
     if isinstance(lo, int) and isinstance(hi, int) and lo >= hi:
         return carry      # statically empty: costs nothing
@@ -410,11 +508,11 @@ def _loop(lo, hi, tile, carry, **kw):
 
 def _crossing(lo, hi, off, n_tiles, size, groups, tile, diag, carry):
     """The tiles [lo, hi) of a sweep (tiles of ``size``) that the diagonal
-    crosses. ``off`` is where the diagonal enters the swept axis (this
-    side's first position minus the swept side's base): where tile lo
-    starts exactly there (and is resident) it is the only crossing tile,
-    and ``diag`` computes its live groups; else every crossing tile is
-    computed whole, and masked."""
+    (or a window's far edge) crosses. ``off`` is where it enters the swept
+    axis (this side's first position minus the swept side's base): where
+    tile lo starts exactly there (and is resident) it is the only crossing
+    tile, and ``diag`` computes its live groups; else every crossing tile
+    is computed whole, and masked."""
     masked = functools.partial(_loop, lo, hi, tile, mask_it=True)
     if not groups:
         return masked(carry)
@@ -431,27 +529,40 @@ def _span(m, size):
 
 
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                  vt_s, *scratch, scale, masked, tq, tk):
+                  vt_s, *scratch, scale, masked, tq, tk, window=None,
+                  band=None):
     # Grid (B, H, Q majors, K majors), K majors sequential. Blocks: q/o
     # [1, 1, major_q, D]; k/v [1, 1, major_k, D], resident across the Q
     # tiles; lse [1, 1, 1, major_q // tq, tq], one lane-dense row a Q tile.
     # Scores are [tk, tq] = k q^T; the online-softmax state (m, l rows
     # [1, tq]; acc^T [Dv, tq] += v^T p^T) is the K sweep's loop carry,
     # float32, and the output tile is transposed back once, at the end.
-    # v and the output have v's own head size Dv, q and k theirs.
+    # v and the output have v's own head size Dv, q and k theirs. Under a
+    # ``window`` the last grid axis walks the Q major's band, and ``band``
+    # gives each of its steps its K major and whether it is live.
     bq, Dv = q_ref.shape[2], v_ref.shape[3]
     bk = k_ref.shape[2]
     kmaj, num_major = pl.program_id(3), pl.num_programs(3)
     q_base = qoff_ref[0] + pl.program_id(2) * bq
+    step, live = kmaj, None
+    if band is not None:
+        kmaj, live = band(pl.program_id(2), step, qoff_ref[0], koff_ref[0])
     k_base = koff_ref[0] + kmaj * bk
     diff = _tile_diff(tk, tq) if masked else None
-    for c in range(bk // tk):       # v^T of the resident V, tile by tile
-        vt_s[c] = v_ref[0, 0, c * tk:(c + 1) * tk, :].T
+
+    def transpose_v():
+        for c in range(bk // tk):   # v^T of the resident V, tile by tile
+            vt_s[c] = v_ref[0, 0, c * tk:(c + 1) * tk, :].T
+
+    if band is None:
+        transpose_v()
+    else:
+        pl.when(live)(transpose_v)
 
     if scratch:   # the state crosses the K majors in VMEM
         m_s, l_s, acc_s = scratch
 
-        @pl.when(kmaj == 0)
+        @pl.when(step == 0)
         def _init():
             m_s[:] = jnp.full_like(m_s, _NEG_INF)
             l_s[:] = jnp.zeros_like(l_s)
@@ -467,7 +578,10 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         def k_tile(j, carry, mask_it):
             m, l, acc = carry
             s = _scores(k_ref[0, 0, _rows(j, tk), :], qb, scale)
-            if mask_it:
+            if mask_it and window is not None:
+                d = q_lo - (k_base + j * tk)
+                s = jnp.where((diff <= d) & (diff > d - window), s, _NEG_INF)
+            elif mask_it:
                 s = jnp.where(diff <= q_lo - (k_base + j * tk), s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -484,9 +598,16 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             carry = (jnp.full((1, tq), _NEG_INF, jnp.float32),
                      jnp.zeros((1, tq), jnp.float32),
                      jnp.zeros((Dv, tq), jnp.float32))
-        full, live = _live_k_tiles(masked, q_lo, k_base, tq, tk, bk // tk)
-        carry = _loop(0, full, k_tile, carry, mask_it=False)
-        carry = _loop(full, live, k_tile, carry, mask_it=True)
+        if window is None:
+            full, hi = _live_k_tiles(masked, q_lo, k_base, tq, tk, bk // tk)
+            carry = _loop(0, full, k_tile, carry, mask_it=False)
+            carry = _loop(full, hi, k_tile, carry, mask_it=True)
+        else:
+            lo, a, b, hi = _window_k_tiles(live, window, q_lo, k_base, tq,
+                                           tk, bk // tk)
+            carry = _loop(lo, a, k_tile, carry, mask_it=True)
+            carry = _loop(a, b, k_tile, carry, mask_it=False)
+            carry = _loop(b, hi, k_tile, carry, mask_it=True)
         m, l, acc = carry
 
         def write():
@@ -499,7 +620,7 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
         if scratch:
             m_s[t], l_s[t], acc_s[t] = m, l, acc
-            pl.when(kmaj == num_major - 1)(write)
+            pl.when(step == num_major - 1)(write)
         else:
             write()
 
@@ -536,12 +657,15 @@ def _head_specs(bq, bk, D, g):
 
 
 def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
-                   interpret):
+                   interpret, window=None):
     """[B, T, H, D] in/out; kernel runs on [B, H, T, D]. K/V may carry
     fewer heads (GQA): each q-head's K/V block index maps onto kv head
     h // g — the repeat never materializes, so KV HBM traffic shrinks by
     the group factor. Returns (out, lse [B, H, Q majors, tiles, tile_q]):
     the logsumexp row-major over the sequence, a lane-dense row a tile."""
+    if window is not None:
+        return _flash_forward_window(q, k, v, q_off, k_off, scale, block_q,
+                                     block_k, interpret, window)
     B, Tq, H, D = q.shape
     g = gqa_group_size(H, k.shape[2])
     Tk, Dv = k.shape[1], v.shape[3]
@@ -580,9 +704,74 @@ def _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
     return out.transpose(0, 2, 1, 3), lse
 
 
+def _band_steps(major: int, other: int, window: int, n_other: int) -> int:
+    """Grid steps of a band: the most majors of ``other`` positions (of
+    ``n_other``) that ``major`` consecutive positions and a window reach,
+    major + window - 1 positions at any alignment."""
+    return min(n_other, -(-(major + window - 2) // other) + 1)
+
+
+def _flash_forward_window(q, k, v, q_off, k_off, scale, block_q, block_k,
+                          interpret, window):
+    """``_flash_forward`` under a window: the grid's last axis walks a Q
+    major's band of K majors (``_fwd_band``), the offsets ride in as
+    scalar prefetch so that the K/V index map can follow them, and the
+    kernel is ``flash_fwd_win``. Records ``flash.win_live_share`` as it is
+    built."""
+    B, Tq, H, D = q.shape
+    g = gqa_group_size(H, k.shape[2])
+    Tk, Dv = k.shape[1], v.shape[3]
+    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k, Dv,
+                      window=window)
+    tq, tk, bq, bk = plan.tile_q, plan.tile_k, plan.major_q, plan.major_k
+    prof.counter(prof.FLASH_WIN_LIVE_SHARE,
+                 window_live_share(Tq, Tk, window, block_q, block_k))
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    vma = _vma_of(q, k, v, q_off, k_off)
+    nkm = Tk // bk
+    nb = _band_steps(bq, bk, window, nkm)
+    band = functools.partial(_fwd_band, bq=bq, bk=bk, nkm=nkm, window=window)
+
+    def q_side(d):
+        return pl.BlockSpec((1, 1, bq, d),
+                            lambda b, h, i, j, qo, ko: (b, h, i, 0))
+
+    def k_side(d):
+        return pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j, qo, ko: (
+            b, h // g, band(i, j, qo[0], ko[0])[0], 0))
+
+    out, lse = pl.pallas_call(
+        functools.partial(_flash_kernel, scale=scale, masked=True, tq=tq,
+                          tk=tk, window=window, band=band),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H, Tq // bq, nb),
+            in_specs=[q_side(D), k_side(D), k_side(Dv)],
+            out_specs=[q_side(Dv), pl.BlockSpec(
+                (1, 1, 1, bq // tq, tq),
+                lambda b, h, i, j, qo, ko: (b, h, i, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((bk // tk, Dv, tk), v.dtype)] + (
+                [] if nb == 1 else [
+                    pltpu.VMEM((bq // tq, 1, tq), jnp.float32),
+                    pltpu.VMEM((bq // tq, 1, tq), jnp.float32),
+                    pltpu.VMEM((bq // tq, Dv, tq), jnp.float32),
+                ])),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, Tq, Dv), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, H, Tq // bq, bq // tq, tq),
+                                 jnp.float32, vma=vma),
+        ],
+        compiler_params=_SWEEP_LAST,
+        interpret=interpret,
+        name=prof.FLASH_FWD_WIN,
+    )(*_offsets(q_off, k_off), qt, kt, vt)
+    return out.transpose(0, 2, 1, 3), lse
+
+
 def _flash_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, dvec_ref, dq_ref, dk_ref, dv_ref, dq_s, dk_s,
-                      dv_s, *, scale, masked, tq, tk, num_q_major):
+                      dv_s, *, scale, masked, tq, tk, num_q_major,
+                      window=None, band=None):
     # Grid (B, Hk, K majors, q_per_kv * Q majors), the last two sequential;
     # the combined (group q-head, Q major) axis is the inner one: under
     # GQA every kv head receives gradient from all q-heads of its group. A
@@ -596,21 +785,41 @@ def _flash_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     # VMEM scratch, in place (as loop carries their vregs spill at every
     # bound): dK/dV of the K major across the inner axis; dQ^T of the
     # call's whole group, a [D, tq] tile a Q tile, across the K majors,
-    # transposed back once as it is written out at the last of them.
+    # transposed back once as it is written out at the last of them. Under
+    # a ``window`` the inner axis walks, for each q-head of the group, the
+    # K major's band of Q majors (``band``), and a Q major's dQ is written
+    # out at the K major that holds the last key it sees.
     bq, bk, D = q_ref.shape[2], k_ref.shape[2], k_ref.shape[3]
     nq = bq // tq
     per_tile = tq // lse_ref.shape[4]    # logsumexp rows a Q tile spans
     kmaj, num_major = pl.program_id(2), pl.num_programs(2)
     t, num_t = pl.program_id(3), pl.num_programs(3)
-    q_base = qoff_ref[0] + jax.lax.rem(t, num_q_major) * bq
+    if band is None:
+        q_base = qoff_ref[0] + jax.lax.rem(t, num_q_major) * bq
+    else:
+        head, qm, live, done = band(kmaj, t, qoff_ref[0], koff_ref[0])
+        q_base = qoff_ref[0] + qm * bq
+        first = (head * num_q_major + qm) * nq
     k_base = koff_ref[0] + kmaj * bk
     fold = _scale_folds(scale)
     diff = _tile_diff(tk, tq) if masked else None
-    mine = pl.ds(t * nq, nq)    # this step's tiles of the group's dQ
 
-    @pl.when(kmaj == 0)
-    def _init_dq():
-        dq_s[mine] = jnp.zeros((nq, D, tq), jnp.float32)
+    if band is None:
+        mine = pl.ds(t * nq, nq)    # this step's tiles of the group's dQ
+
+        def slot(i):
+            return t * nq + i
+
+        @pl.when(kmaj == 0)
+        def _init_dq():
+            dq_s[mine] = jnp.zeros((nq, D, tq), jnp.float32)
+    else:
+        def slot(i):
+            return first + i
+
+        @pl.when((kmaj == 0) & (t == 0))
+        def _init_dq():
+            dq_s[:] = jnp.zeros_like(dq_s)
 
     @pl.when(t == 0)
     def _init_dkv():
@@ -643,12 +852,15 @@ def _flash_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             cols = _rows(i, tq)
             qb = _scaled(q_ref[0, 0, cols, :], scale)
             s = _scores(kb, qb, scale)
-            if mask_it:
+            if mask_it and window is not None:
+                d = q_base + i * tq - k_lo
+                s = jnp.where((diff <= d) & (diff > d - window), s, _NEG_INF)
+            elif mask_it:
                 s = jnp.where(diff <= q_base + i * tq - k_lo, s, _NEG_INF)
             dqt, dk, dv = grad(s, qb, do_ref[0, 0, cols, :], kt, vb,
                                _get_rows(lse_ref, i, per_tile),
                                _get_rows(dvec_ref, i, per_tile))
-            dq_s[t * nq + i] += dqt
+            dq_s[slot(i)] += dqt
             dk_s[rows, :] += dk
             dv_s[rows, :] += dv
 
@@ -667,33 +879,70 @@ def _flash_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     s, qb, do_ref[0, 0, suf, :], kt[:, own], vb[own, :],
                     _get_rows(lse_ref, i, per_tile, r * u),
                     _get_rows(dvec_ref, i, per_tile, r * u))
-                dq_s[t * nq + i, :, r * u:] += dqt
+                dq_s[slot(i), :, r * u:] += dqt
+                dk_s[grp, :] += dk
+                dv_s[grp, :] += dv
+
+        def far_tile(i, _):
+            u = _LANES
+            lse = _get_rows(lse_ref, i, per_tile)
+            dvec = _get_rows(dvec_ref, i, per_tile)
+            for r in range(tk // u):
+                grp = pl.ds(pl.multiple_of(c * tk + r * u, u), u)
+                pre = pl.ds(pl.multiple_of(i * tq, u), (r + 1) * u)
+                qb = _scaled(q_ref[0, 0, pre, :], scale)
+                own = slice(r * u, (r + 1) * u)
+                s = _scores(kb[own, :], qb, scale)
+                s = jnp.where(diff[:u, :(r + 1) * u] > -r * u, s, _NEG_INF)
+                dqt, dk, dv = grad(
+                    s, qb, do_ref[0, 0, pre, :], kt[:, own], vb[own, :],
+                    lse[:, :(r + 1) * u], dvec[:, :(r + 1) * u])
+                dq_s[slot(i), :, :(r + 1) * u] += dqt
                 dk_s[grp, :] += dk
                 dv_s[grp, :] += dv
 
         lo, full = _live_q_tiles(masked, k_lo, q_base, tq, tk, nq)
-        if masked:
-            _crossing(lo, full, k_lo - q_base, nq, tq,
-                      _diag_groups(tq, tk), q_tile, diag_tile, None)
-        _loop(full, nq, q_tile, None, mask_it=False)
+        if window is None:
+            if masked:
+                _crossing(lo, full, k_lo - q_base, nq, tq,
+                          _diag_groups(tq, tk), q_tile, diag_tile, None)
+            _loop(full, nq, q_tile, None, mask_it=False)
+            return
+        # where the window spans a tile, the tile on the diagonal is causal
+        # alone, and one the far edge enters on its first query is the
+        # diagonal's mirror: K row group r sees the queries up to
+        # (r + 1) * 128
+        groups = _diag_groups(tq, tk) if window >= tq else 0
+        _crossing(lo, full, k_lo - q_base, nq, tq, groups, q_tile,
+                  diag_tile, None)
+        b, hi = _window_q_tiles(window, full, k_lo, q_base, tq, tk, nq)
+        _loop(full, b, q_tile, None, mask_it=False)
+        _crossing(b, hi, k_lo + window - q_base, hi, tq, groups, q_tile,
+                  far_tile, None)
 
-    jax.lax.fori_loop(0, bk // tk, k_tile, None)
+    def sweep():
+        jax.lax.fori_loop(0, bk // tk, k_tile, None)
+
+    if band is None:
+        sweep()
+    else:
+        pl.when(live)(sweep)
 
     @pl.when(t == num_t - 1)
     def _write_dkv():
         dk_ref[0, 0] = dk_s[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
 
-    @pl.when(kmaj == num_major - 1)
+    @pl.when(kmaj == num_major - 1 if band is None else done)
     def _write_dq():
         for i in range(nq):     # transposed back once, as it leaves
-            dq = dq_s[t * nq + i].T
+            dq = dq_s[slot(i)].T
             dq_ref[0, 0, i * tq:(i + 1) * tq, :] = (
                 dq * scale if fold else dq).astype(dq_ref.dtype)
 
 
 def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
-                    block_q, block_k, interpret):
+                    block_q, block_k, interpret, window=None):
     """dQ/dK/dV via the one backward kernel; [B, T, H, D] layout.
     ``lse`` and ``dvec`` (rowsum(dO*O) minus the lse cotangent) are
     [B, H, Q majors, tiles, tile_q] as the forward leaves the logsumexp.
@@ -701,6 +950,10 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
     ``span_q`` is less than the sequence, the kernel runs once a span of Q
     rows (their offset added to ``q_off``) and the spans' dK/dV, float32,
     are summed here."""
+    if window is not None:
+        return _flash_backward_window(q, k, v, q_off, k_off, g_out, lse,
+                                      dvec, scale, block_q, block_k,
+                                      interpret, window)
     B, Tq, H, D = q.shape
     Hk = k.shape[2]
     g = gqa_group_size(H, Hk)
@@ -770,26 +1023,107 @@ def _flash_backward(q, k, v, q_off, k_off, g_out, lse, dvec, masked, scale,
             dv.transpose(0, 2, 1, 3))
 
 
+def _flash_backward_window(q, k, v, q_off, k_off, g_out, lse, dvec, scale,
+                           block_q, block_k, interpret, window):
+    """``_flash_backward`` under a window, as ``flash_bwd_win``: the inner
+    grid axis walks, for each q-head of the group, the K major's band of Q
+    majors (``_bwd_band``), the offsets ride in as scalar prefetch for the
+    index maps. A Q major's dQ leaves at the K major that completes it; a
+    step that completes none names a spare block past the call's rows,
+    which is cut off, so that nothing unwritten reaches the result."""
+    B, Tq, H, D = q.shape
+    Hk = k.shape[2]
+    g = gqa_group_size(H, Hk)
+    Tk, Dv = k.shape[1], v.shape[3]
+    plan = flash_plan(Tq, Tk, D, q.dtype.itemsize, block_q, block_k, Dv, g,
+                      window=window)
+    tq, tk, bq, bk = plan.bwd_q, plan.bwd_k, plan.major_q, plan.major_k
+    w, span = plan.tile_q, plan.span_q
+    nqm, nkm = span // bq, Tk // bk
+    nb = _band_steps(bk, bq, window, nqm)
+    qt, kt, vt, dot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, g_out))
+    vma = _vma_of(q, k, v, q_off, k_off, g_out)
+    q_off, k_off = _offsets(q_off, k_off)
+    part = jnp.float32 if span < Tq else None    # dK/dV of a span
+    band = functools.partial(_bwd_band, bq=bq, bk=bk, nqm=nqm, nkm=nkm,
+                             nb=nb, window=window)
+
+    def q_at(b, hk, j, t, qo, ko):
+        head, qm, _, done = band(j, t, qo[0], ko[0])
+        return b, hk * g + head, qm, done
+
+    def q_side(d):
+        return pl.BlockSpec((1, 1, bq, d), lambda *at: q_at(*at)[:3] + (0,))
+
+    def k_side(d):
+        return pl.BlockSpec((1, 1, bk, d),
+                            lambda b, hk, j, t, qo, ko: (b, hk, j, 0))
+
+    def dq_at(*at):
+        b, h, qm, done = q_at(*at)
+        return b, h, jnp.where(done, qm, nqm), 0
+
+    row_spec = pl.BlockSpec((1, 1, 1, bq // w, w),
+                            lambda *at: q_at(*at)[:3] + (0, 0))
+    call = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=scale, masked=True,
+                          tq=tq, tk=tk, num_q_major=nqm, window=window,
+                          band=band),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, Hk, nkm, g * nb),
+            in_specs=[q_side(D), k_side(D), k_side(Dv), q_side(Dv),
+                      row_spec, row_spec],
+            out_specs=[pl.BlockSpec((1, 1, bq, D), dq_at), k_side(D),
+                       k_side(Dv)],
+            scratch_shapes=[pltpu.VMEM((g * span // tq, D, tq), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, Dv), jnp.float32)]),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, span + bq, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hk, Tk, D), part or k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hk, Tk, Dv), part or v.dtype, vma=vma),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+        name=prof.FLASH_BWD_WIN,
+    )
+    dq, dk, dv = zip(*(
+        call(q_off + m * span, k_off, qt[:, :, _span(m, span)], kt, vt,
+             dot[:, :, _span(m, span)], lse[:, :, _span(m, nqm)],
+             dvec[:, :, _span(m, nqm)])
+        for m in range(Tq // span)))
+    dq = jnp.concatenate([x[:, :, :span] for x in dq], axis=2)
+    dk = functools.reduce(jnp.add, dk).astype(k.dtype)
+    dv = functools.reduce(jnp.add, dv).astype(v.dtype)
+    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
+            dv.transpose(0, 2, 1, 3))
+
+
 def _int_zero_cotangent(x):
     import numpy as np
 
     return np.zeros(jnp.shape(x), jax.dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_with_lse(q, k, v, q_off, k_off, masked, scale, block_q, block_k,
-                    interpret):
-    """Core primitive: (out, lse) with global-offset causal masking.
+                    interpret, window=None):
+    """Core primitive: (out, lse) with global-offset causal masking (and,
+    with ``masked``, a ``window``'s: see ``_window_k_tiles``'s note).
     The lse output is a first-class differentiable result — the ring merge
     consumes it, so its cotangent must flow (see _flash_with_lse_bwd)."""
     return _flash_forward(q, k, v, q_off, k_off, masked, scale, block_q,
-                          block_k, interpret)
+                          block_k, interpret, window)
 
 
 def _flash_with_lse_fwd(q, k, v, q_off, k_off, masked, scale, block_q,
-                        block_k, interpret):
+                        block_k, interpret, window=None):
     out, lse = _flash_forward(q, k, v, q_off, k_off, masked, scale,
-                              block_q, block_k, interpret)
+                              block_q, block_k, interpret, window)
     # named BEFORE they part into outputs and residuals, so that a
     # checkpoint policy that saves both names leaves the rematted forward
     # no reader of the kernel: its second run is dead code and is dropped
@@ -803,8 +1137,8 @@ def _flash_with_lse_fwd(q, k, v, q_off, k_off, masked, scale, block_q,
     return (out, lse), (q, k, v, q_off, k_off, out, lse)
 
 
-def _flash_with_lse_bwd(masked, scale, block_q, block_k, interpret, res,
-                        gs):
+def _flash_with_lse_bwd(masked, scale, block_q, block_k, interpret, window,
+                        res, gs):
     q, k, v, q_off, k_off, out, lse = res
     g, g_lse = gs
     # ds = p * (dp - rowsum(dO*O) + g_lse): the lse cotangent enters the
@@ -815,7 +1149,7 @@ def _flash_with_lse_bwd(masked, scale, block_q, block_k, interpret, res,
             - g_lse.astype(jnp.float32))      # row-major over Tq, as lse
     dq, dk, dv = _flash_backward(
         q, k, v, q_off, k_off, g, lse, dvec, masked, scale, block_q,
-        block_k, interpret)
+        block_k, interpret, window)
     return (dq, dk, dv, _int_zero_cotangent(q_off),
             _int_zero_cotangent(k_off))
 
@@ -823,10 +1157,11 @@ def _flash_with_lse_bwd(masked, scale, block_q, block_k, interpret, res,
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
+           window=None):
     zero = jnp.zeros((), jnp.int32)
     return _flash_with_lse(q, k, v, zero, zero, causal, scale, block_q,
-                           block_k, interpret)[0]
+                           block_k, interpret, window)[0]
 
 
 def kernel_supported(q_shape, k_shape, block_q: Optional[int] = None,
@@ -877,6 +1212,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention; same signature/semantics as
     ``ring_attention.reference_attention`` but never materializes the full
@@ -896,15 +1232,24 @@ def flash_attention(
     q, q-head h reads kv head h // group). The kernel path streams the
     small K/V straight from HBM — traffic and ring wire bytes shrink by
     the group factor; the scan repeats heads (exact, memory-expanded).
+
+    ``window`` W (with ``causal``): the query at t sees the keys
+    t - W < s <= t alone. The kernels (``flash_fwd_win``,
+    ``flash_bwd_win``) visit only the band, in their grids, their DMA and
+    their tiles; a W of T or more is causal attention. None: every program
+    is as without the argument.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window {window} bounds causal attention: "
+                         "causal=True and a window of at least one key")
     if _use_kernel(interpret, q.shape, k.shape, block_q, block_k,
                    v.shape[3]):
         return _flash(q, k, v, causal, scale, block_q, block_k,
-                      bool(interpret))
+                      bool(interpret), window)
     return blockwise_attention(q, k, v, causal=causal, scale=scale,
-                               block_k=block_k or 512)
+                               block_k=block_k or 512, window=window)
 
 
 # -------------------------------------------------------- ring flash attn

@@ -584,7 +584,10 @@ class DenseTable:
         if state is not None and accum > 1:
             raise ValueError("a step-to-step state and accum > 1: which "
                              "microbatch's state is handed on is undefined")
-        self.state = state
+        # placed as the step hands it on: a state placed otherwise (a
+        # router's bias made on the default device) builds the step twice
+        self.state = None if state is None else jax.device_put(
+            state, NamedSharding(self.mesh, P()))
         from minips_tpu.ops.quantized_comm import (
             _check, quantized_all_gather, quantized_psum_scatter)
         _check(comm)  # eager: tracing happens on first step call
@@ -773,9 +776,10 @@ class DenseTable:
     def load_state_dict(self, state: dict) -> None:
         self.params = self._at_own_padding(state["params"], self.params)
         if self.state is not None and "state" in state:
-            self.state = jax.tree.unflatten(
+            self.state = jax.device_put(jax.tree.unflatten(
                 jax.tree.structure(self.state),
-                [jnp.asarray(x) for x in jax.tree.leaves(state["state"])])
+                [jnp.asarray(x) for x in jax.tree.leaves(state["state"])]),
+                NamedSharding(self.mesh, P()))
         # Graft by leaf order, not structure: a checkpoint roundtrip turns
         # optax's namedtuple states into plain lists, but leaf order is
         # deterministic either way.

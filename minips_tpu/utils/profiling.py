@@ -124,6 +124,17 @@ MTP_NLL = "mtp.nll"
 LINATTN_DECAY_MEAN = "linattn.decay_mean"
 LINATTN_BETA_MEAN = "linattn.beta_mean"
 LINATTN_STATE_ABSMAX = "linattn.state_absmax"
+# the window/full hybrid (models/laguna.py): lm.attn.window lies inside
+# lm.attn around a window layer's attention, lm.attn.gate around the
+# per-head output gate; its observer (laguna.routing_stats) logs lm.nll,
+# the two routing counters above and, by layer kind, the gate's mean
+# (attn.gate_mean.window, attn.gate_mean.full)
+LM_ATTN_WINDOW = "lm.attn.window"
+LM_ATTN_GATE = "lm.attn.gate"
+ATTN_GATE_MEAN = "attn.gate_mean"
+# ---- recorded once as a windowed flash kernel is built: of the (Q tile,
+# K tile) pairs the causal kernel visits at the shape, the share it visits
+FLASH_WIN_LIVE_SHARE = "flash.win_live_share"
 # ---- under ps.table.init: the zero keys a DenseTable's range padding
 # added (shards on a mesh end on the chip's tile, mesh.SHARD_TILE)
 TABLE_PAD_KEYS = "ps.table.pad_keys"
@@ -135,6 +146,10 @@ CKPT_SKIP_TORN = "ckpt.skip_torn"
 # ---- kernels (pl.pallas_call(name=...)) and the jitted steps' names
 FLASH_FWD = "flash_fwd"
 FLASH_BWD = "flash_bwd"
+# the same kernels under a window (flash_attention(window=)): their grids
+# walk the band alone
+FLASH_FWD_WIN = "flash_fwd_win"
+FLASH_BWD_WIN = "flash_bwd_win"
 # ---- what flash_fwd leaves for the backward kernel, as checkpoint names
 # (jax.ad_checkpoint.checkpoint_name): a block checkpoint whose policy
 # saves both does not run flash_fwd a second time for the backward
@@ -162,8 +177,10 @@ PHASES = (PULL, GRAD, PUSH, PUSH_DENSE, PUSH_SPARSE, UPDATE,
           LM_EMBED, LM_ATTN, LM_MLP, LM_HEAD, LM_ATTN_CCA, LM_MOE,
           LM_MOE_ROUTER, LM_MOE_DISPATCH, LM_MOE_EXPERTS, LM_MOE_COMBINE,
           LM_ATTN_MLA, LM_MOE_SHARED, LM_MTP, LM_LINATTN, LM_LINATTN_PROJ,
-          LM_LINATTN_CONV, LM_LINATTN_SCAN, LM_LINATTN_GATE)
-KERNELS = (FLASH_FWD, FLASH_BWD, GATHER_ROWS, RAGGED_DOT)
+          LM_LINATTN_CONV, LM_LINATTN_SCAN, LM_LINATTN_GATE,
+          LM_ATTN_WINDOW, LM_ATTN_GATE)
+KERNELS = (FLASH_FWD, FLASH_BWD, GATHER_ROWS, RAGGED_DOT, FLASH_FWD_WIN,
+           FLASH_BWD_WIN)
 
 RING_SPANS = 8192
 _STAGE_EVENTS = {

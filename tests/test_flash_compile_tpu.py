@@ -80,6 +80,29 @@ def test_the_kernels_compile_for_the_v5e_at_a_cells_shape(
         assert (name in text) == there
 
 
+@pytest.mark.parametrize("H", [36, 24])
+def test_the_window_kernels_compile_for_the_v5e_at_lagunas_shape(
+        H, one_chip, no_compile_cache):
+    """Laguna-S-2.1's window layer (36 query heads over 4 key/value heads
+    of 128, window 512) and, at a full layer's 24 heads, the same band, at
+    the cell's T 16,384 in bfloat16: the windowed forward and backward
+    compile within the VMEM they ask for (the backward keeps the group's
+    whole dQ, 9 heads over 16,384 rows, beside 512-wide tiles)."""
+    q = jax.ShapeDtypeStruct((1, 16384, H, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa._flash(q, k, v, True, 128 ** -0.5, None, None,
+                                 False, 512).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, k).compile(
+        ).as_text()
+    assert prof.FLASH_FWD_WIN in text and prof.FLASH_BWD_WIN in text
+    assert not re.search(r'"(flash_fwd|flash_bwd)"', text)
+
+
 # ------------------------------------------- the fused PS step's collectives
 def _compiled_step(topo, shards: int, comm: str):
     """``(table, compiled text)`` of a small fused step for ``shards`` of

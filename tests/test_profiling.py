@@ -183,10 +183,11 @@ def test_the_ravel_of_the_gradients_is_the_pushs(shards):
 
 def test_a_program_built_anew_is_staged_anew(mesh4):
     """A state that reaches the first call uncommitted and the second laid
-    on the mesh makes jax build the step twice (JoyAI's balancing bias):
-    the account of the first program turns stale with the second
-    ``ps.compile``, and the third call keeps the account of the program
-    that runs, from jax's caches, with no compilation of its own."""
+    on the mesh makes jax build the step twice: the account of the first
+    program turns stale with the second ``ps.compile``, and the third call
+    keeps the account of the program that runs, from jax's caches, with no
+    compilation of its own. ``make_step`` lays the state it is given on
+    the mesh, so the test puts an uncommitted one in its place."""
     import jax
 
     from minips_tpu.tables.dense import DenseTable
@@ -198,6 +199,8 @@ def test_a_program_built_anew_is_staged_anew(mesh4):
 
     table = DenseTable({"w": jnp.full((41, 3), 0.41)}, mesh4, lr=0.1)
     step = table.make_step(grad_fn, state=jnp.zeros(()))
+    assert table.state.sharding == jax.NamedSharding(mesh4, jax.P())
+    table.state = jnp.zeros(())
     batch = {"x": jax.device_put(jnp.ones((8, 41)), jax.NamedSharding(
         mesh4, jax.P(mesh4.axis_names[0])))}
     profiling.clear()
