@@ -20,14 +20,21 @@ Apps see parameters as a pytree: the table ravels any pytree template via
 ``jax.flatten_util.ravel_pytree``, so "keys" are positions in the raveled
 vector — the same world view as the reference's integer key space.
 
-A table on ONE shard has no range to own and no wire: it keeps the
-template's leaves (``_Leaves``: the vectors stacked by length, so that a
-step passes fewer arrays) and its updater's state over them, and its step
-pulls by a cast and pushes by the cast back, with no ravel, unravel or
-pad. The key-space vector is then a view (``params``,
+The table keeps the template's leaves (``_Leaves``) and its updater's
+state over them, on any number of shards. On ONE shard there is no range
+to own and no wire: the vectors are stacked by length, so that a step
+passes fewer arrays, and the step pulls by a cast and pushes by the cast
+back. On several, each leaf whose shape divides into shards of whole
+tiles of the chip is sharded on that dimension (``shard_dim``), and the
+rest (gains, biases, narrow matrices) lie in one flat remainder,
+range-sharded as the whole vector was; the step gathers and
+reduce-scatters leaf by leaf, so that the compiler can run each
+collective under the model's compute, with no ravel, unravel or pad of
+the whole. The key-space vector is then a view (``params``,
 ``opt_state``), made by one jitted function when somebody reads it
 (``ps.table.view``); the fused step never reads it. adam8's blockwise
-moments need the flat vector, so that updater keeps it on one shard too.
+moments need the flat vector, so that updater keeps it on any number of
+shards.
 """
 
 from __future__ import annotations
@@ -45,6 +52,8 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from minips_tpu.ops.quantized_comm import (quantized_all_gather,
+                                           quantized_psum_scatter)
 from minips_tpu.parallel.mesh import DATA_AXIS, SHARD_TILE
 from minips_tpu.parallel.partition import RangePartitioner
 from minips_tpu.tables.updaters import (Adam8bitState, LearningRate,
@@ -82,9 +91,10 @@ def _clip_by_global_norm(g: PyTree, clip_norm: float) -> PyTree:
 
 
 class _Stacks(tuple):
-    """A one-shard table's parameters, or a part of its updater's state
-    shaped as they are: ``_Leaves.groups``' leaves, each group's stacked
-    on a leading axis."""
+    """A table's parameters, or a part of its updater's state shaped as
+    they are: ``_Leaves.groups``' leaves, each group's stacked on a
+    leading axis on one shard; on several, each sharded leaf as it is and
+    the flat remainder last."""
 
 
 jax.tree_util.register_pytree_node(
@@ -95,17 +105,70 @@ def _is_stacks(x) -> bool:
     return isinstance(x, _Stacks)
 
 
-class _Leaves:
-    """Where a table on one shard keeps its parameters, and its key space
-    as a view of them. Each array a step passes costs the host its
-    dispatch, so the vectors (gains, biases, scales: half of a model's
-    leaves) are stacked by length; every larger leaf stands alone, since
-    a stack's gradient is one more array the step holds whole, and one
-    the size of a model's matrices does not fit beside them. The key
-    space is ``ravel_pytree``'s: the leaves in the tree's order, each
-    raveled, in their common dtype."""
+def _is_spec(x) -> bool:
+    return isinstance(x, P)
 
-    def __init__(self, template: PyTree):
+
+def shard_dim(shape, shards: int) -> Optional[int]:
+    """The dimension a leaf of ``shape`` is sharded on over ``shards``: the
+    first that divides into shards of whole tiles of the chip (a multiple
+    of 8 rows on the second-minor dimension, of 128 lanes on the minor
+    one, any count on a dimension before them). None for a vector, and for
+    a matrix narrower than one lane tile, which the chip pads to 128 lanes
+    a row: such a leaf lies in the flat remainder, dense. A shard that
+    ends off the tile is one the pull's all-gather cannot place: the
+    compiler builds it as an all-reduce of zero-padded shards."""
+    if len(shape) < 2 or shape[-1] < 128:
+        return None
+    for d, size in enumerate(shape):
+        tile = {len(shape) - 1: 128, len(shape) - 2: 8}.get(d, 1)
+        if size % shards == 0 and (size // shards) % tile == 0:
+            return d
+    return None
+
+
+def _gather(x: jax.Array, dim: int, comm: str, dtype) -> jax.Array:
+    """The pull of one part inside the step: its shards gathered on
+    ``dim``, in ``dtype`` where that is given. The float32 wire gathers
+    the cast (the cast commutes with the gather); a compressed wire
+    gathers the part's shards raveled with ``dim`` leading."""
+    if comm == "float32":
+        return jax.lax.all_gather(x if dtype is None else x.astype(dtype),
+                                  DATA_AXIS, axis=dim, tiled=True)
+    m = jnp.moveaxis(x, dim, 0)
+    full = quantized_all_gather(m.reshape(-1), DATA_AXIS, comm)
+    full = jnp.moveaxis(full.reshape((-1,) + m.shape[1:]), 0, dim)
+    return full if dtype is None else full.astype(dtype)
+
+
+def _scatter(g: jax.Array, dim: int, comm: str) -> jax.Array:
+    """The push of one part inside the step: the workers' float32
+    gradients summed into this shard of ``dim``."""
+    if comm == "float32":
+        return jax.lax.psum_scatter(g, DATA_AXIS, scatter_dimension=dim,
+                                    tiled=True)
+    m = jnp.moveaxis(g, dim, 0)
+    part = quantized_psum_scatter(m.reshape(-1), DATA_AXIS, comm)
+    return jnp.moveaxis(part.reshape((-1,) + m.shape[1:]), 0, dim)
+
+
+class _Leaves:
+    """Where a table keeps its parameters, and its key space as a view of
+    them. The key space is ``ravel_pytree``'s: the leaves in the tree's
+    order, each raveled, in their common dtype.
+
+    On one shard each array a step passes costs the host its dispatch, so
+    the vectors (gains, biases, scales: half of a model's leaves) are
+    stacked by length; every larger leaf stands alone, since a stack's
+    gradient is one more array the step holds whole, and one the size of
+    a model's matrices does not fit beside them. On ``shards`` > 1 each
+    leaf ``shard_dim`` finds a dimension for stands alone, sharded on it
+    (``dims``), and the rest are raveled in key order into one flat
+    remainder, padded to shards that end on the chip's tile
+    (``SHARD_TILE``) and range-sharded: ``groups`` holds the sharded
+    leaves and then the remainder's."""
+
+    def __init__(self, template: PyTree, shards: int = 1):
         leaves, self.treedef = jax.tree.flatten(template)
         self.dtypes = [jnp.result_type(x) for x in leaves]
         self.dtype = jnp.result_type(*self.dtypes)
@@ -113,8 +176,32 @@ class _Leaves:
         at = np.cumsum([0] + [math.prod(s) for s in self.shapes]).tolist()
         self.spans = list(zip(at[:-1], at[1:]))
         self.size = at[-1]
-        vectors: dict = {}
+        self.shards = shards
         self.groups = []          # the leaves of each stack, in order
+        if shards > 1:
+            dims = [shard_dim(s, shards) for s in self.shapes]
+            self.groups = [[i] for i, d in enumerate(dims) if d is not None]
+            self.dims = [dims[g[0]] for g in self.groups]
+            self.rest = [i for i, d in enumerate(dims) if d is None]
+            self.rest_keys = sum(math.prod(self.shapes[i])
+                                 for i in self.rest)
+            self.rest_padded = RangePartitioner(
+                self.rest_keys, shards, align=SHARD_TILE).padded
+            if self.rest:
+                self.groups.append(self.rest)
+                self.dims.append(0)
+            # the key space as the views hand it out: the padded vector a
+            # range-sharded table on as many shards holds
+            self.padded = RangePartitioner(self.size, shards,
+                                           align=SHARD_TILE).padded
+            ndims = [len(self.shapes[g[0]]) for g in self.groups]
+            if self.rest:
+                ndims[-1] = 1
+            self.specs = _Stacks(
+                P(*[DATA_AXIS if k == d else None for k in range(nd)])
+                for nd, d in zip(ndims, self.dims))
+            return
+        vectors: dict = {}
         for i, s in enumerate(self.shapes):
             if len(s) > 1:
                 self.groups.append([i])
@@ -123,15 +210,39 @@ class _Leaves:
             else:
                 vectors[s] = [i]
                 self.groups.append(vectors[s])
+        self.padded = self.size
+        self.specs = P()
 
     # lax, not jnp: a view traces these once a leaf, a step as well
     def _stack(self, xs: list) -> _Stacks:
+        if self.shards > 1:
+            parts = [xs[g[0]] for g in self.groups[:len(self.groups)
+                                                   - bool(self.rest)]]
+            if self.rest:
+                flat = [jax.lax.reshape(xs[i], (math.prod(self.shapes[i]),))
+                        for i in self.rest]
+                pad = self.rest_padded - self.rest_keys
+                if pad:
+                    flat.append(jnp.zeros(pad, xs[self.rest[0]].dtype))
+                parts.append(jax.lax.concatenate(flat, 0))
+            return _Stacks(parts)
         return _Stacks(jax.lax.concatenate(
             [jax.lax.expand_dims(xs[i], (0,)) for i in g], 0)
             for g in self.groups)
 
     def _unstack(self, stacks: _Stacks) -> list:
         xs = [None] * len(self.shapes)
+        if self.shards > 1:
+            for g, x in zip(self.groups, stacks):
+                xs[g[0]] = x
+            if self.rest:
+                at = 0
+                for i in self.rest:
+                    size = math.prod(self.shapes[i])
+                    xs[i] = jax.lax.reshape(jax.lax.slice_in_dim(
+                        stacks[-1], at, at + size), self.shapes[i])
+                    at += size
+            return xs
         for g, s in zip(self.groups, stacks):
             for k, i in enumerate(g):
                 xs[i] = (jax.lax.index_in_dim(s, k, keepdims=False)
@@ -144,10 +255,15 @@ class _Leaves:
         return self._stack([jnp.asarray(x, dtype)
                             for x in self.treedef.flatten_up_to(tree)])
 
-    def tree(self, stacks: _Stacks) -> PyTree:
-        """The workers' tree: each leaf in the template's dtype."""
+    def tree(self, stacks: _Stacks, dtype=None) -> PyTree:
+        """The workers' tree: each leaf in the template's dtype, or in
+        ``dtype`` where the template's is floating."""
+        def cast(x, d):
+            return x.astype(dtype if dtype is not None and jnp.issubdtype(
+                d, jnp.floating) else d)
+
         return self.treedef.unflatten(
-            [x.astype(d) for x, d in zip(self._unstack(stacks), self.dtypes)])
+            [cast(x, d) for x, d in zip(self._unstack(stacks), self.dtypes)])
 
     def ravel(self, stacks: _Stacks) -> jax.Array:
         """The key space: the vector ``ravel_pytree`` makes of the tree.
@@ -157,6 +273,12 @@ class _Leaves:
         leaf makes the program a seventh the size (my compile, PR 39:
         14.9 MB of code where 110 took 0.75 s to load in ``gpt2-xl``)."""
         xs = self._unstack(stacks)
+        if self.shards > 1:
+            # the range-sharded vector, padding keys' zeros and all, that a
+            # table on the vector holds over as many shards
+            return jnp.concatenate(
+                [x.reshape(-1) for x in xs]
+                + [jnp.zeros(self.padded - self.size, xs[0].dtype)])
         by_shape: dict = {}
         for i, x in enumerate(xs):
             by_shape.setdefault(x.shape, []).append(i)
@@ -186,11 +308,12 @@ class _Leaves:
 
 def _needs_flat(updater: str, tx, layout: _Leaves) -> bool:
     """An updater whose state is blocks of contiguous keys (adam8's
-    quantized moments) runs on the flat vector."""
+    quantized moments) runs on the flat vector. A user's transform is
+    asked for its state over the key space as a sharded table pads it."""
     if tx is None:
         return updater == "adam8"
     state = jax.eval_shape(
-        tx.init, jax.ShapeDtypeStruct((layout.size,), layout.dtype))
+        tx.init, jax.ShapeDtypeStruct((layout.padded,), layout.dtype))
     return any(isinstance(x, Adam8bitState) for x in jax.tree.leaves(
         state, is_leaf=lambda x: isinstance(x, Adam8bitState)))
 
@@ -218,13 +341,13 @@ class DenseTable:
         self.grad_reduce = grad_reduce
         self.num_shards = mesh.shape[DATA_AXIS]
 
-        layout = _Leaves(template)          # shapes only: no array is made
+        # shapes only: no array is made
+        layout = _Leaves(template, self.num_shards)
         self.num_keys = layout.size
         self._dtype = layout.dtype
-        # one shard has no range to own and no wire: its table keeps the
-        # leaves, unless its updater works on blocks of contiguous keys
-        self._leaves = (layout if self.num_shards == 1 and not _needs_flat(
-            updater, tx, layout) else None)
+        # the table keeps the leaves, unless its updater works on blocks
+        # of contiguous keys
+        self._leaves = None if _needs_flat(updater, tx, layout) else layout
         kw = dict(updater_kwargs or {})
         # adam8's blockwise-quantized moments need whole blocks per shard
         # (one f32 scale per `block` contiguous elements); align the
@@ -239,7 +362,17 @@ class DenseTable:
         self.partitioner = RangePartitioner(self.num_keys, self.num_shards,
                                             align=align)
         self.padded = self.partitioner.padded
-        prof.counter(prof.TABLE_PAD_KEYS, self.padded - self.num_keys)
+        sharded = self._leaves is not None and self.num_shards > 1
+        # the zero keys the table holds: a sharded table on its leaves
+        # pads its flat remainder alone
+        prof.counter(prof.TABLE_PAD_KEYS,
+                     layout.rest_padded - layout.rest_keys if sharded
+                     else self.padded - self.num_keys if self._leaves is None
+                     else 0)
+        if sharded:
+            prof.counter(prof.TABLE_SHARDED_LEAVES,
+                         len(layout.groups) - bool(layout.rest))
+            prof.counter(prof.TABLE_FLAT_KEYS, layout.rest_keys)
         self._shard_shape = (self.padded // self.num_shards,)
         # clip-by-global-norm must see the GLOBAL gradient, but the optax
         # transform runs on one owner shard inside shard_map — intercept
@@ -265,13 +398,21 @@ class DenseTable:
         self._sharding = NamedSharding(mesh, self._pspec)
         if self._leaves is not None:
             self._unravel = layout.unravel_tree
-            # one device holds the whole: the state is the same on every
-            # worker, and the push's sum over workers makes it so
-            self._state_spec = self._opt_specs = P()
+            # one shard: one device holds the whole, the same on every
+            # worker, and the push's sum over workers makes it so; on
+            # several each part of the state is sharded as the
+            # parameters' part it stands beside
+            self._state_spec = layout.specs
+            self._opt_specs = P() if self.num_shards == 1 else jax.tree.map(
+                lambda x: layout.specs if _is_stacks(x) else P(),
+                jax.eval_shape(lambda t: self.tx.init(
+                    layout.stack(t, layout.dtype)), template),
+                is_leaf=_is_stacks)
             self._p, self._opt = jax.jit(
                 lambda t: (lambda s: (s, self.tx.init(s)))(
                     layout.stack(t, layout.dtype)),
-                out_shardings=NamedSharding(mesh, P()))(template)
+                out_shardings=self._on(
+                    (self._state_spec, self._opt_specs)))(template)
         else:
             self._state_spec = self._pspec
             flat, self._unravel = ravel_pytree(template)
@@ -304,6 +445,11 @@ class DenseTable:
                 self.tx.init, out_shardings=opt_shardings)(self._p)
         self.state = None       # see make_step(state=...)
         self._staged = None     # the step whose program step_inplace staged
+
+    def _on(self, specs) -> PyTree:
+        """``specs``, a spec or a tree of them, as shardings on the mesh."""
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs,
+                            is_leaf=_is_spec)
 
     def _opt_specs_tree(self, opt_state) -> PyTree:
         """Spec tree for the opt state: params-length 1-D leaves range-
@@ -338,9 +484,10 @@ class DenseTable:
     @property
     def step_state(self) -> tuple:
         """``(params, opt_state)`` as the fused step takes and hands them
-        back: the range-sharded vector and its updater's state, or on one
-        shard the stacked leaves and theirs. For a caller that drives or
-        lowers ``make_step``'s step itself, and sets what it hands back."""
+        back: the table's parts (``_Leaves``) and its updater's state over
+        them, or adam8's range-sharded vector and its state. For a caller
+        that drives or lowers ``make_step``'s step itself, and sets what it
+        hands back."""
         return self._p, self._opt
 
     @step_state.setter
@@ -357,8 +504,8 @@ class DenseTable:
     @property
     def params(self) -> jax.Array:
         """The key space 0..padded-1 as one range-sharded vector: the
-        table's own, or on one shard a view of its leaves made on each
-        read (``ps.table.view``). Assigning a vector sets the table."""
+        table's own (adam8), or a view of its leaves made on each read
+        (``ps.table.view``). Assigning a vector sets the table."""
         if self._leaves is None:
             return self._p
         prof.counter(prof.TABLE_VIEW, 1)
@@ -408,7 +555,7 @@ class DenseTable:
     @functools.cached_property
     def _unravel_keys(self):
         return jax.jit(self._leaves.unravel,
-                       out_shardings=NamedSharding(self.mesh, P()))
+                       out_shardings=self._on(self._state_spec))
 
     @functools.cached_property
     def _pull_tree(self):
@@ -539,13 +686,15 @@ class DenseTable:
         batch) -> (params, opt, loss)`` is the TPU-native rewrite of one hot
         loop iteration (SURVEY.md §3.3): all-gather (pull), local grad
         (worker compute on MXU), psum_scatter (push), optax on the owner
-        shard (server update). BSP is implicit — the collectives are the
-        barrier (SURVEY.md §2 "BSPModel").
+        shard (server update), on a table of several shards one of each a
+        part, so that the compiler can run each under the model's compute.
+        BSP is implicit — the collectives are the barrier (SURVEY.md §2
+        "BSPModel").
 
         ``comm`` compresses the two collectives' wire format ("bfloat16" or
         "int8"; EQuARX-style, see ops/quantized_comm.py). Params and the
-        optimizer update stay float32 — only bytes-on-wire change; a table
-        on one shard has no wire, and nothing to compress.
+        optimizer update stay float32 — only bytes-on-wire change, part by
+        part; a table on one shard has no wire, and nothing to compress.
 
         ``compute_dtype`` (e.g. ``jnp.bfloat16``) runs the worker math in
         reduced precision — the MXU-native mixed-precision recipe: float32
@@ -577,7 +726,8 @@ class DenseTable:
         num_workers = self.num_shards
         clip_norm = self._clip_norm
         unravel, tx, reduce = self._unravel, self.tx, self.grad_reduce
-        L = self._leaves        # one shard: the leaves (None: the vector)
+        L = self._leaves        # the leaves (None: the vector)
+        sharded = L is not None and num_workers > 1
         bspec = batch_spec if batch_spec is not None else P(DATA_AXIS)
         if accum < 1:
             raise ValueError(f"accum must be >= 1, got {accum}")
@@ -588,8 +738,7 @@ class DenseTable:
         # router's bias made on the default device) builds the step twice
         self.state = None if state is None else jax.device_put(
             state, NamedSharding(self.mesh, P()))
-        from minips_tpu.ops.quantized_comm import (
-            _check, quantized_all_gather, quantized_psum_scatter)
+        from minips_tpu.ops.quantized_comm import _check
         _check(comm)  # eager: tracing happens on first step call
 
         cd = None if compute_dtype is None else jnp.dtype(compute_dtype)
@@ -606,7 +755,7 @@ class DenseTable:
         def to_wire(grads):
             # the push's own work on the workers' gradients: the cast back
             # up (one that is padded goes up after the pad) and the ravel
-            # into the table's one vector, which one shard does not keep
+            # into the table's one vector, which the leaves do not keep
             if L is not None:
                 return cast_floating(grads, L.dtype)
             if cd is not None and not pad:
@@ -615,8 +764,8 @@ class DenseTable:
 
         def _grads(params, batch, *state):
             # (loss, gradients, *state): the gradients as the workers' tree
-            # where accum is 1 (the push ravels it), else the folded
-            # vector, or the folded tree on one shard
+            # where accum is 1, else the folded vector, or the folded tree
+            # on the leaves
             if accum == 1:
                 return grad_fn(params, batch, *state)
 
@@ -659,7 +808,13 @@ class DenseTable:
 
         def local_step(p_shard, opt_shard, batch, *state):
             with jax.named_scope(prof.PULL):
-                if L is not None:
+                if sharded:
+                    # leaf by leaf, each on its own dimension: the model's
+                    # first layers wait for their own leaves alone
+                    params = L.tree(_Stacks(
+                        _gather(x, d, comm, cd)
+                        for x, d in zip(p_shard, L.dims)), cd)
+                elif L is not None:
                     # typed as the gathered vector is: varying over the
                     # workers (a model's custom gradient rules expect it)
                     params = cast_floating(L.tree(jax.lax.pcast(
@@ -671,7 +826,13 @@ class DenseTable:
                 loss, grads, *state = _grads(params, batch, *state)
             with jax.named_scope(prof.PUSH):
                 gflat = to_wire(grads) if accum == 1 else grads
-                if L is not None:
+                if sharded:
+                    # leaf by leaf: a leaf's sum can start as soon as its
+                    # gradient is made
+                    g_shard = _Stacks(
+                        _scatter(g, d, comm)
+                        for g, d in zip(L.stack(gflat, L.dtype), L.dims))
+                elif L is not None:
                     # the sum over the workers: one worker's, which the
                     # compiler drops
                     g_shard = jax.lax.psum(L.stack(gflat), DATA_AXIS)

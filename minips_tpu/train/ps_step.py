@@ -154,10 +154,10 @@ class PSTrainStep:
             # ----- dense push: reduce-scatter + sharded optax update
             if dense is not None:
                 with jax.named_scope(prof.PUSH_DENSE):
-                    held = NamedSharding(mesh, dense._state_spec)
-                    g_dense = jax.tree.map(
-                        lambda g: jax.lax.with_sharding_constraint(g, held),
-                        g_dense)
+                    # the spec, or on several shards the tree of specs
+                    # of the leaves the table keeps
+                    g_dense = jax.lax.with_sharding_constraint(
+                        g_dense, dense._on(dense._state_spec))
                     updates, opt = dense.tx.update(g_dense, opt, p_dense)
                     new_state["dense"] = (
                         optax.apply_updates(p_dense, updates), opt)

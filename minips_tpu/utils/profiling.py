@@ -138,7 +138,12 @@ FLASH_WIN_LIVE_SHARE = "flash.win_live_share"
 # ---- under ps.table.init: the zero keys a DenseTable's range padding
 # added (shards on a mesh end on the chip's tile, mesh.SHARD_TILE)
 TABLE_PAD_KEYS = "ps.table.pad_keys"
-# ---- a one-shard DenseTable made its key-space vector (``params``,
+# ---- under ps.table.init, a DenseTable on its leaves over several shards:
+# the leaves sharded on a dimension of their own (``dense.shard_dim``), and
+# the keys of the leaves that lie in its flat remainder instead
+TABLE_SHARDED_LEAVES = "ps.table.sharded_leaves"
+TABLE_FLAT_KEYS = "ps.table.flat_keys"
+# ---- a DenseTable on its leaves made its key-space vector (``params``,
 # ``opt_state``) from its leaves, under the span open then; value: 1
 TABLE_VIEW = "ps.table.view"
 # ---- a torn checkpoint step that restore() walked past; value: the step

@@ -253,15 +253,18 @@ _HLO_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
 _HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
 _HLO_OPERAND = re.compile(r"%([\w.\-]+)")
 _HLO_CALLS = re.compile(r"\bcalls=%([\w.\-]+)")
+_HLO_SUBS = re.compile(r"\b(?:calls|body|condition|to_apply)=%([\w.\-]+)")
 _HLO_PARAMETER = re.compile(r" parameter\((\d+)\)")
 
 
 class _Hlo(NamedTuple):
     """One instruction line of a compiled module's text."""
+    opcode: str
     operands: list             # instruction names, in order
     scope: Optional[str]       # its op_name
     calls: Optional[str]       # the computation a fusion calls
     parameter: Optional[int]   # a parameter's number
+    subs: tuple                # every computation it calls (a loop's too)
 
 
 def _computations(hlo_text: str) -> Iterable[tuple[str, dict]]:
@@ -296,9 +299,10 @@ def _computations(hlo_text: str) -> Iterable[tuple[str, dict]]:
         number = (_HLO_PARAMETER.search(rest) if opcode == "parameter"
                   else None)
         body[m.group(1)] = _Hlo(
-            operands, scope.group(1) if scope else None,
+            opcode, operands, scope.group(1) if scope else None,
             calls.group(1) if calls else None,
-            int(number.group(1)) if number else None)
+            int(number.group(1)) if number else None,
+            tuple(_HLO_SUBS.findall(rest)))
 
 
 def instruction_phases(hlo_text: str) -> dict[str, Instruction]:
@@ -367,6 +371,79 @@ def instruction_phases(hlo_text: str) -> dict[str, Instruction]:
     return out
 
 
+_ASYNC_START = re.compile(
+    r"(async-collective|all-gather|all-reduce|reduce-scatter|all-to-all|"
+    r"collective-permute)-start\b")
+_ASYNC_DONE = re.compile(
+    r"(async-collective|all-gather|all-reduce|reduce-scatter|all-to-all|"
+    r"collective-permute)-done\b")
+# what the schedule places between a start and its done that is no work
+_NO_WORK = {"get-tuple-element", "tuple", "bitcast", "parameter", "constant",
+            "copy-start", "copy-done", "partition-id", "replica-id",
+            "after-all", ""}
+
+
+def collective_overlap(hlo_text: str) -> dict[str, tuple[bool, int]]:
+    """``{collective instruction name: (asynchronous, instructions
+    between)}`` for every collective of a compiled module's text
+    (``comm_analysis.collective_ops``' names). A collective is
+    asynchronous where the entry computation starts it in one instruction
+    and waits for it in another: an ``*-start`` / ``*-done`` pair, or the
+    TPU's async collective fusions (``async-collective-start``, the
+    fusions that carry it on beside compute, ``async-collective-done``).
+    The count is of the instructions the schedule places between the
+    start and the done that do work (fusions, custom calls, the carrying
+    fusions themselves), and not the tuple plumbing, copies or another
+    collective's start or done: what can run while the collective moves.
+    A collective the entry runs in one instruction reads ``(False, 0)``."""
+    comps = list(_computations(hlo_text))
+    if not comps:
+        return {}
+    names = {op.name for op in comm_analysis.collective_ops(hlo_text)}
+    within: dict[str, set] = {}     # computation -> collectives inside it
+    for comp, body in comps:        # callees come before their callers
+        got = set()
+        for n, v in body.items():
+            if n in names:
+                got.add(n)
+            got = got.union(*(within.get(s, ()) for s in v.subs))
+        within[comp] = got
+    entry = comps[-1][1]
+    order = {n: i for i, n in enumerate(entry)}
+    origin: dict[str, str] = {}     # an entry instruction -> its start
+    done: dict[str, str] = {}       # a start -> its done
+    for n, v in entry.items():
+        if _ASYNC_START.match(n):
+            origin[n] = n
+            continue
+        # the chain runs through the start's tuple and the fusions (and
+        # loops) that carry the collective on; what merely reads a value
+        # one of them made (a matmul's output beside the gathered part) is
+        # not in it
+        if not (v.opcode in ("get-tuple-element", "tuple")
+                or _ASYNC_DONE.match(n)
+                or any(within.get(s) for s in v.subs)):
+            continue
+        o = next((origin[o] for o in v.operands if o in origin
+                  and not _ASYNC_DONE.match(o)), None)
+        if o is not None:
+            origin[n] = o
+            if _ASYNC_DONE.match(n):
+                done[o] = n
+    between = {
+        s: sum(1 for n in list(entry)[order[s] + 1:order[d]]
+               if entry[n].opcode not in _NO_WORK
+               and not _ASYNC_START.match(n) and not _ASYNC_DONE.match(n))
+        for s, d in done.items()}
+    out: dict[str, tuple[bool, int]] = {}
+    for n, v in entry.items():
+        inside = ({n} & names).union(*(within.get(s, ()) for s in v.subs))
+        s = origin.get(n)
+        for c in inside:
+            out[c] = (True, between[s]) if s in between else (False, 0)
+    return out
+
+
 def account(text: str, memory: Optional[dict] = None) -> dict:
     """What ``programs.json`` holds of one program, read from its compiled
     text (``profiling.Program.text()``; ``memory`` is its ``memory``):
@@ -374,13 +451,19 @@ def account(text: str, memory: Optional[dict] = None) -> dict:
     ``instruction_fields``) and ``collectives``, what the compiler BUILT
     for pull and push: every collective of the text
     (``comm_analysis.collective_ops``) as ``{"name", "kind", "shape",
-    "bytes", "ps_phase"}``."""
+    "bytes", "ps_phase", "async", "between"}``, the last two
+    ``collective_overlap``'s: whether the schedule starts it apart from
+    waiting for it, and how many instructions that do work it places in
+    between."""
     known = instruction_phases(text)
+    overlap = collective_overlap(text)
     return {"memory": memory, "text_bytes": len(text),
             "collectives": [
                 {"name": op.name, "kind": op.kind, "shape": op.shape,
                  "bytes": op.bytes,
-                 "ps_phase": getattr(known.get(op.name), "ps_phase", None)}
+                 "ps_phase": getattr(known.get(op.name), "ps_phase", None),
+                 "async": overlap.get(op.name, (False, 0))[0],
+                 "between": overlap.get(op.name, (False, 0))[1]}
                 for op in comm_analysis.collective_ops(text)],
             "instruction_fields": list(Instruction._fields),
             "instructions": {k: list(v) for k, v in known.items()}}
@@ -494,7 +577,9 @@ def programs_summary(programs: dict) -> dict:
         built: dict = {}
         for c in acc["collectives"]:
             built.setdefault(c["ps_phase"] or "no phase", []).append(
-                f"{c['kind']} {c['shape']} {c['bytes']} B ({c['name']})")
+                f"{c['kind']} {c['shape']} {c['bytes']} B ({c['name']})"
+                + (f" async, {c['between']} between"
+                   if c.get("async") else ""))
         out[name] = {"memory": acc["memory"], "collectives": built}
     return out
 

@@ -12,6 +12,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from jax.sharding import NamedSharding, SingleDeviceSharding
@@ -198,3 +199,57 @@ def test_the_steps_account_places_what_the_chips_compiler_built(
     casts = [v for n, v in placed.items() if n.startswith("convert")
              and v.how == "neighbours"]
     assert casts and {v.ps_phase for v in casts} <= set(prof.PS_PHASES)
+
+
+def test_a_sharded_olmo_step_gathers_its_leaves_under_compute(
+        topo, no_compile_cache):
+    """Olmo-Hybrid at widths where its matrices shard
+    (``test_dense_table_sharded.OLMO``), as the four-chip cell builds it,
+    compiled for the described 2x2: no instruction ravels the whole
+    gradient (the one ``concatenate`` of the table's is the flat
+    remainder's), each sharded leaf is gathered by an asynchronous
+    collective with work scheduled between its start and its done, and
+    pushed by a ``reduce-scatter`` of its own."""
+    import functools
+    from unittest import mock
+
+    from minips_tpu.apps.lm_example import model_dp_step
+    from minips_tpu.models import olmo_hybrid
+    from minips_tpu.utils.trace_analysis import account
+    from tests.test_dense_table_sharded import OLMO
+
+    config = dict(OLMO, compute_dtype="bfloat16", attn="flash",
+                  head_chunk=32)
+    m = olmo_hybrid.from_config(config)
+    toks = {"tokens": jnp.zeros((4, 129), jnp.int32)}
+    _, t, _, _ = model_dp_step(
+        config, make_mesh(4, devices=jax.devices()[:4]),
+        olmo_hybrid.init(jax.random.PRNGKey(0), m), toks, updater="adam",
+        lr=1e-3)
+    t.mesh = make_mesh(4, devices=topo.devices)
+    step = t.make_step(functools.partial(
+        olmo_hybrid.grad_fn, m=m, compute_dtype=jnp.bfloat16,
+        attn_impl="flash", head_chunk=32), compute_dtype=jnp.bfloat16)
+    args = jax.tree.map(
+        lambda sh, x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        t._on((t._state_spec, t._opt_specs)), t.step_state)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (4, 129), jnp.int32, sharding=NamedSharding(t.mesh, P(DATA_AXIS)))}
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = step.lower(*args, batch).compile().as_text()
+    L = t._leaves
+    sharded = len(L.groups) - 1
+    assert sharded >= 20 and L.rest
+    concats = [int(np.prod([int(d) for d in s.split(",") if d]))
+               for s in re.findall(r"= \w+\[([\d,]*)\]\S* concatenate\(",
+                                   text)]
+    assert max(concats) <= L.rest_padded < t.num_keys // 8, concats
+    built = account(text)["collectives"]
+    gathers = [c for c in built if c["kind"] == "all-gather"]
+    assert {c["ps_phase"] for c in gathers} == {prof.PULL}
+    overlapped = [c for c in gathers if c["async"] and c["between"] > 0]
+    assert len(overlapped) >= sharded, [
+        (c["name"], c["async"], c["between"]) for c in gathers]
+    pushes = [c for c in built if c["kind"] == "reduce-scatter"]
+    assert len(pushes) >= sharded - 2
+    assert {c["ps_phase"] for c in pushes} == {prof.PUSH}

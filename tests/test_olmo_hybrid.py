@@ -280,19 +280,22 @@ def test_beta_is_doubled_only_where_the_file_allows_negative_eigenvalues():
 # ---------------------------------------------- the one builder of steps
 # the jaxprs of ZAYA's and JoyAI's fused steps as the builder traces them,
 # and of the dense transformer's as the benchmark's ``lm`` adapter builds
-# it. On FOUR shards, the range-sharded vector's, as they were at the
-# parent of the PR that gave a one-shard table its leaves (96f325b): that
-# PR left the sharded program as it was. On ONE shard, as the one-chip
-# cells run them, the leaves' program that PR made. After a change of
-# jax's printing: check out those commits, print the digests there, and
-# compare.
+# it. On FOUR shards, the program of the change that gave a sharded table
+# its leaves (its parent b4b5bd9 had the range-sharded vector's): each
+# leaf gathered and reduce-scattered on its own dimension, the vectors in
+# a flat range-sharded remainder (these small models' leaves are all
+# narrower than a lane tile, so all of them lie there). On ONE shard, as
+# the one-chip cells run them, the leaves' program of the change that
+# gave a one-shard table its leaves (its parent 96f325b), which the
+# sharded change left as it was. After a change of jax's printing: check
+# out those commits, print the digests there, and compare.
 STEP_JAXPR = {
     ("zaya", 4):
-    "49236095fa7611382db0369e9eed2995a164c9280de2af18c87a59d31ea3c256",
+    "d57a0d7c683ccbacd67a434826749c7103e3f9cd6a12db0e65654e8671704fc6",
     ("joyai_llm_flash", 4):
-    "6a37a7648220ea73203fb10cddb7d77d38578016078bcfedc4dad9620c3e382e",
+    "b9d472cd2ca313954e13af7f9b0c477f9679d67ef46c64b69555e8d6a0e55d68",
     ("lm", 4):
-    "24bf57f249f0877eee8b744b6ca08e7150f135c496ead25ab78ad1209dd5e989",
+    "6cf3308756804600d4a65f067fb9f6dac492a930b85e6bcf60667c2bd052628b",
     ("zaya", 1):
     "697e9d35db0bae56c42e97d9451ba3a821bda1b03948c8f08806bc008bd97968",
     ("joyai_llm_flash", 1):

@@ -155,10 +155,12 @@ def test_the_account_puts_every_phase_and_the_collectives_built(mesh4):
 
 @pytest.mark.parametrize("shards", [4, 1])
 def test_the_ravel_of_the_gradients_is_the_pushs(shards):
-    """On four shards the concatenate that makes the table's one vector of
-    the workers' gradients lies under ``ps.push``, so the push reads the
-    same work in every cell; on one shard, whose table keeps its leaves,
-    the step makes no such vector at all."""
+    """On four shards the concatenate that makes the table's flat
+    remainder of the workers' gradients (this table's leaves are all
+    narrower than a lane tile, so all of them lie there, padded to shards
+    that end on the chip's tile) lies under ``ps.push``, so the push reads
+    the same work in every cell; on one shard, whose table stacks its
+    leaves, the step makes no such vector at all."""
     import jax
 
     from minips_tpu.parallel.mesh import make_mesh
@@ -173,9 +175,10 @@ def test_the_ravel_of_the_gradients_is_the_pushs(shards):
     table, step, batch = _dense_step(
         make_mesh(shards, devices=jax.devices()[:shards]))
     jaxpr = jax.make_jaxpr(step)(*table.step_state, batch)
+    flat = table._leaves.rest_padded if shards > 1 else 37 * 5 + 5
     ravels = [e for e in eqns(jaxpr.jaxpr)
               if e.primitive.name == "concatenate"
-              and e.outvars[0].aval.shape == (37 * 5 + 5,)]
+              and e.outvars[0].aval.shape == (flat,)]
     assert bool(ravels) == (shards > 1)
     assert {ps_phase_of(str(e.source_info.name_stack))
             for e in ravels} <= {profiling.PUSH}
