@@ -90,6 +90,19 @@ def test_files_lie_under_paths_and_cells_are_whole(bm):
     assert four <= max(1, len(bm["workloads"]) // 4)
 
 
+def test_every_reader_is_listed_and_every_per_layer_name_once(bm):
+    """A reader under ``metrics/`` that no entry names prints nothing in
+    any run and reaches no ledger line: each file is named by an entry of
+    ``end_to_end`` or ``per_layer``, and no ``per_layer`` name repeats."""
+    per = [m["name"] for m in bm["per_layer"]]
+    assert len(per) == len(set(per))
+    listed = set(per) | {m["name"] for m in bm["end_to_end"]}
+    metrics = os.path.join(ROOT, bm["paths"][0], "metrics")
+    files = {f[:-len(".py")] for f in os.listdir(metrics)
+             if f.endswith(".py")}
+    assert files - listed == set()
+
+
 def test_a_roofline_or_mfu_moves_beside_a_whole_step_share(bm):
     per = bm["per_layer"]
     for m in per:

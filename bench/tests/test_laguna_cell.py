@@ -37,15 +37,19 @@ def test_the_cells_files_are_found_by_name():
     assert mix == {"kind": "lm_tokens", "batch": 1, "seq_len": 16384,
                    "vocab": 12544, "zipf_alpha": 1.05, "pool_batches": 16,
                    "warmup_steps": 2, "trace_seconds": 4.0}
-    assert {m["name"] for m in cell.end_to_end} == {
+    assert {m["name"] for m in cell.end_to_end} >= {
         "samples_per_s_chip", "tokens_per_s_chip", "loss_at_n", "setup_s"}
-    # the seven per-layer metrics due in every cell; the three readers are
-    # not listed: ``test_phase_readers.py`` pins the tail of ``per_layer``
-    assert {m["name"] for m in cell.per_layer} == {
+    # at least the per-layer metrics due in every cell and the cell's three
+    # readers: a metric appended without a ``workloads`` list is due here
+    # too
+    per = {m["name"]: m for m in cell.per_layer}
+    assert set(per) >= {
         "input_ms_per_step", "step_ms_p50", "device_ms_per_step",
         "device_idle", "peak_hbm", "ps_host_ms_per_step",
-        "ps_program_load_s"}
+        "ps_program_load_s", *READERS}
     for name in READERS:
+        assert per[name]["workloads"][:1] == [CELL]
+        assert per[name]["moves"] == "tokens_per_s_chip"
         assert callable(spec.load_reader(name))
     # loss_step1 is left out: the control reads like a sound run there
     assert set(cell.workload["limits"]) == {
@@ -71,10 +75,26 @@ def test_the_accepted_entries_keep_their_places_as_prefixes():
     assert tokens["workloads"][:6] == [
         "gpt2-xl.t1024-b16", "zaya1-8b.t8192-b4", "joyai-llm-flash.t8192-b2",
         "gpt2-xl.t1024-b4", "olmo-hybrid-7b.t8192-b4", CELL]
-    assert [m["name"] for m in bm["end_to_end"]] == [
+    assert [m["name"] for m in bm["end_to_end"]][:4] == [
         "samples_per_s_chip", "tokens_per_s_chip", "loss_at_n", "setup_s"]
     assert bm["run_seconds"] == 25
-    assert sum(w["chips"] == 4 for w in bm["workloads"]) == 1
+    assert [w["name"] for w in bm["workloads"] if w["chips"] == 4][:1] == [
+        "olmo-hybrid-7b.t8192-b4"]
+    assert names[26:29] == list(READERS)
+
+
+def test_the_three_readers_are_listed_with_their_fields():
+    """Appended after PR 37's seven, each due in this cell (the first of
+    its ``workloads``) and moving its rate; the files are the readers PR
+    41 brought."""
+    per = {m["name"]: m for m in spec.load_benchmark()["per_layer"]}
+    rows = [(per[n]["unit"], per[n]["better"], per[n]["source"],
+             per[n]["layer"], per[n]["moves"], per[n]["workloads"][:1])
+            for n in READERS]
+    due = ("tokens_per_s_chip", [CELL])
+    assert rows == [("%", "higher", "host_clock", "whole step") + due,
+                    ("%", "higher", "device_trace", "kernels") + due,
+                    ("%", "higher", "device_trace", "expert layer") + due]
 
 
 def test_the_configuration_holds_the_published_widths_and_its_cut():
@@ -281,7 +301,7 @@ def test_a_tiny_copy_of_the_cell_runs_and_is_correct(root, capsys):
     line, _ = _run(root, SEEDS[0])
     err = capsys.readouterr().err
     assert line["correct"] is True and line["failed"] == 0
-    assert set(line["metrics"]) == {"setup_s", "samples_per_s_chip",
+    assert set(line["metrics"]) >= {"setup_s", "samples_per_s_chip",
                                     "tokens_per_s_chip", "loss_at_n"}
     for row in line["check"].values():
         assert row["value"] <= row["limit"]
@@ -290,14 +310,49 @@ def test_a_tiny_copy_of_the_cell_runs_and_is_correct(root, capsys):
     assert "output gate's mean by layer" in err and "lm.nll" in err
 
 
-def test_a_traced_tiny_run_reports_the_layers_a_cpu_can(root):
+@pytest.fixture(scope="module")
+def traced(root):
+    """One traced run of the tiny copy, and what each reader handed the
+    harness in it."""
+    got, load = {}, spec.load_reader
+
+    def recording(name, bench_dir=spec.BENCH_DIR):
+        read = load(name, bench_dir)
+
+        def record(run):
+            got[name] = read(run)
+            return got[name]
+        return record
+
+    spec.load_reader = recording
+    try:
+        line, _ = _run(root, SEEDS[1], trace=True)
+    finally:
+        spec.load_reader = load
+    return line, got
+
+
+def test_a_traced_tiny_run_reports_the_layers_a_cpu_can(traced):
     """No chip, so no peaks: the three shares report nothing and do not
     raise; the metrics every training cell owes are there but those a CPU
-    trace has no device plane for."""
-    line, _ = _run(root, SEEDS[1], trace=True)
+    trace has no device plane for: the step's own account and stage
+    records are, so ``ps_step_hbm`` and ``ps_program_trace_s`` print."""
+    line, _ = traced
     assert {"input_ms_per_step", "step_ms_p50", "ps_host_ms_per_step",
-            "ps_program_load_s"} <= set(line["metrics"])
+            "ps_program_load_s", "ps_step_hbm",
+            "ps_program_trace_s"} <= set(line["metrics"])
     assert not set(READERS) & set(line["metrics"])
+
+
+def test_on_a_tiny_traced_run_the_three_read_nothing_or_a_share(traced):
+    """What each of the three hands the harness on a whole traced run on
+    the CPU: ``None`` (no chip, no peaks, no device plane) or a share the
+    chip can reach, never one past 105%."""
+    _, got = traced
+    assert set(READERS) <= set(got)
+    for name in READERS:
+        assert got[name] is None or 0.0 <= got[name] <= 105.0, (
+            name, got[name])
 
 
 def test_an_unchanged_state_is_not_correct(root):

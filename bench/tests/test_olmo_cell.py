@@ -46,15 +46,17 @@ def test_the_cells_files_are_found_by_name_with_four_chips():
     assert mix == {"kind": "lm_tokens", "batch": 4, "seq_len": 8192,
                    "vocab": 12544, "zipf_alpha": 1.05, "pool_batches": 16,
                    "warmup_steps": 2, "trace_seconds": 4.0}
-    assert {m["name"] for m in cell.end_to_end} == {
+    assert {m["name"] for m in cell.end_to_end} >= {
         "samples_per_s_chip", "tokens_per_s_chip", "loss_at_n", "setup_s"}
     per = {m["name"]: m for m in cell.per_layer}
-    assert set(per) == {
+    # at least these: a metric appended without a ``workloads`` list is
+    # due here too
+    assert set(per) >= {
         "input_ms_per_step", "step_ms_p50", "device_ms_per_step",
         "device_idle", "peak_hbm", "ps_host_ms_per_step",
         "ps_program_load_s", *READERS}
     for name in READERS:
-        assert per[name]["workloads"] == [CELL]
+        assert per[name]["workloads"][:1] == [CELL]
         assert per[name]["moves"] == "tokens_per_s_chip"
         assert callable(spec.load_reader(name))
     assert per["linattn_roofline.olmo"]["layer"] == "linear attention"
@@ -67,8 +69,11 @@ def test_the_cells_files_are_found_by_name_with_four_chips():
 
 
 def test_it_is_the_benchmarks_one_four_chip_cell():
+    """The first of them: how many a benchmark may hold is
+    ``test_benchmark_json.py``'s to say."""
     bm = spec.load_benchmark()
-    assert [w["name"] for w in bm["workloads"] if w["chips"] == 4] == [CELL]
+    assert [w["name"] for w in bm["workloads"]
+            if w["chips"] == 4][:1] == [CELL]
 
 
 def test_the_accepted_entries_keep_their_places_as_prefixes():
@@ -82,7 +87,7 @@ def test_the_accepted_entries_keep_their_places_as_prefixes():
     tokens = [m for m in bm["end_to_end"]
               if m["name"] == "tokens_per_s_chip"][0]
     assert tokens["workloads"][4] == CELL
-    assert [m["name"] for m in bm["end_to_end"]] == [
+    assert [m["name"] for m in bm["end_to_end"]][:4] == [
         "samples_per_s_chip", "tokens_per_s_chip", "loss_at_n", "setup_s"]
     assert bm["run_seconds"] == 25
 
@@ -294,7 +299,7 @@ def test_a_tiny_copy_of_the_cell_runs_and_is_correct(root, capsys):
     line, _ = _run(root, SEEDS[0])
     err = capsys.readouterr().err
     assert line["correct"] is True and line["failed"] == 0
-    assert set(line["metrics"]) == {"setup_s", "samples_per_s_chip",
+    assert set(line["metrics"]) >= {"setup_s", "samples_per_s_chip",
                                     "tokens_per_s_chip", "loss_at_n"}
     for row in line["check"].values():
         assert row["value"] <= row["limit"]
@@ -306,10 +311,13 @@ def test_a_tiny_copy_of_the_cell_runs_and_is_correct(root, capsys):
 def test_a_traced_tiny_run_reports_the_layers_a_cpu_can(root):
     """No chip, so no peaks and no device plane: the four new readers
     report nothing and do not raise; the metrics every training cell owes
-    are there but those a CPU trace has no device plane for."""
+    are there but those a CPU trace has no device plane for: the step's
+    own account and stage records are, so ``ps_step_hbm`` and
+    ``ps_program_trace_s`` print."""
     line, _ = _run(root, SEEDS[1], trace=True)
     assert {"input_ms_per_step", "step_ms_p50", "ps_host_ms_per_step",
-            "ps_program_load_s"} <= set(line["metrics"])
+            "ps_program_load_s", "ps_step_hbm",
+            "ps_program_trace_s"} <= set(line["metrics"])
     assert not set(READERS) & set(line["metrics"])
 
 

@@ -184,11 +184,14 @@ def test_a_reader_reports_nothing_where_the_program_has_no_account(
 
 
 def test_the_seven_entries_are_the_last_of_per_layer():
-    """Appended in the issue's order, with its table's fields, due in the
-    two ``gpt2-xl`` cells; what was there keeps its place."""
+    """They were the last when appended; the test keeps its name and now
+    asserts their PLACES, ``per[19:26]`` after ``collective_ms_per_step``,
+    so that entries appended after them may follow. In the issue's order,
+    with its table's fields; no ``workloads`` list, so each is due in every
+    cell that reports what it moves."""
     per = spec.load_benchmark()["per_layer"]
     rows = [(m["name"], m["unit"], m["better"], m["source"], m["layer"],
-             m["moves"]) for m in per[-7:]]
+             m["moves"]) for m in per[19:26]]
     step = ("ms", "lower", "device_trace", "fused PS step",
             "samples_per_s_chip")
     assert rows == [
@@ -199,25 +202,22 @@ def test_the_seven_entries_are_the_last_of_per_layer():
          "samples_per_s_chip"),
         ("ps_program_trace_s", "s", "lower", "program_counter", "set-up",
          "setup_s")]
-    assert all(m["workloads"] == ["gpt2-xl.t1024-b4", "gpt2-xl.t1024-b16"]
-               for m in per[-7:])
+    assert not any("workloads" in m for m in per[19:26])
     assert [m["name"] for m in per[:5]] == [
         "input_ms_per_step", "step_ms_p50", "device_ms_per_step",
         "device_idle", "peak_hbm"]
-    assert per[-8]["name"] == "collective_ms_per_step"
+    assert per[18]["name"] == "collective_ms_per_step"
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in
                                   spec.load_benchmark()["workloads"]])
 def test_all_seven_are_due_in_the_gpt2_xl_cells_and_in_no_other(cell):
-    """The other three cells' tests pin the exact set of their per-layer
-    metrics (``test_the_cells_files_are_found_by_name*``), and are the
-    benchmark's to widen."""
+    """Due in every cell: the readers read any fused step (every cell's
+    system runs one), and a program that keeps no account reads ``None``
+    and prints nothing. The name is the one the test had while the seven
+    were due in the two ``gpt2-xl`` cells alone."""
     due = {m["name"] for m in spec.load_cell(cell).per_layer}
-    if cell.startswith("gpt2-xl."):
-        assert set(ALL) <= due
-    else:
-        assert not set(ALL) & due
+    assert set(ALL) <= due
 
 
 def test_a_traced_tiny_run_prints_the_programs_own_two(tmp_path):

@@ -86,9 +86,10 @@ def test_benchmark_json_lists_both_metrics_for_the_lm_cell():
     assert (load["source"], load["moves"], load["unit"]) == (
         "program_counter", "setup_s", "s")
     assert "workloads" not in host and "workloads" not in load
-    # appended: the entries the benchmark had keep their places
+    # appended: the entries the benchmark had keep their places, and those
+    # appended after them may follow
     names = [m["name"] for m in spec.load_benchmark()["per_layer"]]
-    assert names[-2:] == ["ps_host_ms_per_step", "ps_program_load_s"]
+    assert names[7:9] == ["ps_host_ms_per_step", "ps_program_load_s"]
     assert names[:7] == ["input_ms_per_step", "step_ms_p50",
                          "device_ms_per_step", "device_idle", "peak_hbm",
                          "attn_roofline", "step_mfu.lm"]

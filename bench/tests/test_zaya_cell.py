@@ -32,16 +32,18 @@ def test_the_cells_files_are_found_by_name():
     assert mix == {"kind": "lm_tokens", "batch": 4, "seq_len": 8192,
                    "vocab": 32784, "zipf_alpha": 1.05, "pool_batches": 16,
                    "warmup_steps": 2, "trace_seconds": 3.0}
-    assert {m["name"] for m in cell.end_to_end} == {
+    assert {m["name"] for m in cell.end_to_end} >= {
         "samples_per_s_chip", "tokens_per_s_chip", "loss_at_n", "setup_s"}
     per = {m["name"]: m for m in cell.per_layer}
-    assert set(per) == {
+    # at least these: a metric appended without a ``workloads`` list is
+    # due here too
+    assert set(per) >= {
         "input_ms_per_step", "step_ms_p50", "device_ms_per_step",
         "device_idle", "peak_hbm", "ps_host_ms_per_step",
         "ps_program_load_s", "step_mfu.zaya", "moe_roofline",
         "attn_roofline.zaya"}
     for name in ("step_mfu.zaya", "moe_roofline", "attn_roofline.zaya"):
-        assert per[name]["workloads"] == [CELL]
+        assert per[name]["workloads"][:1] == [CELL]
         assert per[name]["moves"] == "samples_per_s_chip"
         assert callable(spec.load_reader(name))
     assert set(cell.workload["limits"]) == {
@@ -51,23 +53,25 @@ def test_the_cells_files_are_found_by_name():
 
 
 def test_the_accepted_entries_keep_their_places_and_only_grow():
+    """Asserted as prefixes and places: the contract appends entries, so
+    what follows these may grow."""
     bm = spec.load_benchmark()
-    assert [c["name"] for c in bm["configs"]] == ["gpt2-xl", "zaya1-8b"]
-    assert [w["name"] for w in bm["workloads"]] == ["gpt2-xl.t1024-b16",
-                                                    CELL]
+    assert [c["name"] for c in bm["configs"]][:2] == ["gpt2-xl", "zaya1-8b"]
+    assert [w["name"] for w in bm["workloads"]][:2] == [
+        "gpt2-xl.t1024-b16", CELL]
     names = [m["name"] for m in bm["per_layer"]]
     assert names[:9] == [
         "input_ms_per_step", "step_ms_p50", "device_ms_per_step",
         "device_idle", "peak_hbm", "attn_roofline", "step_mfu.lm",
         "ps_host_ms_per_step", "ps_program_load_s"]
-    assert names[9:] == ["step_mfu.zaya", "moe_roofline",
-                         "attn_roofline.zaya"]
+    assert names[9:12] == ["step_mfu.zaya", "moe_roofline",
+                           "attn_roofline.zaya"]
     tokens = [m for m in bm["end_to_end"]
               if m["name"] == "tokens_per_s_chip"][0]
-    assert tokens["workloads"] == ["gpt2-xl.t1024-b16", CELL]
+    assert tokens["workloads"][:2] == ["gpt2-xl.t1024-b16", CELL]
     old = {m["name"]: m for m in bm["per_layer"]}
-    assert old["attn_roofline"]["workloads"] == ["gpt2-xl.t1024-b16"]
-    assert old["step_mfu.lm"]["workloads"] == ["gpt2-xl.t1024-b16"]
+    assert old["attn_roofline"]["workloads"][:1] == ["gpt2-xl.t1024-b16"]
+    assert old["step_mfu.lm"]["workloads"][:1] == ["gpt2-xl.t1024-b16"]
 
 
 @pytest.mark.parametrize("cell", ["gpt2-xl.t1024-b16", CELL])
@@ -218,7 +222,7 @@ def test_a_tiny_copy_of_the_cell_runs_and_is_correct(root, capsys):
     line, _ = _run(root, SEEDS[0])
     err = capsys.readouterr().err
     assert line["correct"] is True and line["failed"] == 0
-    assert set(line["metrics"]) == {"setup_s", "samples_per_s_chip",
+    assert set(line["metrics"]) >= {"setup_s", "samples_per_s_chip",
                                     "tokens_per_s_chip", "loss_at_n"}
     for row in line["check"].values():
         assert row["value"] <= row["limit"]
@@ -229,11 +233,13 @@ def test_a_tiny_copy_of_the_cell_runs_and_is_correct(root, capsys):
 
 def test_a_traced_tiny_run_reports_the_layers_a_cpu_can(root):
     """No chip, so no peaks: the three shares report nothing and do not
-    raise; the seven metrics every training cell owes are there but those
-    a CPU trace has no device plane for."""
+    raise; the metrics every training cell owes are there but those a CPU
+    trace has no device plane for: the step's own account and stage
+    records are, so ``ps_step_hbm`` and ``ps_program_trace_s`` print."""
     line, _ = _run(root, SEEDS[1], trace=True)
     assert {"input_ms_per_step", "step_ms_p50", "ps_host_ms_per_step",
-            "ps_program_load_s"} <= set(line["metrics"])
+            "ps_program_load_s", "ps_step_hbm",
+            "ps_program_trace_s"} <= set(line["metrics"])
     assert not {"step_mfu.zaya", "moe_roofline",
                 "attn_roofline.zaya"} & set(line["metrics"])
 
